@@ -70,27 +70,22 @@ def run_pandas(df):
     return out
 
 
-def _ensure_backend():
-    """Fall back to CPU when the configured accelerator backend is broken."""
+def compile_cache_dir(sub: str = "") -> str:
+    """Where a bench phase keeps its persistent compile cache: the directory
+    ``JAX_COMPILATION_CACHE_DIR`` places, else ``<repo>/.jax_cache[/sub]`` —
+    fixed, because the path is part of the cache key."""
     import os
 
-    import jax
+    from dask_sql_tpu.serving import compile_cache
 
-    try:
-        jax.devices()
-    except Exception:
-        os.environ["JAX_PLATFORMS"] = "cpu"
-        try:
-            jax.config.update("jax_platforms", "cpu")
-        except Exception:
-            pass
-        jax.devices()
+    base = compile_cache.checkout_path()
+    return compile_cache.env_path() or (
+        os.path.join(base, sub) if sub else base)
 
 
 def bench_q3_line(backend: str):
-    """TPC-H Q3 (3-way join + topN) on the same chip — VERDICT r4 #2: the
-    join path had no on-hardware number.  Emitted as its own JSON line
-    before the headline metric."""
+    """TPC-H Q3 (3-way join + topN) on the same chip.  Emitted as its own
+    JSON line before the headline metric."""
     import sys
 
     sys.path.insert(0, "tests")
@@ -130,8 +125,6 @@ def run_inject_smoke():
     on every change without slowing the normal bench path.
     """
     import jax
-
-    _ensure_backend()
 
     from dask_sql_tpu import Context
     from dask_sql_tpu import config as config_module
@@ -179,8 +172,6 @@ def run_estimate_smoke():
     below measured, or measured rows outside the cardinality interval).
     Host + small-device work only — safe to run on every change.
     """
-    _ensure_backend()
-
     from dask_sql_tpu import Context
     from dask_sql_tpu.analysis import estimator
     from dask_sql_tpu.planner.parser import parse_sql
@@ -260,7 +251,6 @@ def run_profile_smoke():
     import json as _json
     import os
 
-    _ensure_backend()
     from dask_sql_tpu import Context
 
     c = Context()
@@ -309,19 +299,24 @@ def run_coldstart_smoke():
     """
     import json as _json
     import os
+    import shutil
     import tempfile
 
     import jax
 
-    _ensure_backend()
     from dask_sql_tpu import Context
     from dask_sql_tpu import config as config_module
     from dask_sql_tpu.serving import compile_cache
 
-    work = tempfile.mkdtemp(prefix="dsql_coldstart_")
+    # the phase wants an EMPTY cache: it empties its own fixed
+    # sub-directory (a directory the environment places is left as found)
+    cache_dir = compile_cache_dir("coldstart")
+    if compile_cache.env_path() is None:
+        shutil.rmtree(cache_dir, ignore_errors=True)
+    work = tempfile.mkdtemp(prefix="dsql_coldstart_")  # snapshot only
     config_module.config.update({
         "serving.cache.enabled": False,
-        "serving.compile_cache.path": os.path.join(work, "compile-cache"),
+        "serving.compile_cache.path": cache_dir,
     })
     df = gen_lineitem(100_000, seed=0)
 
@@ -386,7 +381,6 @@ def run_families_smoke():
     """
     import json as _json
 
-    _ensure_backend()
     import jax
 
     from dask_sql_tpu import Context
@@ -524,7 +518,6 @@ def run_compressed_smoke():
     """
     import json as _json
 
-    _ensure_backend()
     import jax
 
     from dask_sql_tpu import Context
@@ -639,7 +632,6 @@ def run_spmd_smoke():
     if "host_platform_device_count" not in flags:
         os.environ["XLA_FLAGS"] = (
             flags + " --xla_force_host_platform_device_count=8")
-    _ensure_backend()
     import jax
 
     from dask_sql_tpu import Context
@@ -726,7 +718,6 @@ def run_predict_smoke():
     """
     import json as _json
 
-    _ensure_backend()
     import jax
 
     from dask_sql_tpu import Context
@@ -827,7 +818,6 @@ def run_lint_smoke():
         desc = RULES.get(rule, "syntax error")
         print(f"  {rule:<{width}}  {count:>8}  {desc}", flush=True)
 
-    _ensure_backend()
     from dask_sql_tpu import Context
 
     c = Context()
@@ -917,7 +907,6 @@ def run_schedule_smoke():
     """
     import json as _json
 
-    _ensure_backend()
     import jax
 
     from dask_sql_tpu import Context
@@ -1080,7 +1069,6 @@ def run_stream_smoke():
     """
     import json as _json
 
-    _ensure_backend()
     import jax
     import pandas as pd
 
@@ -1192,7 +1180,6 @@ def run_live_smoke():
     import urllib.error
     import urllib.request
 
-    _ensure_backend()
     import jax
 
     from dask_sql_tpu import Context
@@ -1321,7 +1308,6 @@ def run_reuse_smoke():
     """
     import json as _json
 
-    _ensure_backend()
     import jax
 
     from dask_sql_tpu import Context
@@ -1497,7 +1483,6 @@ def run_chaos_smoke():
     """
     import json as _json
 
-    _ensure_backend()
     import jax
 
     from dask_sql_tpu.resilience.chaos import run_campaign
@@ -1567,7 +1552,6 @@ def run_fleet_smoke():
     import json as _json
     from concurrent.futures import ThreadPoolExecutor
 
-    _ensure_backend()
     import jax
 
     from dask_sql_tpu import Context
@@ -1662,6 +1646,11 @@ def run_fleet_smoke():
 def main():
     import sys
 
+    if "--coldstart" not in sys.argv:  # that phase empties a cache of its own
+        from dask_sql_tpu.serving import compile_cache
+
+        compile_cache.enable(compile_cache_dir())
+
     if "--fleet" in sys.argv:
         run_fleet_smoke()
         return
@@ -1709,8 +1698,6 @@ def main():
         return
 
     import jax
-
-    _ensure_backend()
 
     from dask_sql_tpu import Context
     from dask_sql_tpu.utils import TRANSFER_STATS

@@ -3,7 +3,7 @@
 Phases: parse+plan / execute-dispatch / device-sync / to_pandas, plus the raw
 compiled-kernel time (direct call on resident device buffers) as the floor.
 Emits each phase as ITS OWN JSON line the moment it is measured, so a crash
-in a later phase can't swallow earlier data (VERDICT r3 weak #3), then one
+in a later phase can't swallow earlier data, then one
 combined line at the end.  Run on the real chip:  python benchmarks/profile_q1.py
 """
 from __future__ import annotations
@@ -14,7 +14,7 @@ import time
 
 sys.path.insert(0, ".")
 
-from bench import N_ROWS, QUERY, gen_lineitem, _ensure_backend  # noqa: E402
+from bench import N_ROWS, QUERY, gen_lineitem  # noqa: E402
 
 phases = {}
 
@@ -25,7 +25,6 @@ def emit(name, value):
 
 
 def main():
-    _ensure_backend()
     import jax
 
     from dask_sql_tpu import Context

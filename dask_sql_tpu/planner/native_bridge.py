@@ -34,7 +34,8 @@ def _build() -> bool:
                        capture_output=True, timeout=120)
         return os.path.exists(_LIB_PATH)
     except Exception as e:  # dsql: allow-broad-except — any failure means fallback
-        logger.debug("native build failed: %s", e)
+        logger.warning("native planner library did not build (%s); the "
+                       "Python parser and binder serve instead", e)
         return False
 
 

@@ -174,6 +174,10 @@ def run_campaign(seed: int, queries: int = 40, rounds: int = 4,
     saved = list(config_module.config.effective_items())
     faults.reset()
     lock_baseline = runtime_locks.violation_count()
+    # threads alive before the campaign are not its zombies: another engine
+    # in this process (an earlier test's parked background compiler) would
+    # otherwise fail the thread invariant whatever this campaign did
+    thread_baseline = frozenset(t.ident for t in threading.enumerate())
     try:
         config_module.config.update(dict(_BASE_CONFIG))
         context = _build_context(rng)
@@ -287,7 +291,8 @@ def run_campaign(seed: int, queries: int = 40, rounds: int = 4,
             # statements about the engine's state after a clean shutdown
             runtime.shutdown(wait=True)
             _check_invariants(report, context, runtime, qids,
-                              lock_baseline=lock_baseline)
+                              lock_baseline=lock_baseline,
+                              thread_baseline=thread_baseline)
         finally:
             runtime.shutdown(wait=True)
     finally:
@@ -325,7 +330,8 @@ def _finisher(context, qid: str):
 
 
 def _check_invariants(report: ChaosReport, context, runtime,
-                      qids: List[str], lock_baseline: int = 0) -> None:
+                      qids: List[str], lock_baseline: int = 0,
+                      thread_baseline: frozenset = frozenset()) -> None:
     """The global post-drain invariants; appends human-readable violation
     strings to the report (and counts ``chaos.violations``)."""
     from ..observability import flight
@@ -382,7 +388,7 @@ def _check_invariants(report: ChaosReport, context, runtime,
         strays = [t.name for t in threading.enumerate()
                   if t.name.startswith(("dsql-warmup", "dsql-bg-compile",
                                         "dsql-compile-watchdog"))
-                  and t.is_alive()]
+                  and t.ident not in thread_baseline and t.is_alive()]
         if not strays:
             break
         time.sleep(0.05)

@@ -40,7 +40,7 @@ Sites wired through the engine (each raises the matching taxonomy error):
     checkpoint  checkpoint.save_state mid-write, before the atomic CURRENT
                 repoint (ExecutionError — proves crash recoverability)
     d2h         the packed device-to-host transfer (columnar/pack.py;
-                TransientExecutionError — a dropped tunnel transfer is
+                TransientExecutionError — a dropped transfer is
                 retryable at the serving worker and must never charge the
                 rung breaker or degrade the query)
 

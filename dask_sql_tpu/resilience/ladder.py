@@ -65,6 +65,10 @@ T = TypeVar("T")
 #: never pre-pay a compile worth skipping)
 _COMPILE_RUNG_PREFIXES = ("compiled_", "spmd_")
 
+#: rungs that write their own richer ``rung:<name>`` span where they answer
+#: (streaming/select.py, streaming/aggregate.py, physical/compiled_predict.py)
+_SELF_REPORTING_RUNGS = ("streamed_", "compiled_predict")
+
 
 def plan_fingerprint(rel) -> str:
     """Stable identity of a plan shape for breaker keys: dataclass reprs
@@ -268,10 +272,11 @@ def attempt(executor, rung: str, fn: Callable[[], Optional[T]],
         from ..observability import live
 
         live.update(rung=rung)
-        if rung.startswith("spmd_"):
-            # the acceptance-visible marker that a query executed on a
-            # sharded rung: a zero-duration span with spmd attrs
-            trace_event(f"rung:{rung}", rung=rung, spmd=True)
+        if not rung.startswith(_SELF_REPORTING_RUNGS):
+            # the acceptance-visible marker of which rung answered: a
+            # zero-duration span, flagged spmd for the sharded rungs
+            trace_event(f"rung:{rung}", rung=rung,
+                        spmd=rung.startswith("spmd_"))
         if key is not None and breaker.record_success(key):
             # an OPEN circuit just closed on its half-open trial: the
             # rung is healthy again for this family
@@ -374,6 +379,7 @@ def execute_interpreted(executor, rel):
                 "sql.compile": False}), jax.default_device(cpu):
             out = executor.execute(rel)
         metrics.inc("resilience.rung.cpu")
+        trace_event("rung:cpu", rung="cpu", spmd=False)
         from ..observability import live
 
         live.update(rung="cpu")

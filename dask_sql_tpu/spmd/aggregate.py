@@ -87,7 +87,7 @@ class SpmdAggregate(CompiledAggregate):
             has_row_valid=table.row_valid is not None,
             n_params=0,  # rebuilt lazily once the param arity is known
             out_specs=(jax.sharding.PartitionSpec(None, None)),
-            check_rep=False)
+            check_vma=False)
         self._wraps: Dict[int, ColumnSpmdWrap] = {0: self._wrap}
         self._batched_jit = None
 
@@ -102,7 +102,7 @@ class SpmdAggregate(CompiledAggregate):
                 self._fn_raw, self.mesh, base.valid_present,
                 base.has_row_valid, n_params,
                 out_specs=(jax.sharding.PartitionSpec(None, None)),
-                check_rep=False)
+                check_vma=False)
             self._wraps[n_params] = w
         return w
 
@@ -275,6 +275,10 @@ def try_spmd_aggregate(rel: p.Aggregate, executor) -> Optional[Table]:
         return None
     except (ValueError, TypeError, NotImplementedError) as e:
         # a shape the shard_map wrap mis-handles must never sink the query
-        # — the single-chip rungs below are always correct
-        logger.debug("spmd aggregate declined: %s", e)
+        # — the single-chip rungs below are always correct.  WARNING, not
+        # DEBUG: a decline by exception is a fault in the wrap (not an
+        # ineligible shape), and silence here leaves every sharded table
+        # running on one device
+        logger.warning("spmd aggregate declined (%s: %s); a single-chip "
+                       "rung serves instead", type(e).__name__, e)
         return None

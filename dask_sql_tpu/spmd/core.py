@@ -18,11 +18,7 @@ import logging
 from typing import Callable, Sequence, Tuple
 
 import jax
-
-try:
-    from jax import shard_map
-except ImportError:  # pre-0.4.x top-level export: experimental namespace
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from ..parallel.mesh import AXIS
@@ -106,7 +102,7 @@ class ColumnSpmdWrap:
 
     def __init__(self, fn_raw: Callable, mesh,
                  valid_present: Sequence[bool], has_row_valid: bool,
-                 n_params: int, out_specs, check_rep: bool = True):
+                 n_params: int, out_specs, check_vma: bool = True):
         self.mesh = mesh
         self.valid_present = tuple(bool(v) for v in valid_present)
         self.has_row_valid = bool(has_row_valid)
@@ -133,7 +129,7 @@ class ColumnSpmdWrap:
         )
         self.mapped = shard_map(packed_fn, mesh=mesh, in_specs=in_specs,
                                 out_specs=out_specs,
-                                check_rep=check_rep)
+                                check_vma=check_vma)
         self.jitted = jax.jit(self.mapped)
 
     def pack_args(self, datas, valids, row_valid, params) -> Tuple:

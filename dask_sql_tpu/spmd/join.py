@@ -22,12 +22,8 @@ from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
-
-try:
-    from jax import shard_map
-except ImportError:  # pre-0.4.x top-level export: experimental namespace
-    from jax.experimental.shard_map import shard_map
 
 from ..columnar.table import Table
 from ..parallel.mesh import AXIS
@@ -120,7 +116,7 @@ class SpmdJoinAggregate(CompiledJoinAggregate):
             (P(),) * n_params,
         )
         mapped = shard_map(packed_fn, mesh=self.mesh, in_specs=in_specs,
-                           out_specs=P(None, None), check_rep=False)
+                           out_specs=P(None, None), check_vma=False)
         fn = jax.jit(mapped)
         self._mapped[n_params] = fn
         return fn
@@ -296,6 +292,10 @@ def try_spmd_join_aggregate(rel: p.Aggregate, executor) -> Optional[Table]:
         return None
     except (ValueError, TypeError, NotImplementedError) as e:
         # a shape the shard_map wrap mis-handles must never sink the query
-        # — the single-chip rungs below are always correct
-        logger.debug("spmd join pipeline declined: %s", e)
+        # — the single-chip rungs below are always correct.  WARNING, not
+        # DEBUG: a decline by exception is a fault in the wrap (not an
+        # ineligible shape), and silence here leaves every sharded table
+        # running on one device
+        logger.warning("spmd join pipeline declined (%s: %s); a single-chip "
+                       "rung serves instead", type(e).__name__, e)
         return None

@@ -99,7 +99,7 @@ class SpmdSelect(CompiledSelect):
             w = ColumnSpmdWrap(
                 self._mask_shard, self.mesh, self._valid_present,
                 self._has_row_valid, n_params,
-                out_specs=(P(AXIS), P(AXIS)), check_rep=False)
+                out_specs=(P(AXIS), P(AXIS)), check_vma=False)
             self._mask_wraps[n_params] = w
         return w
 
@@ -117,7 +117,7 @@ class SpmdSelect(CompiledSelect):
 
             w = ColumnSpmdWrap(gather_shard, self.mesh, self._valid_present,
                                True, n_params,
-                               out_specs=P(None, AXIS), check_rep=False)
+                               out_specs=P(None, AXIS), check_vma=False)
             fn = (w, w.jitted)
             self._spmd_gathers[key] = fn
         return fn
@@ -328,5 +328,6 @@ def try_spmd_select(root, executor) -> Optional[Table]:
         logger.debug("spmd select unsupported: %s", e)
         return None
     except (ValueError, TypeError, NotImplementedError) as e:
-        logger.debug("spmd select declined: %s", e)
+        logger.warning("spmd select declined (%s: %s); a single-chip rung "
+                       "serves instead", type(e).__name__, e)
         return None

@@ -7,7 +7,7 @@ statements with the usual qualification substitutions, lightly normalized
 (no vendor-specific syntax; `days` interval arithmetic written as
 INTERVAL 'n' DAY).
 
-Used by tests/unit/test_queries_ds.py (runner + xfail list) and
+Used by tests/unit/test_queries_ds.py + _b/_c (runner + xfail list) and
 tests/unit/test_native_parser.py (parser differential corpus).
 """
 

@@ -56,9 +56,11 @@ def config_keys():
 
 
 @pytest.fixture
-def persistent_cache(tmp_path, config_keys):
+def persistent_cache(tmp_path, config_keys, monkeypatch):
     """A live persistent compile cache for this test, torn down after (the
-    jax cache dir is process-global state)."""
+    jax cache dir is process-global state).  The path goes through config,
+    so nothing from outside may place the directory."""
+    monkeypatch.delenv(compile_cache.ENV_DIR, raising=False)
     path = str(tmp_path / "compile-cache")
     config_keys({"serving.compile_cache.path": path})
     yield path
@@ -99,6 +101,45 @@ def test_persistent_cache_survives_restart(persistent_cache, config_keys):
     assert c2.metrics.counter("resilience.compile_cache.hit") >= 1
     spans = _compile_spans(c2.last_trace)
     assert spans and any(s.attrs.get("persistent_hit") for s in spans)
+
+
+@pytest.mark.parametrize("placed", ["env", "unset"])
+def test_cache_directory_is_placed_from_outside(placed, tmp_path,
+                                                 config_keys, monkeypatch):
+    """One rule for the one seam: where JAX_COMPILATION_CACHE_DIR is set the
+    program adopts that directory and sets none in code; where it is unset
+    the bench helper resolves to the fixed in-checkout path."""
+    import jax
+
+    import bench
+
+    repo = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    compile_cache.disable()
+    try:
+        if placed == "env":
+            outside = str(tmp_path / "placed-from-outside")
+            monkeypatch.setenv(compile_cache.ENV_DIR, outside)
+            config_keys({"serving.compile_cache.path":
+                         str(tmp_path / "ignored-config-path")})
+            before = jax.config.jax_compilation_cache_dir
+            assert compile_cache.maybe_enable(config_module.config)
+            assert jax.config.jax_compilation_cache_dir == before
+            assert compile_cache.enabled_path() == outside
+            assert not os.path.exists(str(tmp_path / "ignored-config-path"))
+            assert bench.compile_cache_dir("coldstart") == outside
+            # ... and teardown leaves the environment's directory alone
+            compile_cache.disable()
+            assert jax.config.jax_compilation_cache_dir == before
+        else:
+            monkeypatch.delenv(compile_cache.ENV_DIR, raising=False)
+            assert not compile_cache.maybe_enable(config_module.config)
+            assert bench.compile_cache_dir() == os.path.join(
+                repo, ".jax_cache")
+            assert bench.compile_cache_dir("coldstart") == os.path.join(
+                repo, ".jax_cache", "coldstart")
+    finally:
+        compile_cache.disable()
 
 
 def test_torn_cache_entry_degrades_to_recompile(persistent_cache,

@@ -1,0 +1,197 @@
+"""Ask the TPU v5e's compiler about the main path, with no chip attached.
+
+Section 2 of the on-chip-measurement guide: libtpu compiles for a DESCRIBED
+``v5e:2x2`` topology from shapes only, so what the chip's compiler would
+refuse (a Mosaic kernel it cannot lower, a program that does not fit 16 GB)
+is refused here, at no chip time.  Nothing runs: a compile that passes is
+not a chip run.
+
+The topology is described inside a module-scoped fixture — never at import
+— because only one process may load the TPU's library: every xdist worker
+imports this file, only the worker that runs it loads libtpu.  All such
+tests live in this one file for the same reason.
+
+Shapes are `chip_smoke.py`'s default: TPC-H lineitem at SF10, 60,000,000
+rows, in the dtypes `Context.create_table` encodes the smoke's frame to.
+No sort-based program is compiled here (64-bit sorts take minutes).
+"""
+import os
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, \
+    SingleDeviceSharding
+
+ROWS = 60_000_000
+HBM_BYTES = 16 * 10**9  # one v5e chip
+SMALL_ROWS = 200_000
+
+#: what create_table encodes chip_smoke's lineitem to (24 B/row)
+ENCODED_DTYPES = {
+    "l_returnflag": "int32", "l_linestatus": "int32", "l_quantity": "int16",
+    "l_extendedprice": "float64", "l_discount": "int16", "l_tax": "int16",
+    "l_shipdate": "int16",
+}
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no libtpu here, or another process holds it
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described device is written to the persistent cache
+    # but can never be read back without a chip: keep the cache out of it
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def captured():
+    """Run the smoke's Q1 and Q6 at a small size on the CPU — single-chip
+    and row-sharded — and keep what the compiled rungs were built from:
+    ``{"q1": (pipeline, table, params), ..., "spmd_q1": (ctor args, params)}``.
+    The segment sum is steered to what ``auto`` picks on a TPU for these
+    domains (the blocked matmul) through the existing config key."""
+    import chip_smoke
+    from dask_sql_tpu import Context
+    from dask_sql_tpu.physical.compiled import CompiledAggregate
+    from dask_sql_tpu.spmd.aggregate import SpmdAggregate
+
+    df = chip_smoke.gen_lineitem(SMALL_ROWS, seed=0)
+    runs, ctors = [], []
+    run, init = CompiledAggregate.run, SpmdAggregate.__init__
+
+    def spy_run(self, table=None, params=()):
+        runs.append((self, table, params))
+        return run(self, table, params)
+
+    def spy_init(self, *args, **kwargs):
+        ctors.append(args)
+        init(self, *args, **kwargs)
+
+    out = {}
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(CompiledAggregate, "run", spy_run)
+        mp.setattr(SpmdAggregate, "__init__", spy_init)
+        c = Context()
+        c.config.update({"serving.cache.enabled": False})
+        c.create_table("lineitem", df)
+        table = c.schema[c.schema_name].tables["lineitem"].table
+        assert {n: str(col.data.dtype) for n, col in table.columns.items()} \
+            == ENCODED_DTYPES
+        for name in ("q1", "q6"):
+            c.sql(chip_smoke.QUERIES[name],
+                  config_options={"sql.compile.segsum": "matmul"}).compute()
+            out[name] = runs[-1]
+            assert out[name][0].segsum_mode == "matmul"
+        sharded = Context()
+        sharded.config.update({"serving.cache.enabled": False})
+        sharded.create_table("lineitem", df, distributed=True)
+        sharded.sql(chip_smoke.QUERIES["q1"]).compute()
+        assert sharded.metrics.counter("resilience.rung.spmd_aggregate") == 1
+        out["spmd_q1"] = (ctors[-1], runs[-1][2])
+    return out
+
+
+def _shapes(tree, sharding):
+    """Concrete (tiny) runtime parameters -> shapes placed by `sharding`."""
+    return jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sharding),
+        jax.eval_shape(lambda t: jax.tree.map(jnp.asarray, t), tree))
+
+
+def _column_shapes(table, rows, sharding):
+    cols = [table.columns[n] for n in table.column_names]
+    datas = tuple(jax.ShapeDtypeStruct((rows,), c.data.dtype,
+                                       sharding=sharding) for c in cols)
+    valids = tuple(None if c.validity is None else jax.ShapeDtypeStruct(
+        (rows,), jnp.bool_, sharding=sharding) for c in cols)
+    return datas, valids
+
+
+def _device_bytes(compiled) -> int:
+    m = compiled.memory_analysis()
+    return (m.argument_size_in_bytes + m.output_size_in_bytes
+            + m.temp_size_in_bytes + m.generated_code_size_in_bytes)
+
+
+def test_blocked_segsum_compiles_at_sf10(one_chip):
+    from dask_sql_tpu.ops.pallas_kernels import segsum_scan_blocked
+
+    gid = jax.ShapeDtypeStruct((ROWS,), jnp.int32, sharding=one_chip)
+    cols = [jax.ShapeDtypeStruct((ROWS,), jnp.float32, sharding=one_chip)] * 8
+    compiled = jax.jit(
+        lambda g, cs: segsum_scan_blocked(g, cs, 6)).lower(gid, cols).compile()
+    assert _device_bytes(compiled) < HBM_BYTES
+
+
+@pytest.mark.parametrize("domain", [6, 2048])
+def test_pallas_segsum_compiles(one_chip, domain):
+    """The kernel that had only ever run in interpret mode: Mosaic takes it
+    with x64 on (int32 index maps) and for a wide domain (128-lane blocks)."""
+    from dask_sql_tpu.ops.pallas_kernels import segsum_pallas
+
+    n = 1 << 20
+    gid = jax.ShapeDtypeStruct((n,), jnp.int32, sharding=one_chip)
+    contribs = jax.ShapeDtypeStruct((n, 8), jnp.float32, sharding=one_chip)
+    compiled = jax.jit(lambda g, c: segsum_pallas(g, c, domain)).lower(
+        gid, contribs).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("name", ["q1", "q6"])
+def test_compiled_aggregate_fits_one_chip_at_sf10(one_chip, captured, name):
+    """The jitted pipeline `physical/compiled.py` builds for the query, at
+    60M rows of the encoded dtypes, with the table resident beside it."""
+    pipeline, table, params = captured[name]
+    datas, valids = _column_shapes(table, ROWS, one_chip)
+    compiled = jax.jit(pipeline._fn_raw).lower(
+        datas, valids, None, _shapes(tuple(params), one_chip)).compile()
+    resident = sum(ROWS * np.dtype(d).itemsize
+                   for d in ENCODED_DTYPES.values())
+    used = _device_bytes(compiled)
+    assert used < HBM_BYTES, (used, compiled.memory_analysis())
+    # arguments are the projected columns of the resident table: the rest of
+    # the table still has to fit beside the program
+    args = compiled.memory_analysis().argument_size_in_bytes
+    assert used - args + resident < HBM_BYTES
+
+
+def test_spmd_aggregate_compiles_for_four_chips(topo, captured):
+    """The sharded rung as one program over the 2x2 mesh: row-sharded
+    columns in, an all-reduce combining the per-shard partial states."""
+    from dask_sql_tpu.parallel.mesh import AXIS
+    from dask_sql_tpu.spmd.aggregate import SpmdAggregate
+
+    (_, rel, table, scan, filters, group_exprs, agg_exprs), params = \
+        captured["spmd_q1"]
+    mesh = Mesh(np.array(topo.devices), (AXIS,))
+    assert mesh.devices.size == 4
+    rows, replicated = NamedSharding(mesh, P(AXIS)), NamedSharding(mesh, P())
+    pipeline = SpmdAggregate(mesh, rel, table, scan, filters, group_exprs,
+                             agg_exprs)
+    wrap = pipeline._wrap_for(len(params))
+    datas, valids = _column_shapes(table, ROWS, rows)
+    args = wrap.pack_args(datas, valids, None,
+                          _shapes(tuple(params), replicated))
+    compiled = wrap.jitted.lower(*args).compile()
+    assert "all-reduce" in compiled.as_text()
+    assert _device_bytes(compiled) < HBM_BYTES  # per device
