@@ -34,6 +34,7 @@ from .dtypes import (
     sql_to_np,
 )
 from .encodings import Encoding
+from ..observability.spans import load_span
 
 _NS_PER_DAY = 86_400_000_000_000
 
@@ -75,7 +76,7 @@ class Column:
                                              force=bool(encode))
                 if col is not None:
                     return col
-            return Column(jnp.asarray(vals), sql_type, _dev_mask(msk))
+            return Column(to_device(vals), sql_type, _dev_mask(msk))
 
         kind = arr.dtype.kind
         if kind == "M":  # datetime64 -> ns int64
@@ -107,12 +108,16 @@ class Column:
         mask = _merge_mask(mask, ~isnull)
         filled = obj.copy()
         filled[isnull] = ""
-        uniques, codes = np.unique(filled.astype(str), return_inverse=True)
+        with load_span("encode", encoding="STRING") as attrs:
+            uniques, codes = np.unique(filled.astype(str), return_inverse=True)
+            codes = codes.astype(np.int32)
+            uniques = uniques.astype(object)
+            attrs["distinct"] = len(uniques)
         return Column(
-            jnp.asarray(codes.astype(np.int32)),
+            to_device(codes),
             SqlType.VARCHAR,
             _dev_mask(mask),
-            uniques.astype(object),
+            uniques,
         )
 
     @staticmethod
@@ -300,4 +305,11 @@ def _dev_mask(mask: Optional[np.ndarray]) -> Optional[jnp.ndarray]:
     mask = np.asarray(mask, dtype=bool)
     if mask.all():
         return None
-    return jnp.asarray(mask)
+    return to_device(mask)
+
+
+def to_device(host) -> jnp.ndarray:
+    """`jnp.asarray` of a host array; inside a table registration the call
+    is the load's ``h2d`` span (observability/spans.py `load_span`)."""
+    with load_span("h2d", bytes=int(getattr(host, "nbytes", 0))):
+        return jnp.asarray(host)

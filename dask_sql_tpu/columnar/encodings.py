@@ -111,10 +111,19 @@ def maybe_encode(values: np.ndarray, valid: Optional[np.ndarray],
     representation (ints/floats; datetimes already epoch-ns int64), or
     return None (caller constructs PLAIN).  ``valid`` is a host bool mask
     (True = valid) or None.  ``force=True`` bypasses the load-scope/config
-    gate (tests), not the heuristics."""
+    gate (tests), not the heuristics.  Inside a table registration the
+    probes and the build are the load's ``encode`` span."""
+    from ..observability.spans import load_span
+
+    with load_span("encode") as attrs:
+        col = _select_and_build(values, valid, sql_type, force)
+        attrs["encoding"] = "PLAIN" if col is None else col.encoding.value
+        return col
+
+
+def _select_and_build(values, valid, sql_type, force):
     from .. import config
-    from .column import Column, _dev_mask
-    import jax.numpy as jnp
+    from .column import Column, _dev_mask, to_device
 
     if not force and not should_auto_encode():
         return None
@@ -150,7 +159,7 @@ def maybe_encode(values: np.ndarray, valid: Optional[np.ndarray],
                 filled = values if valid is None else \
                     np.where(np.asarray(valid, bool), values, u[0])
                 codes = np.searchsorted(u, filled).astype(cd)
-                return Column(jnp.asarray(codes), sql_type, _dev_mask(valid),
+                return Column(to_device(codes), sql_type, _dev_mask(valid),
                               None, encoding=Encoding.DICT,
                               enc_values=u.astype(sql_to_np(sql_type)))
 
@@ -171,7 +180,7 @@ def maybe_encode(values: np.ndarray, valid: Optional[np.ndarray],
                 filled = values if valid is None else \
                     np.where(np.asarray(valid, bool), values, lo)
                 codes = ((filled.astype(np.int64) - lo) // scale).astype(cd)
-                return Column(jnp.asarray(codes), sql_type, _dev_mask(valid),
+                return Column(to_device(codes), sql_type, _dev_mask(valid),
                               None, encoding=Encoding.FOR, enc_ref=lo,
                               enc_scale=scale)
 
@@ -198,10 +207,10 @@ def maybe_encode(values: np.ndarray, valid: Optional[np.ndarray],
                 if run_valid is not None and bool(run_valid.all()):
                     run_valid = None
                 return Column(
-                    jnp.asarray(run_vals), sql_type,
-                    None if run_valid is None else jnp.asarray(run_valid),
+                    to_device(run_vals), sql_type,
+                    None if run_valid is None else to_device(run_valid),
                     None, encoding=Encoding.RLE,
-                    enc_lengths=jnp.asarray(lengths), enc_rows=n)
+                    enc_lengths=to_device(lengths), enc_rows=n)
 
             candidates.append((rle_bytes, -1, build_rle))
 

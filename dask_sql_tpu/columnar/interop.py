@@ -8,9 +8,8 @@ from __future__ import annotations
 
 import numpy as np
 
-import jax.numpy as jnp
-
-from .column import Column
+from ..observability.spans import load_span
+from .column import Column, to_device
 from .dtypes import STRING_TYPES, SqlType
 from .table import Table
 
@@ -21,8 +20,12 @@ def arrow_to_table(at) -> Table:
 
     cols = {}
     for name, col in zip(at.column_names, at.columns):
-        arr = col.combine_chunks() if isinstance(col, pa.ChunkedArray) else col
-        cols[name] = _arrow_array_to_column(arr)
+        # the registration's per-column span: the encode and h2d spans
+        # opened below are cut out of it
+        with load_span("convert", column=name):
+            arr = col.combine_chunks() if isinstance(col, pa.ChunkedArray) \
+                else col
+            cols[name] = _arrow_array_to_column(arr)
     return Table(cols, at.num_rows)
 
 
@@ -39,9 +42,11 @@ def _arrow_array_to_column(arr) -> Column:
         uniques = np.asarray(arr.dictionary.to_pylist(), dtype=object)
         if len(uniques) == 0:
             uniques = np.array([""], dtype=object)
-        return Column(jnp.asarray(codes), SqlType.VARCHAR, _mask(mask), uniques)
+        return Column(to_device(codes), SqlType.VARCHAR, _mask(mask), uniques)
     if pa.types.is_string(t) or pa.types.is_large_string(t):
-        enc = pc.dictionary_encode(arr)
+        with load_span("encode", encoding="STRING") as attrs:
+            enc = pc.dictionary_encode(arr)
+            attrs["distinct"] = len(enc.dictionary)
         return _arrow_array_to_column(enc)
     if pa.types.is_timestamp(t):
         ns = np.asarray(arr.cast(pa.timestamp("ns")).fill_null(0)).astype("datetime64[ns]").view(np.int64)
@@ -54,7 +59,7 @@ def _arrow_array_to_column(arr) -> Column:
         return _build(vals, mask, SqlType.DECIMAL)
     if pa.types.is_boolean(t):
         vals = np.asarray(arr.fill_null(False))
-        return Column(jnp.asarray(vals), SqlType.BOOLEAN, _mask(mask))
+        return Column(to_device(vals), SqlType.BOOLEAN, _mask(mask))
     vals = np.asarray(arr.fill_null(0)) if arr.null_count else np.asarray(arr)
     return Column.from_numpy(vals, mask)
 
@@ -62,7 +67,7 @@ def _arrow_array_to_column(arr) -> Column:
 def _mask(mask):
     if mask is None or mask.all():
         return None
-    return jnp.asarray(mask)
+    return to_device(mask)
 
 
 def _build(vals, mask, sql_type) -> Column:
@@ -73,7 +78,7 @@ def _build(vals, mask, sql_type) -> Column:
     col = maybe_encode(vals, mask, sql_type)
     if col is not None:
         return col
-    return Column(jnp.asarray(vals), sql_type, _mask(mask))
+    return Column(to_device(vals), sql_type, _mask(mask))
 
 
 def table_to_arrow(table: Table):

@@ -79,15 +79,15 @@ def packed_host_arrays(bufs: List) -> Optional[List[np.ndarray]]:
         _jit_cache[key] = fn
     from ..config import config as _config
     from ..resilience import faults
-    from ..utils import count_d2h
+    from ..utils import d2h_fetch
 
     # fault site ``d2h`` (resilience/faults.py): the packed transfer is
     # the one device->host transfer of a result that can fail — injected
     # here as a retryable TransientExecutionError so the serving worker's
     # backoff retry (never the rung breaker) absorbs it
     faults.maybe_inject("d2h", _config)
-    count_d2h()
-    packed = np.asarray(jax.device_get(fn(*bufs)))
+    with d2h_fetch():
+        packed = np.asarray(jax.device_get(fn(*bufs)))
     out = []
     for i, (kind, dt) in enumerate(sig):
         row = np.ascontiguousarray(packed[i])

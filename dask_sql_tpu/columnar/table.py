@@ -71,20 +71,26 @@ class Table:
         """``encode``: load-time compressed encodings (columnar/encodings.py)
         — None consults the registration load-scope + config, True forces
         the selection heuristics, False stays dense."""
+        from ..observability.spans import load_span
+
         cols = {}
         for name in df.columns:
-            ser = df[name]
-            mask = None
-            values = ser.to_numpy()
-            if ser.isna().any():
-                mask = ~ser.isna().to_numpy()
-                if values.dtype.kind in ("i", "u", "b"):
-                    pass  # no NaN possible; mask already captured
-            if str(ser.dtype) in ("string", "str") or ser.dtype == object:
-                values = ser.astype(object).to_numpy()
-            elif values.dtype.kind not in ("O", "U", "S", "M", "m", "f", "i", "u", "b"):
-                values = ser.astype(object).to_numpy()
-            cols[str(name)] = Column.from_numpy(values, mask, encode=encode)
+            # the registration's per-column span: the encode and h2d spans
+            # opened inside `Column.from_numpy` are cut out of it
+            with load_span("convert", column=str(name)):
+                ser = df[name]
+                mask = None
+                values = ser.to_numpy()
+                if ser.isna().any():
+                    mask = ~ser.isna().to_numpy()
+                    if values.dtype.kind in ("i", "u", "b"):
+                        pass  # no NaN possible; mask already captured
+                if str(ser.dtype) in ("string", "str") or ser.dtype == object:
+                    values = ser.astype(object).to_numpy()
+                elif values.dtype.kind not in ("O", "U", "S", "M", "m", "f", "i", "u", "b"):
+                    values = ser.astype(object).to_numpy()
+                cols[str(name)] = Column.from_numpy(values, mask,
+                                                    encode=encode)
         return Table(cols, len(df))
 
     @staticmethod
@@ -233,10 +239,11 @@ class Table:
                            type(exc).__name__, exc, len(bufs))
             host = None
         if host is None:
-            from ..utils import count_d2h
+            from ..utils import d2h_fetch
 
-            count_d2h(sum(1 for b in bufs if not isinstance(b, np.ndarray)))
-            return {n: c.to_numpy() for n, c in cols.items()}
+            pulled = [b for b in bufs if not isinstance(b, np.ndarray)]
+            with d2h_fetch(len(pulled), sum(int(b.nbytes) for b in pulled)):
+                return {n: c.to_numpy() for n, c in cols.items()}
         # decode errors propagate: a silent fallback here would double-pay
         # the transfer on every call while hiding the defect
         out = {}

@@ -221,7 +221,9 @@ class TpuFrame:
                 # semantic reuse tiers (materialize/): an incremental
                 # aggregate state or a PROVABLY-subsuming cached sibling
                 # answers the query without compiling or scanning anything
-                reuse = ctx.materialize.try_reuse(self._plan, family, key)
+                with observability.stage("reuse"):
+                    reuse = ctx.materialize.try_reuse(self._plan, family,
+                                                      key)
                 if reuse is not None:
                     served, tier = reuse
                     if tr is not None:
@@ -237,51 +239,52 @@ class TpuFrame:
                             deps=ctx._plan_table_deps(self._plan))
                     self._result = served
                     return self._result
-                estimate = ctx._plan_estimate(self._plan)
-                routed = None
-                if estimate is not None:
-                    # pre-compile OOM gate: a provable over-budget query is
-                    # shed HERE — before the executor compiles anything —
-                    # with a structured, non-retryable taxonomy error.
-                    # Oversize-but-partitionable plans are routed to the
-                    # streaming rungs instead (streaming/): shedding is the
-                    # last resort, not the first.
-                    from .serving.admission import check_estimated_bytes
+                with observability.stage("admit"):
+                    estimate = ctx._plan_estimate(self._plan)
+                    routed = None
+                    if estimate is not None:
+                        # pre-compile OOM gate: a provable over-budget query is
+                        # shed HERE — before the executor compiles anything —
+                        # with a structured, non-retryable taxonomy error.
+                        # Oversize-but-partitionable plans are routed to the
+                        # streaming rungs instead (streaming/): shedding is the
+                        # last resort, not the first.
+                        from .serving.admission import check_estimated_bytes
 
-                    routed = check_estimated_bytes(
-                        estimate, ctx.config, ctx.metrics,
-                        plan=self._plan, context=ctx)
-                    # result-cache admission: a result whose PROVABLE bytes
-                    # already exceed the per-entry cap is never cacheable;
-                    # skip the insert instead of materializing-then-evicting
-                    if key is not None and estimate.result_bytes.lo > \
-                            ctx._result_cache.max_entry_bytes:
-                        ctx.metrics.inc("query.cache.estimate_skip")
-                        key = None
-                trace = bool(ctx.config.get("serving.metrics.node_traces",
-                                            False))
-                executor = Executor(ctx, trace=trace)
-                if routed is not None:
-                    # per-EXECUTION streaming verdict: keyed by the
-                    # streamable node's identity on THIS executor, so a
-                    # concurrent execution of the same cached plan under a
-                    # different budget cannot null it mid-flight
-                    node, decision = routed
-                    executor.stream_decisions[id(node)] = decision
-                exec_plan = self._plan
-                if routed is None:
-                    # sub-plan materialization (materialize/manager.py):
-                    # when this plan's scan->filter stem is pinned, execute
-                    # a rewritten copy that scans the materialized stem —
-                    # the base table is never touched and nothing compiles.
-                    # Streamed executions keep the original plan: their
-                    # routing decision is keyed on ITS node identity.
-                    rewritten = ctx.materialize.try_stem_rewrite(self._plan)
-                    if rewritten is not None:
-                        exec_plan, stem_overrides = rewritten
-                        executor.table_overrides.update(stem_overrides)
-                        if tr is not None:
-                            tr.event("materialized_stem_scan")
+                        routed = check_estimated_bytes(
+                            estimate, ctx.config, ctx.metrics,
+                            plan=self._plan, context=ctx)
+                        # result-cache admission: a result whose PROVABLE bytes
+                        # already exceed the per-entry cap is never cacheable;
+                        # skip the insert instead of materializing-then-evicting
+                        if key is not None and estimate.result_bytes.lo > \
+                                ctx._result_cache.max_entry_bytes:
+                            ctx.metrics.inc("query.cache.estimate_skip")
+                            key = None
+                    trace = bool(ctx.config.get("serving.metrics.node_traces",
+                                                False))
+                    executor = Executor(ctx, trace=trace)
+                    if routed is not None:
+                        # per-EXECUTION streaming verdict: keyed by the
+                        # streamable node's identity on THIS executor, so a
+                        # concurrent execution of the same cached plan under a
+                        # different budget cannot null it mid-flight
+                        node, decision = routed
+                        executor.stream_decisions[id(node)] = decision
+                    exec_plan = self._plan
+                    if routed is None:
+                        # sub-plan materialization (materialize/manager.py):
+                        # when this plan's scan->filter stem is pinned, execute
+                        # a rewritten copy that scans the materialized stem —
+                        # the base table is never touched and nothing compiles.
+                        # Streamed executions keep the original plan: their
+                        # routing decision is keyed on ITS node identity.
+                        rewritten = ctx.materialize.try_stem_rewrite(self._plan)
+                        if rewritten is not None:
+                            exec_plan, stem_overrides = rewritten
+                            executor.table_overrides.update(stem_overrides)
+                            if tr is not None:
+                                tr.event("materialized_stem_scan")
                 t0 = time.perf_counter()
                 # executor boundary: every failure leaves here as a taxonomy
                 # QueryError (code/retryable/degradable), never a raw
@@ -290,71 +293,85 @@ class TpuFrame:
                     self._result = wrap_boundary(
                         lambda: executor.execute_root(exec_plan))
                 exec_ms = (time.perf_counter() - t0) * 1000.0
-                ctx.metrics.observe("query.execute_ms", exec_ms)
-                ctx.metrics.inc("query.executed")
-                if trace:
-                    executor.tracer.publish(ctx.metrics)
-                    if tr is not None:
-                        tr.attach_node_tree(executor.tracer.root)
-                from .serving.cache import table_nbytes
+                with observability.stage("account"):
+                    ctx.metrics.observe("query.execute_ms", exec_ms)
+                    ctx.metrics.inc("query.executed")
+                    if trace:
+                        executor.tracer.publish(ctx.metrics)
+                        if tr is not None:
+                            tr.attach_node_tree(executor.tracer.root)
+                    from .serving.cache import table_nbytes
 
-                result_bytes = table_nbytes(self._result)
-                ctx.profiles.record_exec(
-                    fp, sql=sql_text, exec_ms=exec_ms,
-                    result_bytes=result_bytes,
-                    family=family_fp,
-                    rows=self._result.num_rows)
-                from .serving.runtime import current_ticket
+                    result_bytes = table_nbytes(self._result)
+                    ctx.profiles.record_exec(
+                        fp, sql=sql_text, exec_ms=exec_ms,
+                        result_bytes=result_bytes,
+                        family=family_fp,
+                        rows=self._result.num_rows)
+                    from .serving.runtime import current_ticket
 
-                ticket = current_ticket()
-                if ticket is not None:
-                    # measured footprint for the packing scheduler's
-                    # reservation reconciliation (release surfaces the
-                    # drift as serving.scheduler.reserve_drift): result
-                    # bytes + the MEASURED resident bytes of the scanned
-                    # tables — table_nbytes accounting on both sides, so
-                    # reserve-vs-measured comparisons cannot drift
-                    ticket.measured_bytes = result_bytes \
-                        + ctx._measured_scan_bytes(
-                            self._plan,
-                            routed[1] if routed is not None else None)
-                    # the ledger's measured-vs-reserved reconciliation
-                    # reads the same number off the live entry
-                    entry.measured_bytes = ticket.measured_bytes
-                est = getattr(self._plan, "_dsql_estimate", None)
-                if est is not None:
-                    # the "estimated" side of SHOW PROFILES' observed-vs-
-                    # estimated pairing, recorded HERE because the entry
-                    # now exists (record_estimate never creates entries)
-                    ctx.profiles.record_estimate(fp, est.rows.hi,
-                                                 family=family_fp)
-                deps = ctx._plan_table_deps(self._plan)
-                if key is not None:
-                    # deps-tagged: append_rows/DDL invalidate exactly the
-                    # entries reading the mutated tables (epoch-scoped)
-                    ctx._result_cache.put(key, self._result, deps=deps)
-                # semantic reuse observation (materialize/): stem hit
-                # counting (pin at threshold), subsumption candidate
-                # registration, incremental capture registration
-                ctx.materialize.observe(self._plan, family, key, deps,
-                                        self._result)
+                    ticket = current_ticket()
+                    if ticket is not None:
+                        # measured footprint for the packing scheduler's
+                        # reservation reconciliation (release surfaces the
+                        # drift as serving.scheduler.reserve_drift): result
+                        # bytes + the MEASURED resident bytes of the scanned
+                        # tables — table_nbytes accounting on both sides, so
+                        # reserve-vs-measured comparisons cannot drift
+                        with observability.detail(
+                                "scan_bytes", parent="account") as scan_attrs:
+                            scan_bytes = ctx._measured_scan_bytes(
+                                self._plan,
+                                routed[1] if routed is not None else None)
+                            scan_attrs["bytes"] = scan_bytes
+                        ticket.measured_bytes = result_bytes + scan_bytes
+                        # the ledger's measured-vs-reserved reconciliation
+                        # reads the same number off the live entry
+                        entry.measured_bytes = ticket.measured_bytes
+                    est = getattr(self._plan, "_dsql_estimate", None)
+                    if est is not None:
+                        # the "estimated" side of SHOW PROFILES' observed-vs-
+                        # estimated pairing, recorded HERE because the entry
+                        # now exists (record_estimate never creates entries)
+                        ctx.profiles.record_estimate(fp, est.rows.hi,
+                                                     family=family_fp)
+                    deps = ctx._plan_table_deps(self._plan)
+                    if ticket is not None:
+                        scan_attrs["tables"] = len(deps)
+                    if key is not None:
+                        # deps-tagged: append_rows/DDL invalidate exactly the
+                        # entries reading the mutated tables (epoch-scoped)
+                        ctx._result_cache.put(key, self._result, deps=deps)
+                    # semantic reuse observation (materialize/): stem hit
+                    # counting (pin at threshold), subsumption candidate
+                    # registration, incremental capture registration
+                    with observability.detail("observe", parent="account"):
+                        ctx.materialize.observe(self._plan, family, key,
+                                                deps, self._result)
         return self._result
 
     def compute(self):
         """Materialize to a pandas DataFrame with the SQL output names."""
         table = self.execute()
-        t0 = time.perf_counter()
-        df = table.to_pandas()
-        t1 = time.perf_counter()
+        tr = self._trace
+        with contextlib.ExitStack() as stack:
+            if tr is not None:
+                # add-once: a repeated compute() must not mutate a finished
+                # (possibly already slow-logged) trace with duplicate stages
+                first = stack.enter_context(tr.span_once(
+                    "d2h", rows=table.num_rows, cols=len(self._field_names)))
+                # the pull's `fetch` detail span needs the trace active (a
+                # lazy compute runs outside Context.sql's scope), and a
+                # repeated compute() needs it inactive
+                stack.enter_context(
+                    observability.activate(tr if first else None))
+            t0 = time.perf_counter()
+            df = table.to_pandas()
+            t1 = time.perf_counter()
         # every call transfers again, so every call observes — but the
         # metric must not go dark when tracing is off
         self._context.metrics.observe("query.d2h_ms", (t1 - t0) * 1e3)
-        tr = self._trace
         if tr is not None:
-            # add-once: a repeated compute() must not mutate a finished
-            # (possibly already slow-logged) trace with duplicate stages
-            tr.add_span_once("d2h", t0, t1, rows=table.num_rows,
-                             cols=len(self._field_names))
             # the lifecycle ends here for the Context API (the server path
             # appends its serialize span post-finish): run the slow-query
             # check exactly once
@@ -729,67 +746,75 @@ class Context:
         schema_name = schema_name or self.schema_name
         if schema_name not in self.schema:
             raise KeyError(f"Schema {schema_name} not found")
-        dc = InputUtil.to_dc(input_table, table_name, format=format,
-                             persist=persist, **kwargs)
-        # normalize: the CREATE TABLE ... WITH (distributed=...) passthrough
-        # delivers SQL literals, and a string 'false' must not shard
-        from .spmd.storage import maybe_auto_shard, truthy_option
+        with contextlib.ExitStack() as load:
+            load.enter_context(
+                observability.load_trace(self, schema_name, table_name))
+            with observability.load_span("convert"):
+                dc = InputUtil.to_dc(input_table, table_name, format=format,
+                                     persist=persist, **kwargs)
+            # everything below, to the end of the registration
+            load.enter_context(observability.load_span("register"))
+            # normalize: the CREATE TABLE ... WITH (distributed=...) passthrough
+            # delivers SQL literals, and a string 'false' must not shard
+            from .spmd.storage import maybe_auto_shard, truthy_option
 
-        if truthy_option(distributed):
+            if truthy_option(distributed):
+                from .datacontainer import LazyParquetContainer
+                from .parallel.distribute import shard_table
+
+                if isinstance(dc, LazyParquetContainer):
+                    from .datacontainer import DataContainer
+
+                    dc = DataContainer(shard_table(dc.table))
+                else:
+                    dc.table = shard_table(dc.table)
+            elif distributed is None:
+                # parallel.auto_shard policy (spmd/storage.py): eligible
+                # registrations row-shard over the default mesh without
+                # per-table opt-in, so the SPMD rungs serve plain create_table.
+                # An EXPLICIT distributed=False (or WITH (distributed='false'))
+                # is a per-table opt-out the policy must respect.
+                dc = maybe_auto_shard(dc, self.config, self.metrics)
+            self.schema[schema_name].tables[table_name] = dc
             from .datacontainer import LazyParquetContainer
-            from .parallel.distribute import shard_table
 
-            if isinstance(dc, LazyParquetContainer):
-                from .datacontainer import DataContainer
+            if statistics is None:
+                if isinstance(dc, LazyParquetContainer):
+                    # footer row counts, no data scan (parity: context.py:281-289)
+                    if dc.statistics and dc.statistics.get("num-rows"):
+                        statistics = Statistics(float(dc.statistics["num-rows"]))
+                elif dc.table.num_rows:
+                    statistics = Statistics(float(dc.table.num_rows))
+            if statistics is not None:
+                self.schema[schema_name].statistics[table_name] = statistics
+            filepath = getattr(dc, "filepath", None)
+            if filepath:
+                self.schema[schema_name].filepaths[table_name] = filepath
+            # LazyParquetContainer.table is a LOADING property — peeking it here
+            # would defeat lazy registration; lazy scans are PLAIN anyway
+            table = None if isinstance(dc, LazyParquetContainer) \
+                else getattr(dc, "table", None)
+            if table is not None:
+                self.metrics.inc("load.rows", int(table.num_rows))
+            if table is not None and table.has_encoded_columns():
+                # compressed-encoding accounting (columnar/encodings.py):
+                # encoded vs would-be-dense resident bytes of this registration
+                from .columnar.encodings import Encoding, scan_bytes
 
-                dc = DataContainer(shard_table(dc.table))
+                n_enc = sum(1 for c in table.columns.values()
+                            if c.encoding is not Encoding.PLAIN)
+                enc_b, dec_b = scan_bytes(table)
+                self.metrics.inc("columnar.encoding.encoded_columns", n_enc)
+                self.metrics.observe("columnar.encoding.encoded_bytes", enc_b)
+                self.metrics.observe("columnar.encoding.decoded_bytes", dec_b)
+            self._bump_table_epoch(schema_name, table_name)
+            if self._views.setdefault(schema_name, {}).pop(table_name, None) is not None:
+                # replacing a VIEW with a table: results over OTHER views may
+                # reference this name through their plans — full invalidation
+                self._catalog_serial += 1
+                self._on_catalog_change()
             else:
-                dc.table = shard_table(dc.table)
-        elif distributed is None:
-            # parallel.auto_shard policy (spmd/storage.py): eligible
-            # registrations row-shard over the default mesh without
-            # per-table opt-in, so the SPMD rungs serve plain create_table.
-            # An EXPLICIT distributed=False (or WITH (distributed='false'))
-            # is a per-table opt-out the policy must respect.
-            dc = maybe_auto_shard(dc, self.config, self.metrics)
-        self.schema[schema_name].tables[table_name] = dc
-        from .datacontainer import LazyParquetContainer
-
-        if statistics is None:
-            if isinstance(dc, LazyParquetContainer):
-                # footer row counts, no data scan (parity: context.py:281-289)
-                if dc.statistics and dc.statistics.get("num-rows"):
-                    statistics = Statistics(float(dc.statistics["num-rows"]))
-            elif dc.table.num_rows:
-                statistics = Statistics(float(dc.table.num_rows))
-        if statistics is not None:
-            self.schema[schema_name].statistics[table_name] = statistics
-        filepath = getattr(dc, "filepath", None)
-        if filepath:
-            self.schema[schema_name].filepaths[table_name] = filepath
-        # LazyParquetContainer.table is a LOADING property — peeking it here
-        # would defeat lazy registration; lazy scans are PLAIN anyway
-        table = None if isinstance(dc, LazyParquetContainer) \
-            else getattr(dc, "table", None)
-        if table is not None and table.has_encoded_columns():
-            # compressed-encoding accounting (columnar/encodings.py):
-            # encoded vs would-be-dense resident bytes of this registration
-            from .columnar.encodings import Encoding, scan_bytes
-
-            n_enc = sum(1 for c in table.columns.values()
-                        if c.encoding is not Encoding.PLAIN)
-            enc_b, dec_b = scan_bytes(table)
-            self.metrics.inc("columnar.encoding.encoded_columns", n_enc)
-            self.metrics.observe("columnar.encoding.encoded_bytes", enc_b)
-            self.metrics.observe("columnar.encoding.decoded_bytes", dec_b)
-        self._bump_table_epoch(schema_name, table_name)
-        if self._views.setdefault(schema_name, {}).pop(table_name, None) is not None:
-            # replacing a VIEW with a table: results over OTHER views may
-            # reference this name through their plans — full invalidation
-            self._catalog_serial += 1
-            self._on_catalog_change()
-        else:
-            self._on_catalog_change(tables={(schema_name, table_name)})
+                self._on_catalog_change(tables={(schema_name, table_name)})
 
     def drop_table(self, table_name: str, schema_name: Optional[str] = None) -> None:
         schema_name = schema_name or self.schema_name
@@ -1083,13 +1108,14 @@ class Context:
                 return False
 
             scope.push(_finish_owned_on_error)
-            key = self._plan_cache_key(sql, config_options)
-            plans = None
-            if key is not None:
-                with self._plan_lock:
-                    plans = self._plan_cache.get(key)
-                    if plans is not None:
-                        self._plan_cache.move_to_end(key)
+            with observability.stage("plan_lookup"):
+                key = self._plan_cache_key(sql, config_options)
+                plans = None
+                if key is not None:
+                    with self._plan_lock:
+                        plans = self._plan_cache.get(key)
+                        if plans is not None:
+                            self._plan_cache.move_to_end(key)
             result = None
             if plans is not None:
                 self.metrics.inc("query.plan_cache.hit")
@@ -1135,7 +1161,8 @@ class Context:
             # converts them immediately, create_memory_table.py etc.)
             from .physical.executor import Executor
 
-            table = Executor(self).execute(plan)
+            with observability.stage("execute"):
+                table = Executor(self).execute(plan)
             if not table.columns:
                 return None
             frame = TpuFrame(self, plan, list(table.column_names), config_options)

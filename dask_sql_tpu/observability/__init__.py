@@ -42,6 +42,9 @@ from .spans import (
     compile_sink,
     current_trace,
     detail,
+    fetch,
+    load_span,
+    load_trace,
     merge_chrome_traces,
     stage,
     timed_jit_call,
@@ -61,8 +64,11 @@ __all__ = [
     "compile_sink",
     "current_trace",
     "detail",
+    "fetch",
     "flight",
     "live",
+    "load_span",
+    "load_trace",
     "maybe_log_slow",
     "merge_chrome_traces",
     "render_prometheus",

@@ -9,11 +9,17 @@ instrumentation of compiled pipelines to attribute tensor-runtime time;
 this module is that instrumentation for the whole engine:
 
 - `QueryTrace`: one trace per query (Context API or Presto server), a flat
-  list of `Span`s — sequential lifecycle *stages* (queue_wait, cache_lookup,
-  parse, bind, optimize, verify, estimate, execute, d2h, serialize),
-  *detail* spans nested inside a stage (per-rung XLA compiles, the
+  list of `Span`s — sequential lifecycle *stages* that tile the request
+  (queue_wait, plan_lookup, parse, bind, optimize, verify, estimate,
+  cache_lookup, reuse, admit, execute, account, d2h, result_wait,
+  serialize), *detail* spans nested inside a stage (per-rung XLA compiles,
+  every jitted `launch` and every blocking device->host `fetch`, the
   executor's per-node tree), and zero-duration *events* (resilience-ladder
   degradations, breaker skips, estimator rung-proof skips).
+- `load_trace` / `load_span`: the same model for `Context.create_table` —
+  a ``load:<schema>.<table>`` trace whose ``load:convert`` / ``load:encode``
+  / ``load:h2d`` / ``load:register`` spans tile the registration, summed
+  into the ``load.*_ms`` histograms.
 - A `contextvars` activation scope: `activate(trace)` installs the trace
   for the current thread of control, so the planner, the ladder and the
   compiled pipelines can attach spans without threading a handle through
@@ -28,8 +34,8 @@ this module is that instrumentation for the whole engine:
   per-fingerprint profile entry whenever the call triggered a fresh XLA
   compile (detected via the jit cache-size delta).  The recorded wall time
   is the first-call time — trace + lower + XLA compile + first dispatch —
-  which is the cost a cold fingerprint actually pays; warm calls are never
-  recorded.
+  which is the cost a cold fingerprint actually pays; warm calls record
+  only their ``launch`` detail span.
 
 Span clocks: `time.perf_counter()` (monotonic, process-wide comparable);
 each trace also carries an epoch anchor so exported timestamps are
@@ -94,6 +100,9 @@ class QueryTrace:
         self.epoch_offset = time.time() - self.created_perf
         self.finished = False
         self.slow_logged = False
+        #: rung of the newest `launch` span: the `fetch` that pulls its
+        #: output carries the same ``rung`` attr
+        self.last_rung: Optional[str] = None
 
     # ------------------------------------------------------------- writes
     def add_span(self, name: str, t0: float, t1: Optional[float],
@@ -132,6 +141,33 @@ class QueryTrace:
                 return False
             self.spans.append(Span(name, t0, t1, kind, parent, dict(attrs)))
             return True
+
+    @contextlib.contextmanager
+    def span_once(self, name: str, kind: str = STAGE,
+                  parent: Optional[str] = None, **attrs):
+        """`span` with `add_span_once`'s rule: the scoped span is recorded
+        (open, under the same atomic check) only when no span of this name
+        exists yet; otherwise the body runs unrecorded.  Yields whether it
+        recorded."""
+        with self._lock:
+            span = None
+            if not any(s.name == name for s in self.spans):
+                span = Span(name, time.perf_counter(), None, kind, parent,
+                            dict(attrs))
+                self.spans.append(span)
+        try:
+            yield span is not None
+        finally:
+            if span is not None:
+                span.t1 = time.perf_counter()
+
+    def open_stage(self) -> Optional[str]:
+        """Name of the stage open right now (stages never nest), if any."""
+        with self._lock:
+            for s in reversed(self.spans):
+                if s.kind == STAGE and s.t1 is None:
+                    return s.name
+        return None
 
     def event(self, name: str, **attrs) -> Span:
         t = time.perf_counter()
@@ -374,6 +410,125 @@ def trace_event(name: str, **attrs) -> None:
         tr.event(name, **attrs)
 
 
+def fetch(nbytes: Optional[int] = None):
+    """Scoped ``fetch`` DETAIL span around ONE blocking device->host pull
+    (`utils.d2h_fetch` opens it at every pull site), nested under the stage
+    open at the time: ``execute`` for a rung's packed-result pull, ``d2h``
+    for the result's own `to_pandas`.  ``rung`` is the newest launch's;
+    ``bytes`` where the size is known before the pull."""
+    tr = current_trace()
+    if tr is None:
+        return contextlib.nullcontext({})
+    return tr.span("fetch", kind=DETAIL, parent=tr.open_stage(),
+                   rung=tr.last_rung, bytes=nbytes)
+
+
+# ---------------------------------------------------------------------------
+# load traces (Context.create_table)
+# ---------------------------------------------------------------------------
+LOAD_PHASES = ("convert", "encode", "h2d", "register")
+
+
+class _LoadSink:
+    """Where the ``load:*`` spans of one `create_table` go.  Phases nest in
+    code (an ``h2d`` inside an ``encode`` inside a ``convert``) but are
+    recorded as EXCLUSIVE segments: entering a child closes the parent's
+    running segment and leaving it reopens one, so the recorded spans are
+    sequential, disjoint and tile the call by construction."""
+
+    def __init__(self, trace: Optional[QueryTrace], kind: str,
+                 parent: Optional[str]):
+        self.trace, self.kind, self.parent = trace, kind, parent
+        self.seconds = dict.fromkeys(LOAD_PHASES, 0.0)
+        self.h2d_bytes = 0
+        #: (phase, attrs, segments recorded so far) of the open phases
+        self._stack: List[tuple] = []
+        self._since = 0.0  # start of the running segment
+
+    def _close_segment(self, now: float) -> None:
+        if not self._stack or now <= self._since:
+            return
+        phase, _, segments = self._stack[-1]
+        self.seconds[phase] += now - self._since
+        if self.trace is not None:
+            segments.append(self.trace.add_span(
+                f"load:{phase}", self._since, now, kind=self.kind,
+                parent=self.parent))
+
+    def enter(self, phase: str, attrs: dict) -> None:
+        now = time.perf_counter()
+        self._close_segment(now)
+        if self._stack and "column" not in attrs \
+                and "column" in self._stack[-1][1]:
+            attrs["column"] = self._stack[-1][1]["column"]
+        self._stack.append((phase, attrs, []))
+        self._since = now
+
+    def leave(self) -> None:
+        now = time.perf_counter()
+        self._close_segment(now)
+        phase, attrs, segments = self._stack.pop()
+        for span in segments:  # every segment carries the phase's attrs
+            span.attrs.update(attrs)
+        if phase == "h2d":
+            self.h2d_bytes += int(attrs.get("bytes") or 0)
+        self._since = now
+
+
+_load: "contextvars.ContextVar[Optional[_LoadSink]]" = \
+    contextvars.ContextVar("dsql_load_sink", default=None)
+
+
+@contextlib.contextmanager
+def load_trace(context, schema_name: str, table_name: str):
+    """The dynamic extent of one `Context.create_table`: `load_span`s inside
+    record onto a trace of the load's own (``qid="load:<schema>.<table>"``,
+    kept in ``context.traces``) as stages — or, when the registration runs
+    inside a statement that already has a trace (``CREATE TABLE ... WITH``),
+    onto that trace as DETAIL spans under ``execute``.  On exit the four
+    phase sums land in the ``load.*_ms`` histograms and the bytes the ``h2d``
+    spans carried in ``load.h2d_bytes`` (tracing on or off)."""
+    tr, kind, parent, owned = current_trace(), DETAIL, "execute", False
+    if tr is None and context._trace_enabled():
+        name = f"{schema_name}.{table_name}"
+        tr = QueryTrace(sql=f"create_table {name}", qid=f"load:{name}",
+                        metrics=context.metrics, profiles=context.profiles)
+        context.traces.put(tr.qid, tr)
+        kind, parent, owned = STAGE, None, True
+    sink = _LoadSink(tr, kind, parent)
+    token = _load.set(sink)
+    try:
+        yield
+    finally:
+        _load.reset(token)
+        metrics, seconds = context.metrics, sink.seconds
+        metrics.observe("load.convert_ms", seconds["convert"] * 1e3)
+        metrics.observe("load.encode_ms", seconds["encode"] * 1e3)
+        metrics.observe("load.h2d_ms", seconds["h2d"] * 1e3)
+        metrics.observe("load.register_ms", seconds["register"] * 1e3)
+        metrics.inc("load.h2d_bytes", sink.h2d_bytes)
+        if owned:
+            tr.finish()
+
+
+@contextlib.contextmanager
+def load_span(phase: str, **attrs):
+    """One phase of the running load (``convert`` / ``encode`` / ``h2d`` /
+    ``register``), exclusive of the phases nested inside it; a no-op outside
+    `load_trace`, so `Column.from_numpy` at query time records nothing.
+    Yields the span's attrs (``column``, ``encoding``, ``distinct``,
+    ``bytes``) for the body to fill in."""
+    sink = _load.get()
+    if sink is None:
+        yield attrs
+        return
+    sink.enter(phase, attrs)
+    try:
+        yield attrs
+    finally:
+        sink.leave()
+
+
 # ---------------------------------------------------------------------------
 # per-rung compile timing
 # ---------------------------------------------------------------------------
@@ -411,7 +566,9 @@ def timed_jit_call(rung: str, fn, *args, may_compile: Optional[bool] = None,
     """Invoke a `jax.jit` callable, recording the call as a fresh XLA
     compile for `rung` when the jit's executable cache grew.
 
-    Recorded (only on a compile): a ``resilience.compile_ms.<rung>``
+    Recorded on EVERY call when a trace is active: a ``launch`` detail span
+    (attr ``rung``) around the call.  Recorded only on a compile: a
+    ``resilience.compile_ms.<rung>``
     histogram observation and a per-fingerprint ProfileStore entry (via the
     installed `compile_sink` — independent of tracing, so SHOW METRICS and
     the pre-warm input stay populated with tracing disabled), plus a
@@ -453,6 +610,12 @@ def timed_jit_call(rung: str, fn, *args, may_compile: Optional[bool] = None,
             metrics=metrics)
     else:
         out = fn(*args, **kwargs)
+    if tr is not None:
+        # every call, warm or cold: the host's side of the dispatch (a warm
+        # call returns before the device is done; the wait shows in `fetch`)
+        tr.add_span("launch", t0, time.perf_counter(), kind=DETAIL,
+                    parent=tr.open_stage() or "execute", rung=rung)
+        tr.last_rung = rung
     if before is None:
         return out
     after = _jit_cache_size(fn)

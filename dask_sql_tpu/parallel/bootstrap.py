@@ -99,19 +99,19 @@ def host_read(arr):
     import jax
     import numpy as np
 
-    from ..utils import count_d2h
+    from ..utils import d2h_fetch
 
-    count_d2h()
-    if not hasattr(arr, "sharding") or getattr(
-            arr, "is_fully_addressable", True):
-        return np.asarray(arr)
-    from jax.sharding import NamedSharding, PartitionSpec
+    with d2h_fetch(nbytes=getattr(arr, "nbytes", None)):
+        if not hasattr(arr, "sharding") or getattr(
+                arr, "is_fully_addressable", True):
+            return np.asarray(arr)
+        from jax.sharding import NamedSharding, PartitionSpec
 
-    sharding = arr.sharding
-    rep = jax.jit(
-        lambda x: x,
-        out_shardings=NamedSharding(sharding.mesh, PartitionSpec()))(arr)
-    return np.asarray(rep)
+        sharding = arr.sharding
+        rep = jax.jit(
+            lambda x: x,
+            out_shardings=NamedSharding(sharding.mesh, PartitionSpec()))(arr)
+        return np.asarray(rep)
 
 
 def all_processes_allgather(local_np):

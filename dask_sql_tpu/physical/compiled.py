@@ -190,17 +190,17 @@ def fetch_packed(packed, domain: int) -> Tuple[np.ndarray, np.ndarray]:
 
     Returns (host_matrix[:, present], present) as numpy arrays; row 0 of the
     matrix is the group-present indicator."""
-    from ..utils import count_d2h
+    from ..utils import d2h_fetch
 
     if domain <= HOST_PULL_DOMAIN:
-        count_d2h()
-        host = np.asarray(jax.device_get(packed))
+        with d2h_fetch(nbytes=int(packed.nbytes)):
+            host = np.asarray(jax.device_get(packed))
         present = np.nonzero(host[0] != 0.0)[0]
         return host[:, present], present
     present_dev = jnp.nonzero(packed[0] != 0.0)[0]
-    count_d2h()
-    host, present = (np.asarray(a) for a in jax.device_get(
-        (packed[:, present_dev], present_dev)))
+    with d2h_fetch():
+        host, present = (np.asarray(a) for a in jax.device_get(
+            (packed[:, present_dev], present_dev)))
     return host, present
 
 
@@ -1195,7 +1195,7 @@ class CompiledAggregate:
         member decodes its slice of the packed output."""
         from ..families import stack_params
         from ..observability import timed_jit_call
-        from ..utils import count_d2h
+        from ..utils import d2h_fetch
 
         n = len(params_list)
         stacked, bucket = stack_params(params_list)
@@ -1209,8 +1209,9 @@ class CompiledAggregate:
                                 may_compile=bucket not in self._warm_batch)
         self._warm_batch.add(bucket)
         tags = self._pack_tags
-        count_d2h()
-        host_all = np.asarray(jax.device_get(packed))  # (bucket, R, domain)
+        with d2h_fetch(nbytes=int(packed.nbytes)):
+            # (bucket, R, domain)
+            host_all = np.asarray(jax.device_get(packed))
         out = []
         for b in range(n):
             host = host_all[b]

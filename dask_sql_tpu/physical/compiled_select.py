@@ -275,7 +275,7 @@ class CompiledSelect:
         return jnp.cumsum(mask.astype(jnp.int64))
 
     def run(self, table: Optional[Table] = None, params: Tuple = ()) -> Table:
-        from ..utils import count_d2h
+        from ..utils import d2h_fetch
         from ..observability import timed_jit_call
 
         # parameter, not shared state: cached pipelines serve concurrent
@@ -287,8 +287,8 @@ class CompiledSelect:
             self._RUNG, self._mask_fn, datas, valids, t.row_valid,
             tuple(params), may_compile=not self._mask_warm)
         self._mask_warm = True
-        count_d2h()
-        count = int(count_dev)  # one scalar round trip
+        with d2h_fetch(nbytes=int(count_dev.nbytes)):
+            count = int(count_dev)  # one scalar round trip
         return self._finish(datas, valids, mask, count, tuple(params))
 
     def _batched_param_split(self) -> Optional[int]:
@@ -307,7 +307,7 @@ class CompiledSelect:
         bucket by repeating the last member).  Survivor gathers then run
         per member — they share the per-bucket gather executables."""
         from ..families import stack_params
-        from ..utils import count_d2h
+        from ..utils import d2h_fetch
         from ..observability import timed_jit_call
 
         n = len(params_list)
@@ -337,14 +337,14 @@ class CompiledSelect:
             table.row_valid, launch_params,
             may_compile=bucket not in self._warm_mask_batch)
         self._warm_mask_batch.add(bucket)
-        count_d2h()
-        counts = np.asarray(jax.device_get(counts_dev))
+        with d2h_fetch(nbytes=int(counts_dev.nbytes)):
+            counts = np.asarray(jax.device_get(counts_dev))
         return [self._finish(datas, valids, masks[b], int(counts[b]),
                              member_params[b]) for b in range(n)]
 
     def _finish(self, datas, valids, mask, count: int,
                 params: Tuple) -> Table:
-        from ..utils import count_d2h
+        from ..utils import d2h_fetch
         from ..observability import timed_jit_call
 
         # without an ORDER BY, a LIMIT caps how many survivors we even pull:
@@ -363,8 +363,8 @@ class CompiledSelect:
                                     may_compile=bucket not in
                                     self._warm_buckets)
             self._warm_buckets.add(bucket)
-            count_d2h()
-            host = np.asarray(jax.device_get(packed))
+            with d2h_fetch(nbytes=int(packed.nbytes)):
+                host = np.asarray(jax.device_get(packed))
         cols, valid_arrs = self._decode_packed(host, count)
         return self._assemble(cols, valid_arrs, count)
 

@@ -62,11 +62,11 @@ class WindowPlugin(BaseRelPlugin):
                       zip(names[len(inp.column_names):], results)
                       if col.validity is not None]
         if with_masks:
-            from ....utils import count_d2h
+            from ....utils import d2h_fetch
 
-            count_d2h()
-            flags = np.asarray(jax.device_get(jnp.stack(
-                [jnp.all(col.validity) for _, col in with_masks])))
+            with d2h_fetch(nbytes=len(with_masks)):
+                flags = np.asarray(jax.device_get(jnp.stack(
+                    [jnp.all(col.validity) for _, col in with_masks])))
             dense = {name: bool(f) for (name, _), f in zip(with_masks, flags)}
         for name, col in zip(names[len(inp.column_names):], results):
             if col.validity is not None and dense.get(name):

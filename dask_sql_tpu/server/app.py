@@ -55,6 +55,9 @@ class _QueryEntry:
     started: Optional[float] = None
     plan_done: Optional[float] = None
     finished: Optional[float] = None
+    #: `finished` on the spans' clock (perf_counter): where the trace's
+    #: ``handoff`` stage ends and its ``result_wait`` stage starts
+    finished_perf: Optional[float] = None
     error: bool = False
     #: the query's lifecycle trace (observability/spans.py), when tracing
     #: is enabled — the status handler appends the serialize span to it
@@ -243,6 +246,7 @@ class _QueryRegistry:
             if e is None or e.finished is not None:
                 return
             e.finished = time.monotonic()
+            e.finished_perf = time.perf_counter()
             if e.started is None:
                 self.n_queued -= 1
             else:
@@ -285,6 +289,15 @@ class _QueryRegistry:
                 observability.flight.flush_on_failure(
                     qid, live_code, self.context.config,
                     self.context.metrics)
+        if e.trace is not None:
+            # the worker's hand-over — compute()'s tail, the runtime's
+            # completion accounting, resolving the future — runs under no
+            # engine stage; under load each step waits for the interpreter
+            # lock, so it gets a stage: the last stage's end -> this callback
+            stages = e.trace.stage_spans()
+            if stages:
+                e.trace.add_span_once("handoff", stages[-1].t1,
+                                      e.finished_perf)
         if e.trace is not None and self.context is not None:
             # terminal for EVERY outcome (result, error, deadline, cancel):
             # close the lifecycle so failed/cancelled outliers reach the
@@ -634,7 +647,12 @@ def _make_handler(context, registry: _QueryRegistry, jdbc_meta: bool,
                 trace = entry.trace
                 if trace is not None:
                     # atomic add-once: concurrent polls of a finished query
-                    # both serialize, but only the first records the stage
+                    # both serialize, but only the first records the stages:
+                    # the finished result's wait for this poll, then the
+                    # serialization itself
+                    if entry.finished_perf is not None:
+                        trace.add_span_once("result_wait",
+                                            entry.finished_perf, t0)
                     trace.add_span_once("serialize", t0, t1,
                                         rows=len(payload["data"]))
             self._send(payload)

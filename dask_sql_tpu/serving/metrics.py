@@ -77,6 +77,14 @@ DOCUMENTED_METRICS = frozenset({
     "parallel.dist.sort_kernel",
     "parallel.dist.join_kernel",
     "parallel.dist.broadcast_join",
+    # observability/spans.py load_trace — Context.create_table: the four
+    # phase sums of one registration (histograms, ms) and what it landed
+    "load.convert_ms",
+    "load.encode_ms",
+    "load.h2d_ms",
+    "load.register_ms",
+    "load.rows",
+    "load.h2d_bytes",
     # observability/ — lifecycle tracing + slow-query log + flight recorder
     "observability.slow_query",
     "observability.flight.dumps",

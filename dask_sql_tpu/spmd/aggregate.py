@@ -128,7 +128,7 @@ class SpmdAggregate(CompiledAggregate):
         every member over a single sharded scan."""
         from ..families import stack_params
         from ..observability import timed_jit_call
-        from ..utils import count_d2h
+        from ..utils import d2h_fetch
 
         n = len(params_list)
         stacked, bucket = stack_params(params_list)
@@ -143,8 +143,9 @@ class SpmdAggregate(CompiledAggregate):
                                 may_compile=bucket not in self._warm_batch)
         self._warm_batch.add(bucket)
         tags = self._pack_tags
-        count_d2h()
-        host_all = np.asarray(jax.device_get(packed))  # (bucket, R, domain)
+        with d2h_fetch(nbytes=int(packed.nbytes)):
+            # (bucket, R, domain)
+            host_all = np.asarray(jax.device_get(packed))
         out = []
         for b in range(n):
             host = host_all[b]

@@ -125,7 +125,7 @@ class SpmdSelect(CompiledSelect):
     # ------------------------------------------------------------ execution
     def run(self, table: Optional[Table] = None, params: Tuple = ()) -> Table:
         from ..observability import timed_jit_call
-        from ..utils import count_d2h
+        from ..utils import d2h_fetch
 
         t = table if table is not None else self.table
         datas = [t.columns[n].data for n in t.column_names]
@@ -135,8 +135,8 @@ class SpmdSelect(CompiledSelect):
         mask, counts = timed_jit_call("spmd_select", wrap.jitted, *args,
                                       may_compile=not self._mask_warm)
         self._mask_warm = True
-        count_d2h()
-        counts_h = np.asarray(jax.device_get(counts)).astype(np.int64)
+        with d2h_fetch(nbytes=int(counts.nbytes)):
+            counts_h = np.asarray(jax.device_get(counts)).astype(np.int64)
         return self._finish_spmd(datas, valids, mask, counts_h, params)
 
     def run_batched(self, table: Table, params_list: List[Tuple]
@@ -146,7 +146,7 @@ class SpmdSelect(CompiledSelect):
         per-bucket SPMD gather executables."""
         from ..families import stack_params
         from ..observability import timed_jit_call
-        from ..utils import count_d2h
+        from ..utils import d2h_fetch
 
         n = len(params_list)
         stacked, bucket = stack_params(params_list)
@@ -161,15 +161,15 @@ class SpmdSelect(CompiledSelect):
             "spmd_select", self._mask_batched_jit, *args,
             may_compile=bucket not in self._warm_mask_batch)
         self._warm_mask_batch.add(bucket)
-        count_d2h()
-        counts_h = np.asarray(jax.device_get(counts)).astype(np.int64)
+        with d2h_fetch(nbytes=int(counts.nbytes)):
+            counts_h = np.asarray(jax.device_get(counts)).astype(np.int64)
         return [self._finish_spmd(datas, valids, masks[b], counts_h[b],
                                   params_list[b]) for b in range(n)]
 
     def _finish_spmd(self, datas, valids, mask, counts_h: np.ndarray,
                      params: Tuple) -> Table:
         from ..observability import timed_jit_call
-        from ..utils import count_d2h
+        from ..utils import d2h_fetch
 
         total = int(counts_h.sum())
         want = self._limit_trim(total)
@@ -190,8 +190,9 @@ class SpmdSelect(CompiledSelect):
         packed = timed_jit_call("spmd_select", gfn, *args,
                                 may_compile=bucket not in self._warm_buckets)
         self._warm_buckets.add(bucket)
-        count_d2h()
-        host_all = np.asarray(jax.device_get(packed))  # [R, ndev*bucket]
+        with d2h_fetch(nbytes=int(packed.nbytes)):
+            # [R, ndev*bucket]
+            host_all = np.asarray(jax.device_get(packed))
         parts = [host_all[:, d * bucket: d * bucket + int(take[d])]
                  for d in range(self.ndev) if take[d]]
         host = np.concatenate(parts, axis=1) if parts else None

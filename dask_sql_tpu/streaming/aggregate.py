@@ -197,10 +197,10 @@ class StreamedAggregate(CompiledAggregate):
         finalize arithmetic of `segment_agg_outputs` phase B in numpy, then
         the parent's `_decode` (group-key radix decode, output naming,
         zero-row global-aggregate semantics — literally shared code)."""
-        from ..utils import count_d2h
+        from ..utils import d2h_fetch
 
-        count_d2h()
-        host = [np.asarray(x) for x in jax.device_get(tuple(acc))]
+        with d2h_fetch(nbytes=sum(int(x.nbytes) for x in acc)):
+            host = [np.asarray(x) for x in jax.device_get(tuple(acc))]
         hit = host[0]
         rows: List[np.ndarray] = [(hit != 0).astype(np.float64)]
         tags: List[Tuple[str, np.dtype]] = [("as", np.dtype(np.float64))]

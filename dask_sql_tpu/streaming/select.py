@@ -55,7 +55,7 @@ class _StreamableSelect(CompiledSelect):
 
     def run(self, table=None, params=()):
         from ..observability import timed_jit_call
-        from ..utils import count_d2h
+        from ..utils import d2h_fetch
 
         t = table if table is not None else self.table
         shape = t.padded_rows
@@ -65,9 +65,9 @@ class _StreamableSelect(CompiledSelect):
             self._RUNG, self._mask_fn, datas, valids, t.row_valid,
             tuple(params), may_compile=shape not in self._warm_shapes)
         self._warm_shapes.add(shape)
-        count_d2h()
-        return self._finish(datas, valids, mask, int(count_dev),
-                            tuple(params))
+        with d2h_fetch(nbytes=int(count_dev.nbytes)):
+            count = int(count_dev)
+        return self._finish(datas, valids, mask, count, tuple(params))
 
 
 _CACHE_CAP = 8

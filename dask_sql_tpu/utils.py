@@ -4,7 +4,7 @@ utils.py:121-141, new_temporary_column)."""
 from __future__ import annotations
 
 import uuid
-from typing import Any, Dict
+from typing import Any, Dict, Optional
 
 
 class Pluggable:
@@ -74,8 +74,15 @@ class LoggableDataFrame:
 TRANSFER_STATS: Dict[str, int] = {"d2h": 0}
 
 
-def count_d2h(n: int = 1) -> None:
+def d2h_fetch(n: int = 1, nbytes: Optional[int] = None):
+    """Scope of ``n`` blocking device->host pulls (a `jax.device_get` /
+    `np.asarray` of device buffers): counts them in `TRANSFER_STATS` and
+    records one ``fetch`` detail span on the active query trace
+    (observability/spans.py), ``nbytes`` where the size is known."""
+    from .observability.spans import fetch
+
     TRANSFER_STATS["d2h"] = TRANSFER_STATS.get("d2h", 0) + n
+    return fetch(nbytes)
 
 
 def host_ints(*vals):
@@ -83,5 +90,5 @@ def host_ints(*vals):
     call blocks on its own round trip)."""
     import jax
 
-    count_d2h()
-    return tuple(int(v) for v in jax.device_get(vals))
+    with d2h_fetch():
+        return tuple(int(v) for v in jax.device_get(vals))

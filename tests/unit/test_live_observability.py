@@ -156,7 +156,7 @@ def test_live_entry_records_stage_rung_and_measured_bytes():
     c.sql("SELECT SUM(b) AS s FROM lt", return_futures=False)
     entry = c.live_queries.entries()[-1]
     assert entry.state == "done"
-    assert entry.stage == "execute"
+    assert entry.stage == "account"  # the last stage stamped
     assert entry.rung  # the ladder stamped the answering rung
     assert entry.measured_bytes is not None and entry.measured_bytes > 0
 
