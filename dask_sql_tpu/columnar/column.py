@@ -33,7 +33,7 @@ from .dtypes import (
     np_to_sql,
     sql_to_np,
 )
-from .encodings import Encoding
+from .encodings import Encoding, prime_dictionary_nbytes
 from ..observability.spans import load_span
 
 _NS_PER_DAY = 86_400_000_000_000
@@ -111,7 +111,11 @@ class Column:
         with load_span("encode", encoding="STRING") as attrs:
             uniques, codes = np.unique(filled.astype(str), return_inverse=True)
             codes = codes.astype(np.int32)
+            # the lengths are one vectorised pass while the uniques are
+            # still a <U array: the byte accounting never walks them
+            chars = int(np.char.str_len(uniques).sum())
             uniques = uniques.astype(object)
+            prime_dictionary_nbytes(uniques, chars)
             attrs["distinct"] = len(uniques)
         return Column(
             to_device(codes),

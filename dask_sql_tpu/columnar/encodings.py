@@ -37,10 +37,13 @@ from __future__ import annotations
 import contextlib
 import contextvars
 import enum
-from typing import Optional, Tuple
+import threading
+import weakref
+from typing import Dict, Optional, Tuple
 
 import numpy as np
 
+from ..utils import DICTIONARY_STATS
 from .dtypes import SqlType, sql_to_np, STRING_TYPES
 
 
@@ -285,6 +288,61 @@ def decode_column(col):
 # ---------------------------------------------------------------------------
 # byte accounting (metrics / estimator / bench)
 # ---------------------------------------------------------------------------
+#: character counts of string dictionaries, kept per dictionary ARRAY:
+#: ``id(array) -> (weak reference, characters)``.  Keyed by the array and not
+#: by the Column because `take` / `filter` / `slice` / `replace(...)` hand the
+#: same array to new columns, and every sharer reads the one kept number.
+#: Sound because a dictionary array is never written in place: every path
+#: that changes a dictionary builds a new array.  Held weakly: a dropped
+#: table's entry goes with its array, before the id can be handed out again.
+_DICTIONARY_CHARS: Dict[int, Tuple[weakref.ref, int]] = {}
+_WALK_LOCK = threading.Lock()
+
+
+def _kept_chars(dictionary) -> Optional[int]:
+    kept = _DICTIONARY_CHARS.get(id(dictionary))
+    if kept is not None and kept[0]() is dictionary:
+        return kept[1]
+    return None
+
+
+def prime_dictionary_nbytes(dictionary, chars: int) -> None:
+    """Keep ``chars``, the character count `dictionary_nbytes` would walk
+    out, for a dictionary array whose builder had the lengths cheaply (the
+    load paths: numpy's ``<U`` uniques, arrow's ``utf8_length``), so that
+    it is never walked."""
+    key = id(dictionary)
+    try:
+        # the callback runs while the array is being freed, so before its
+        # id can belong to another
+        ref = weakref.ref(dictionary,
+                          lambda _ref: _DICTIONARY_CHARS.pop(key, None))
+    except TypeError:  # a duck-typed stand-in: counted on every ask
+        return
+    _DICTIONARY_CHARS[key] = (ref, int(chars))
+
+
+def dictionary_nbytes(dictionary) -> int:
+    """Host bytes of a string dictionary (an object array of uniques, whose
+    ``nbytes`` only counts pointers): its characters plus its pointers.  The
+    characters are counted once per dictionary array and kept with it; the
+    interpreted walk that counts them shows in
+    ``DICTIONARY_STATS["columnar.dictionary.walks"]``."""
+    chars = _kept_chars(dictionary)
+    if chars is None:
+        # one walker at a time: threads that ask for the same new array
+        # together walk it once, and the counters lose no update
+        with _WALK_LOCK:
+            chars = _kept_chars(dictionary)
+            if chars is None:
+                chars = sum(len(str(v)) for v in dictionary)
+                DICTIONARY_STATS["columnar.dictionary.walks"] += 1
+                DICTIONARY_STATS["columnar.dictionary.walk_entries"] += \
+                    len(dictionary)
+                prime_dictionary_nbytes(dictionary, chars)
+    return chars + dictionary.nbytes
+
+
 def encoded_nbytes(col) -> int:
     """Resident bytes of a column AS STORED: data buffer + validity mask +
     RLE lengths; host-side dictionaries (strings and DICT values) included
@@ -304,8 +362,7 @@ def encoded_nbytes(col) -> int:
         total += int(enc_values.nbytes)
     dictionary = getattr(col, "dictionary", None)
     if dictionary is not None:
-        # host object array of uniques: nbytes only counts pointers
-        total += sum(len(str(v)) for v in dictionary) + dictionary.nbytes
+        total += dictionary_nbytes(dictionary)
     return total
 
 
@@ -318,8 +375,7 @@ def decoded_nbytes(col) -> int:
     if col.validity is not None:
         total += n  # bool mask, expanded for RLE
     if col.dictionary is not None:
-        total += sum(len(str(v)) for v in col.dictionary) \
-            + col.dictionary.nbytes
+        total += dictionary_nbytes(col.dictionary)
     return total
 
 

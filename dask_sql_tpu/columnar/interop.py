@@ -11,6 +11,7 @@ import numpy as np
 from ..observability.spans import load_span
 from .column import Column, to_device
 from .dtypes import STRING_TYPES, SqlType
+from .encodings import prime_dictionary_nbytes
 from .table import Table
 
 
@@ -42,6 +43,13 @@ def _arrow_array_to_column(arr) -> Column:
         uniques = np.asarray(arr.dictionary.to_pylist(), dtype=object)
         if len(uniques) == 0:
             uniques = np.array([""], dtype=object)
+        elif pa.types.is_string(t.value_type) \
+                or pa.types.is_large_string(t.value_type):
+            # characters, not bytes: `dictionary_nbytes`'s rule; a null
+            # entry decodes to None, which that rule reads as "None"
+            chars = pc.sum(pc.utf8_length(arr.dictionary)).as_py() or 0
+            prime_dictionary_nbytes(
+                uniques, chars + 4 * arr.dictionary.null_count)
         return Column(to_device(codes), SqlType.VARCHAR, _mask(mask), uniques)
     if pa.types.is_string(t) or pa.types.is_large_string(t):
         with load_span("encode", encoding="STRING") as attrs:

@@ -73,6 +73,13 @@ class LoggableDataFrame:
 #: perf work drives toward 1.  Reset with `TRANSFER_STATS.clear()`.
 TRANSFER_STATS: Dict[str, int] = {"d2h": 0}
 
+#: full interpreted walks of a string dictionary made to count its characters
+#: (columnar/encodings.py::dictionary_nbytes), and the entries they visited.
+#: A dictionary array is walked at most once in its life and the load paths
+#: prime theirs, so over a window of queries both stand still.
+DICTIONARY_STATS: Dict[str, int] = {"columnar.dictionary.walks": 0,
+                                    "columnar.dictionary.walk_entries": 0}
+
 
 def d2h_fetch(n: int = 1, nbytes: Optional[int] = None):
     """Scope of ``n`` blocking device->host pulls (a `jax.device_get` /

@@ -366,3 +366,144 @@ def test_checkpoint_roundtrip_reencodes(tmp_path):
     got = c2.sql("SELECT SUM(l_quantity) AS s FROM lineitem",
                  return_futures=False)
     assert float(got["s"][0]) == float(df["l_quantity"].sum())
+
+
+# ------------------------------------------- dictionary byte count, kept once
+_STRINGS = ["a", "héllo", "", "日本語", "ab  ", None, "x" * 40, "Ünï", "a"] * 5
+
+
+def _walked(d):
+    """The rule, walked: characters (not bytes) plus the pointers."""
+    return sum(len(str(v)) for v in d) + d.nbytes
+
+
+def _walks():
+    from dask_sql_tpu.utils import DICTIONARY_STATS
+
+    return (DICTIONARY_STATS["columnar.dictionary.walks"],
+            DICTIONARY_STATS["columnar.dictionary.walk_entries"])
+
+
+def _pandas_loaded():
+    return Table.from_pandas(pd.DataFrame({"s": _STRINGS})).columns["s"]
+
+
+def _arrow_loaded(dictionary_typed):
+    import pyarrow as pa
+
+    arr = pa.array(_STRINGS)
+    if dictionary_typed:
+        arr = arr.dictionary_encode()
+    return Table.from_arrow(pa.table({"s": arr})).columns["s"]
+
+
+def _string_function_output():
+    from dask_sql_tpu.ops import strings
+
+    return strings.map_unary(_pandas_loaded(), lambda s: s.upper() * 2)
+
+
+def _append_rows_union():
+    from dask_sql_tpu import Context
+
+    c = Context()
+    c.create_table("t", pd.DataFrame({"s": _STRINGS}))
+    c.append_rows("t", pd.DataFrame({"s": ["neu", "ñandú", ""]}))
+    return c.schema["root"].tables["t"].table.columns["s"]
+
+
+@pytest.mark.parametrize("build,first_walks", [
+    (_pandas_loaded, 0),
+    (lambda: _arrow_loaded(False), 0),
+    (lambda: _arrow_loaded(True), 0),
+    (lambda: _pandas_loaded().compact_dictionary(), 1),
+    (_string_function_output, 1),
+    (_append_rows_union, 1),
+], ids=["pandas", "arrow_string", "arrow_dictionary", "compact_dictionary",
+        "string_function", "append_rows"])
+def test_dictionary_bytes_are_counted_once_per_array(build, first_walks):
+    """A load path hands its dictionary over counted; any other dictionary
+    is walked on the first ask and never again, whichever column carries
+    it; the number is the walked one; a dropped array's entry goes."""
+    import gc
+    from dataclasses import replace
+
+    col = build()
+    d = col.dictionary
+    assert any(ord(ch) > 127 for v in d for ch in str(v)) and "" in list(d)
+    want = _walked(d)
+    others = int(col.data.nbytes) + (0 if col.validity is None
+                                     else int(col.validity.nbytes))
+    dense = len(col) * 4 + (0 if col.validity is None else len(col))
+    before = _walks()
+    assert encodings.encoded_nbytes(col) == others + want
+    after_first = _walks()
+    assert after_first[0] - before[0] == first_walks
+    assert after_first[1] - before[1] == first_walks * len(d)
+    assert encodings.decoded_nbytes(col) == dense + want
+    assert encodings.dictionary_nbytes(d) == want
+    assert col.device_nbytes() == others + want
+    siblings = [col.take(np.array([0, 2, 1])), col.slice(1, 4),
+                col.filter(np.arange(len(col)) % 2 == 0),
+                replace(col, validity=None)]
+    for sib in siblings:
+        assert sib.dictionary is d
+        assert encodings.encoded_nbytes(sib) \
+            == int(sib.data.nbytes) + (0 if sib.validity is None else
+                                       int(sib.validity.nbytes)) + want
+    assert _walks() == after_first
+
+    key = id(d)
+    assert key in encodings._DICTIONARY_CHARS
+    del col, d, sib, siblings
+    gc.collect()
+    assert key not in encodings._DICTIONARY_CHARS
+
+
+def test_dictionary_count_never_serves_a_recycled_id():
+    """An entry whose array has died is not read for a new array that got
+    the same id: the memo checks the weak reference, not the key alone."""
+    d = np.array(["abc", "de"], dtype=object)
+    other = np.array(["z"], dtype=object)
+    encodings.prime_dictionary_nbytes(d, 5)
+    encodings._DICTIONARY_CHARS[id(other)] = encodings._DICTIONARY_CHARS[id(d)]
+    before = _walks()
+    assert encodings.dictionary_nbytes(other) == 1 + other.nbytes
+    assert _walks()[0] - before[0] == 1
+    assert encodings.dictionary_nbytes(d) == 5 + d.nbytes
+
+
+def test_dictionary_counted_once_under_concurrent_asks():
+    """More threads than cores ask for the same new dictionaries together,
+    on a short switch interval: every answer is the walked one, each array
+    is walked exactly once and the counters lose no update."""
+    import sys
+    import threading
+
+    dicts = [np.array([f"wört {i}-{j}" * (j % 3) for j in range(400)],
+                      dtype=object) for i in range(24)]
+    want = [_walked(d) for d in dicts]
+    before = _walks()
+    wrong, start = [], threading.Barrier(16)
+
+    def ask(offset):
+        start.wait(30)
+        for k in range(len(dicts)):
+            i = (k + offset) % len(dicts)
+            if encodings.dictionary_nbytes(dicts[i]) != want[i]:
+                wrong.append(i)
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        threads = [threading.Thread(target=ask, args=(n,)) for n in range(16)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(60)
+    finally:
+        sys.setswitchinterval(interval)
+    assert not any(t.is_alive() for t in threads) and not wrong
+    after = _walks()
+    assert after[0] - before[0] == len(dicts)
+    assert after[1] - before[1] == sum(len(d) for d in dicts)

@@ -123,6 +123,56 @@ def test_stages_tile_the_request(surface, served, monkeypatch):
     assert [s.parent for s in _details(trace, "observe")] == ["account"]
 
 
+def _walked_table_nbytes(table):
+    """The accounting rule spelled out with every dictionary walked, as
+    before the character count was kept per dictionary array."""
+    total = 0 if table.row_valid is None else int(table.row_valid.nbytes)
+    for col in table.columns.values():
+        for buf in (col.data, col.validity, col.enc_lengths, col.enc_values):
+            if buf is not None:
+                total += int(buf.nbytes)
+        if col.dictionary is not None:
+            total += sum(len(str(v)) for v in col.dictionary) \
+                + col.dictionary.nbytes
+    return total
+
+
+def test_scan_bytes_reads_the_kept_dictionary_count():
+    """Two queries over a table with a 50,000-entry string dictionary that
+    neither reads: `scan_bytes` reports the same, walked-rule bytes in
+    both, the ticket's measured bytes are what a walk would give, and no
+    dictionary is walked between the first query and the second."""
+    from dask_sql_tpu.serving.cache import table_nbytes
+    from dask_sql_tpu.utils import DICTIONARY_STATS
+
+    rows = 50_000
+    c = Context()
+    c.create_table("wide", pd.DataFrame({
+        "g": (np.arange(rows) % 5).astype(np.int64),
+        "v": np.arange(rows, dtype=np.float64) * 0.25,
+        "note": np.array([f"n\u00f6te {i:06d}" + "x" * (i % 9)
+                          for i in range(rows)], dtype=object),
+    }))
+    table = c.schema["root"].tables["wide"].table
+    assert len(table.columns["note"].dictionary) == rows
+    scanned = _walked_table_nbytes(table)
+    assert table_nbytes(table) == scanned
+
+    seen = []
+    for cut in (1, 2):
+        frame = c.sql(f"SELECT g, SUM(v) AS sv FROM wide WHERE v > {cut} "
+                      "GROUP BY g")
+        frame.compute()
+        scan, = _details(c.last_trace, "scan_bytes")
+        entry = c.live_queries.entries()[-1]
+        seen.append((scan.attrs["bytes"], scan.attrs["tables"],
+                     entry.measured_bytes
+                     - _walked_table_nbytes(frame._result),
+                     dict(DICTIONARY_STATS)))
+    assert seen[0][:3] == seen[1][:3] == (scanned, 1, scanned)
+    assert seen[0][3] == seen[1][3]
+
+
 @pytest.mark.parametrize("temperature", ["cold", "warm"])
 def test_launch_and_fetch_inside_execute(temperature):
     """(b) every jitted call is a `launch`, every blocking pull a `fetch`,
@@ -329,3 +379,9 @@ def test_load_spans_and_metrics_are_in_the_docs():
     assert "`load.rows`" in section and "`load.h2d_bytes`" in section
     for name in NEW_SPANS:
         assert f"`{name}`" in text, name
+
+
+def test_dictionary_walk_counters_are_in_the_docs():
+    from dask_sql_tpu.utils import DICTIONARY_STATS
+
+    assert _doc_table("| counter | counts |") == list(DICTIONARY_STATS)
