@@ -18,8 +18,8 @@ this module is that instrumentation for the whole engine:
   degradations, breaker skips, estimator rung-proof skips).
 - `load_trace` / `load_span`: the same model for `Context.create_table` —
   a ``load:<schema>.<table>`` trace whose ``load:convert`` / ``load:encode``
-  / ``load:h2d`` / ``load:register`` spans tile the registration, summed
-  into the ``load.*_ms`` histograms.
+  / ``load:h2d`` / ``load:shard`` / ``load:register`` spans tile the
+  registration, summed into the ``load.*_ms`` histograms.
 - A `contextvars` activation scope: `activate(trace)` installs the trace
   for the current thread of control, so the planner, the ladder and the
   compiled pipelines can attach spans without threading a handle through
@@ -426,7 +426,7 @@ def fetch(nbytes: Optional[int] = None):
 # ---------------------------------------------------------------------------
 # load traces (Context.create_table)
 # ---------------------------------------------------------------------------
-LOAD_PHASES = ("convert", "encode", "h2d", "register")
+LOAD_PHASES = ("convert", "encode", "h2d", "shard", "register")
 
 
 class _LoadSink:
@@ -485,7 +485,7 @@ def load_trace(context, schema_name: str, table_name: str):
     record onto a trace of the load's own (``qid="load:<schema>.<table>"``,
     kept in ``context.traces``) as stages — or, when the registration runs
     inside a statement that already has a trace (``CREATE TABLE ... WITH``),
-    onto that trace as DETAIL spans under ``execute``.  On exit the four
+    onto that trace as DETAIL spans under ``execute``.  On exit the five
     phase sums land in the ``load.*_ms`` histograms and the bytes the ``h2d``
     spans carried in ``load.h2d_bytes`` (tracing on or off)."""
     tr, kind, parent, owned = current_trace(), DETAIL, "execute", False
@@ -505,6 +505,8 @@ def load_trace(context, schema_name: str, table_name: str):
         metrics.observe("load.convert_ms", seconds["convert"] * 1e3)
         metrics.observe("load.encode_ms", seconds["encode"] * 1e3)
         metrics.observe("load.h2d_ms", seconds["h2d"] * 1e3)
+        # 0 on an unsharded load: the five always tile the call
+        metrics.observe("load.shard_ms", seconds["shard"] * 1e3)
         metrics.observe("load.register_ms", seconds["register"] * 1e3)
         metrics.inc("load.h2d_bytes", sink.h2d_bytes)
         if owned:
@@ -514,10 +516,11 @@ def load_trace(context, schema_name: str, table_name: str):
 @contextlib.contextmanager
 def load_span(phase: str, **attrs):
     """One phase of the running load (``convert`` / ``encode`` / ``h2d`` /
-    ``register``), exclusive of the phases nested inside it; a no-op outside
-    `load_trace`, so `Column.from_numpy` at query time records nothing.
+    ``shard`` / ``register``), exclusive of the phases nested inside it; a
+    no-op outside `load_trace`, so `Column.from_numpy` at query time records
+    nothing.
     Yields the span's attrs (``column``, ``encoding``, ``distinct``,
-    ``bytes``) for the body to fill in."""
+    ``bytes``, ``devices``) for the body to fill in."""
     sink = _load.get()
     if sink is None:
         yield attrs
@@ -562,13 +565,14 @@ def _jit_cache_size(fn) -> Optional[int]:
 
 
 def timed_jit_call(rung: str, fn, *args, may_compile: Optional[bool] = None,
-                   **kwargs):
+                   launch_attrs: Optional[dict] = None, **kwargs):
     """Invoke a `jax.jit` callable, recording the call as a fresh XLA
     compile for `rung` when the jit's executable cache grew.
 
     Recorded on EVERY call when a trace is active: a ``launch`` detail span
-    (attr ``rung``) around the call.  Recorded only on a compile: a
-    ``resilience.compile_ms.<rung>``
+    (attr ``rung``, plus the caller's ``launch_attrs``: the sharded rungs
+    give ``devices`` and ``rows_per_device``) around the call.  Recorded
+    only on a compile: a ``resilience.compile_ms.<rung>``
     histogram observation and a per-fingerprint ProfileStore entry (via the
     installed `compile_sink` — independent of tracing, so SHOW METRICS and
     the pre-warm input stay populated with tracing disabled), plus a
@@ -614,7 +618,8 @@ def timed_jit_call(rung: str, fn, *args, may_compile: Optional[bool] = None,
         # every call, warm or cold: the host's side of the dispatch (a warm
         # call returns before the device is done; the wait shows in `fetch`)
         tr.add_span("launch", t0, time.perf_counter(), kind=DETAIL,
-                    parent=tr.open_stage() or "execute", rung=rung)
+                    parent=tr.open_stage() or "execute", rung=rung,
+                    **(launch_attrs or {}))
         tr.last_rung = rung
     if before is None:
         return out

@@ -73,15 +73,18 @@ DOCUMENTED_METRICS = frozenset({
     "parallel.auto_shard.tables",
     "parallel.spmd.launches",
     "parallel.spmd.rows",
+    "parallel.spmd.devices",
     "parallel.dist.agg_kernel",
     "parallel.dist.sort_kernel",
     "parallel.dist.join_kernel",
     "parallel.dist.broadcast_join",
-    # observability/spans.py load_trace — Context.create_table: the four
-    # phase sums of one registration (histograms, ms) and what it landed
+    # observability/spans.py load_trace — Context.create_table: the five
+    # phase sums of one registration (histograms, ms; shard is 0 on an
+    # unsharded load) and what it landed
     "load.convert_ms",
     "load.encode_ms",
     "load.h2d_ms",
+    "load.shard_ms",
     "load.register_ms",
     "load.rows",
     "load.h2d_bytes",

@@ -40,7 +40,8 @@ from ..physical.compiled import (
     singleflight_get_or_build,
 )
 from ..planner import plan as p
-from .core import ColumnSpmdWrap, mesh_key, mesh_of_sharded_table, rung_enabled
+from .core import (ColumnSpmdWrap, count_launch, launch_attrs, mesh_key,
+                   mesh_of_sharded_table, raise_rung_fault, rung_enabled)
 
 logger = logging.getLogger(__name__)
 
@@ -114,8 +115,9 @@ class SpmdAggregate(CompiledAggregate):
         valids = [table.columns[n].validity for n in table.column_names]
         wrap = self._wrap_for(len(params))
         args = wrap.pack_args(datas, valids, table.row_valid, params)
-        packed = timed_jit_call("spmd_aggregate", wrap.jitted, *args,
-                                may_compile=not self._warm)
+        packed = timed_jit_call(
+            "spmd_aggregate", wrap.jitted, *args, may_compile=not self._warm,
+            launch_attrs=launch_attrs(self.mesh, table.padded_rows))
         self._warm = True
         tags = self._pack_tags
         host, present = fetch_packed(packed, self.domain)
@@ -139,8 +141,10 @@ class SpmdAggregate(CompiledAggregate):
         datas = [table.columns[n_].data for n_ in table.column_names]
         valids = [table.columns[n_].validity for n_ in table.column_names]
         args = wrap.pack_args(datas, valids, table.row_valid, stacked)
-        packed = timed_jit_call("spmd_aggregate", self._batched_jit, *args,
-                                may_compile=bucket not in self._warm_batch)
+        packed = timed_jit_call(
+            "spmd_aggregate", self._batched_jit, *args,
+            may_compile=bucket not in self._warm_batch,
+            launch_attrs=launch_attrs(self.mesh, table.padded_rows))
         self._warm_batch.add(bucket)
         tags = self._pack_tags
         with d2h_fetch(nbytes=int(packed.nbytes)):
@@ -257,8 +261,7 @@ def try_spmd_aggregate(rel: p.Aggregate, executor) -> Optional[Table]:
 
             trace_event("family_hit", rung="spmd_aggregate",
                         params=len(params))
-        ctx.metrics.inc("parallel.spmd.launches")
-        ctx.metrics.inc("parallel.spmd.rows", table.num_rows)
+        count_launch(ctx.metrics, mesh, table.num_rows)
         from ..resilience import faults
 
         faults.maybe_inject("oom", executor.config)
@@ -275,11 +278,6 @@ def try_spmd_aggregate(rel: p.Aggregate, executor) -> Optional[Table]:
         logger.debug("spmd aggregate unsupported: %s", e)
         return None
     except (ValueError, TypeError, NotImplementedError) as e:
-        # a shape the shard_map wrap mis-handles must never sink the query
-        # — the single-chip rungs below are always correct.  WARNING, not
-        # DEBUG: a decline by exception is a fault in the wrap (not an
-        # ineligible shape), and silence here leaves every sharded table
-        # running on one device
-        logger.warning("spmd aggregate declined (%s: %s); a single-chip "
-                       "rung serves instead", type(e).__name__, e)
-        return None
+        # a fault in the wrap (not an ineligible shape): a counted step
+        # down — the single-chip rungs below still answer
+        raise_rung_fault("spmd_aggregate", e)

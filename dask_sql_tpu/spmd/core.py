@@ -45,6 +45,37 @@ def rung_enabled(config, rung: str) -> bool:
     return str(v).lower() not in ("off", "false", "0", "none")
 
 
+def raise_rung_fault(rung: str, exc: BaseException):
+    """Re-raise an exception out of building or running a sharded rung's
+    wrap as the taxonomy's degradable `CompileError`, so `ladder.attempt`
+    counts the step down (``resilience.degraded.<rung>``, the breaker, the
+    ``degraded:<rung>`` trace event) and the lower rung still answers.  A
+    bare ValueError/TypeError would classify as the NON-degradable
+    ExecutionError and sink the query; returning None instead (the old
+    behaviour) left a mesh quietly answering from one device."""
+    from ..resilience.errors import CompileError
+
+    msg = (f"{rung} declined ({type(exc).__name__}: {exc}); a lower rung "
+           "serves instead")
+    logger.warning(msg)
+    raise CompileError(msg) from exc
+
+
+def count_launch(metrics, mesh, rows: int) -> None:
+    """One sharded-rung execution in the registry: ``parallel.spmd.launches``
+    / ``.rows`` accumulate, ``parallel.spmd.devices`` is the mesh width of
+    the newest one (a gauge)."""
+    metrics.inc("parallel.spmd.launches")
+    metrics.inc("parallel.spmd.rows", rows)
+    metrics.gauge("parallel.spmd.devices", int(mesh.devices.size))
+
+
+def launch_attrs(mesh, padded_rows: int) -> dict:
+    """Attrs of a sharded rung's ``launch`` span (`timed_jit_call`)."""
+    ndev = int(mesh.devices.size)
+    return {"devices": ndev, "rows_per_device": int(padded_rows) // ndev}
+
+
 def mesh_of_sharded_table(table):
     """The mesh a table's buffers are row-sharded over, or None when the
     table is not mesh-sharded (or the mesh has a single device)."""

@@ -41,7 +41,8 @@ from ..physical.compiled_join import (
 )
 from ..planner import plan as p
 from .aggregate import SpmdSegmentReducer
-from .core import mesh_key, mesh_of_sharded_table, rung_enabled
+from .core import (count_launch, launch_attrs, mesh_key,
+                   mesh_of_sharded_table, raise_rung_fault, rung_enabled)
 
 logger = logging.getLogger(__name__)
 
@@ -162,7 +163,8 @@ class SpmdJoinAggregate(CompiledJoinAggregate):
         packed = timed_jit_call(
             "spmd_join_aggregate", fn, tuple(pdatas), pvalids_p, luts,
             tuple(bdatas), tuple(bvalids_p), rv_t, params,
-            may_compile=not self._warm)
+            may_compile=not self._warm,
+            launch_attrs=launch_attrs(self.mesh, pt.padded_rows))
         self._warm = True
         tags = self._pack_tags
         host, present = fetch_packed(packed, self.domain)
@@ -277,8 +279,7 @@ def try_spmd_join_aggregate(rel: p.Aggregate, executor) -> Optional[Table]:
 
             trace_event("family_hit", rung="spmd_join_aggregate",
                         params=len(params))
-        ctx.metrics.inc("parallel.spmd.launches")
-        ctx.metrics.inc("parallel.spmd.rows", probe_table.num_rows)
+        count_launch(ctx.metrics, mesh, probe_table.num_rows)
         from ..resilience import faults
 
         faults.maybe_inject("oom", config)
@@ -291,11 +292,6 @@ def try_spmd_join_aggregate(rel: p.Aggregate, executor) -> Optional[Table]:
             _declined.add(decline_key)
         return None
     except (ValueError, TypeError, NotImplementedError) as e:
-        # a shape the shard_map wrap mis-handles must never sink the query
-        # — the single-chip rungs below are always correct.  WARNING, not
-        # DEBUG: a decline by exception is a fault in the wrap (not an
-        # ineligible shape), and silence here leaves every sharded table
-        # running on one device
-        logger.warning("spmd join pipeline declined (%s: %s); a single-chip "
-                       "rung serves instead", type(e).__name__, e)
-        return None
+        # a fault in the wrap (not an ineligible shape): a counted step
+        # down — the single-chip rungs below still answer
+        raise_rung_fault("spmd_join_aggregate", e)

@@ -37,7 +37,8 @@ from ..physical.compiled import (
     singleflight_get_or_build,
 )
 from ..physical.compiled_select import CompiledSelect, _extract
-from .core import ColumnSpmdWrap, mesh_key, mesh_of_sharded_table, rung_enabled
+from .core import (ColumnSpmdWrap, count_launch, launch_attrs, mesh_key,
+                   mesh_of_sharded_table, raise_rung_fault, rung_enabled)
 
 logger = logging.getLogger(__name__)
 
@@ -132,8 +133,10 @@ class SpmdSelect(CompiledSelect):
         valids = [t.columns[n].validity for n in t.column_names]
         wrap = self._mask_wrap(len(params))
         args = wrap.pack_args(datas, valids, t.row_valid, params)
-        mask, counts = timed_jit_call("spmd_select", wrap.jitted, *args,
-                                      may_compile=not self._mask_warm)
+        mask, counts = timed_jit_call(
+            "spmd_select", wrap.jitted, *args,
+            may_compile=not self._mask_warm,
+            launch_attrs=launch_attrs(self.mesh, t.padded_rows))
         self._mask_warm = True
         with d2h_fetch(nbytes=int(counts.nbytes)):
             counts_h = np.asarray(jax.device_get(counts)).astype(np.int64)
@@ -159,7 +162,8 @@ class SpmdSelect(CompiledSelect):
         args = wrap.pack_args(datas, valids, table.row_valid, stacked)
         masks, counts = timed_jit_call(
             "spmd_select", self._mask_batched_jit, *args,
-            may_compile=bucket not in self._warm_mask_batch)
+            may_compile=bucket not in self._warm_mask_batch,
+            launch_attrs=launch_attrs(self.mesh, table.padded_rows))
         self._warm_mask_batch.add(bucket)
         with d2h_fetch(nbytes=int(counts.nbytes)):
             counts_h = np.asarray(jax.device_get(counts)).astype(np.int64)
@@ -187,8 +191,10 @@ class SpmdSelect(CompiledSelect):
         bucket = 1 << (int(take.max()) - 1).bit_length()
         wrap, gfn = self._gather_mapped(bucket, len(params))
         args = wrap.pack_args(datas, valids, mask, params)
-        packed = timed_jit_call("spmd_select", gfn, *args,
-                                may_compile=bucket not in self._warm_buckets)
+        packed = timed_jit_call(
+            "spmd_select", gfn, *args,
+            may_compile=bucket not in self._warm_buckets,
+            launch_attrs=launch_attrs(self.mesh, datas[0].shape[0]))
         self._warm_buckets.add(bucket)
         with d2h_fetch(nbytes=int(packed.nbytes)):
             # [R, ndev*bucket]
@@ -313,8 +319,7 @@ def try_spmd_select(root, executor) -> Optional[Table]:
             from ..observability import trace_event
 
             trace_event("family_hit", rung="spmd_select", params=len(params))
-        ctx.metrics.inc("parallel.spmd.launches")
-        ctx.metrics.inc("parallel.spmd.rows", table.num_rows)
+        count_launch(ctx.metrics, mesh, table.num_rows)
         from ..resilience import faults
 
         faults.maybe_inject("oom", executor.config)
@@ -329,6 +334,4 @@ def try_spmd_select(root, executor) -> Optional[Table]:
         logger.debug("spmd select unsupported: %s", e)
         return None
     except (ValueError, TypeError, NotImplementedError) as e:
-        logger.warning("spmd select declined (%s: %s); a single-chip rung "
-                       "serves instead", type(e).__name__, e)
-        return None
+        raise_rung_fault("spmd_select", e)

@@ -250,20 +250,35 @@ def _arrow(frame):
     return pa.Table.from_pandas(frame)
 
 
-@pytest.mark.parametrize("source", ["pandas", "pyarrow"])
-def test_create_table_leaves_a_tiled_load_trace(source):
-    """(c) the four `load:*` stages tile the call and sum to `load.*_ms`."""
+@pytest.fixture
+def mesh4():
+    """The default mesh cut to four of the virtual devices, then restored."""
+    from dask_sql_tpu.parallel import mesh as mesh_module
+
+    was = mesh_module._default_mesh
+    mesh_module.set_default_mesh(mesh_module.make_mesh(4))
+    yield
+    mesh_module.set_default_mesh(was)
+
+
+@pytest.mark.parametrize("source", ["pandas", "pyarrow", "sharded"])
+def test_create_table_leaves_a_tiled_load_trace(source, mesh4):
+    """(c) the `load:*` stages tile the call and sum to `load.*_ms`; the
+    fifth, `load:shard`, is all of `shard_table` on a `distributed=True`
+    load and observes 0 on any other."""
     frame = _frame(20000)
-    data = frame if source == "pandas" else _arrow(frame)
+    data = _arrow(frame) if source == "pyarrow" else frame
+    sharded = source == "sharded"
     c = Context()
     t0 = time.perf_counter()
-    c.create_table("ld", data)
+    c.create_table("ld", data, distributed=sharded)
     call_ms = (time.perf_counter() - t0) * 1e3
     trace = c.traces.get("load:root.ld")
     assert trace is not None and trace.finished
     assert trace.sql == "create_table root.ld"
     stages = trace.stage_spans()
-    assert {s.name for s in trace.spans} == {f"load:{p}" for p in LOAD_PHASES}
+    assert {s.name for s in trace.spans} == {
+        f"load:{p}" for p in LOAD_PHASES if sharded or p != "shard"}
     assert all(s.kind == STAGE for s in trace.spans)
     hists = c.metrics.snapshot()["histograms"]
     total = 0.0
@@ -272,6 +287,18 @@ def test_create_table_leaves_a_tiled_load_trace(source):
         assert hists[f"load.{phase}_ms"]["count"] == 1
         assert hists[f"load.{phase}_ms"]["sum"] == pytest.approx(ms, abs=0.01)
         total += ms
+    shard = [s for s in stages if s.name == "load:shard"]
+    if sharded:
+        table = c.schema["root"].tables["ld"].table
+        placed = [col.data for col in table.columns.values()] \
+            + [col.validity for col in table.columns.values()
+               if col.validity is not None]
+        assert all(len(b.sharding.device_set) == 4 for b in placed)
+        assert all(s.attrs["devices"] == 4 for s in shard)
+        assert shard[-1].attrs["bytes"] == sum(b.nbytes for b in placed)
+        assert hists["load.shard_ms"]["sum"] > 0
+    else:
+        assert hists["load.shard_ms"]["sum"] == 0
     assert _covered(trace) >= 0.99  # exclusive segments: no gap, no overlap
     assert 0.9 * call_ms <= total <= call_ms
     assert c.metrics.counter("load.rows") == 20000

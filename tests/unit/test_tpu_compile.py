@@ -26,6 +26,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, \
     SingleDeviceSharding
 
 ROWS = 60_000_000
+CELL_ROWS = 24_000_000  # perfbench's tpch_sf10_lineitem_4chip: 6M a shard
 HBM_BYTES = 16 * 10**9  # one v5e chip
 SMALL_ROWS = 200_000
 
@@ -108,6 +109,23 @@ def captured():
         sharded.sql(chip_smoke.QUERIES["q1"]).compute()
         assert sharded.metrics.counter("resilience.rung.spmd_aggregate") == 1
         out["spmd_q1"] = (ctors[-1], runs[-1][2])
+        # the benchmark's four-chip cell (sf10_q1_sharded_4chip): all
+        # sixteen columns of perfbench's LINEITEM from pyarrow, its own Q1
+        from perfbench import traffic
+        from perfbench.datagen import tpch_lineitem
+
+        arrays = tpch_lineitem.generate(SMALL_ROWS, seed=29, scale_factor=10)
+        query = traffic.load("queries", "tpch_q1")
+        cell = Context()
+        cell.config.update({"serving.cache.enabled": False})
+        cell.create_table("lineitem",
+                          tpch_lineitem.arrow_tables(arrays)["lineitem"],
+                          distributed=True)
+        assert len(cell.schema[cell.schema_name]
+                   .tables["lineitem"].table.columns) == 16
+        cell.sql(traffic.render(query, {"DELTA": 90})).compute()
+        assert cell.metrics.counter("resilience.rung.spmd_aggregate") == 1
+        out["spmd_q1_cell"] = (ctors[-1], runs[-1][2])
     return out
 
 
@@ -175,21 +193,27 @@ def test_compiled_aggregate_fits_one_chip_at_sf10(one_chip, captured, name):
     assert used - args + resident < HBM_BYTES
 
 
-def test_spmd_aggregate_compiles_for_four_chips(topo, captured):
+@pytest.mark.parametrize("name, rows", [("spmd_q1", ROWS),
+                                        ("spmd_q1_cell", CELL_ROWS)])
+def test_spmd_aggregate_compiles_for_four_chips(topo, captured, name, rows):
     """The sharded rung as one program over the 2x2 mesh: row-sharded
-    columns in, an all-reduce combining the per-shard partial states."""
+    columns in, an all-reduce combining the per-shard partial states.  At
+    the smoke's 15M rows a shard, and at the 6M rows a shard of the
+    benchmark's four-chip cell (Q1 from `perfbench/queries/tpch_q1.json`
+    over the columns it projects from the sixteen)."""
     from dask_sql_tpu.parallel.mesh import AXIS
     from dask_sql_tpu.spmd.aggregate import SpmdAggregate
 
     (_, rel, table, scan, filters, group_exprs, agg_exprs), params = \
-        captured["spmd_q1"]
+        captured[name]
     mesh = Mesh(np.array(topo.devices), (AXIS,))
     assert mesh.devices.size == 4
-    rows, replicated = NamedSharding(mesh, P(AXIS)), NamedSharding(mesh, P())
+    row_blocks, replicated = NamedSharding(mesh, P(AXIS)), \
+        NamedSharding(mesh, P())
     pipeline = SpmdAggregate(mesh, rel, table, scan, filters, group_exprs,
                              agg_exprs)
     wrap = pipeline._wrap_for(len(params))
-    datas, valids = _column_shapes(table, ROWS, rows)
+    datas, valids = _column_shapes(table, rows, row_blocks)
     args = wrap.pack_args(datas, valids, None,
                           _shapes(tuple(params), replicated))
     compiled = wrap.jitted.lower(*args).compile()
