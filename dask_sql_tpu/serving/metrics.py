@@ -250,6 +250,7 @@ DOCUMENTED_METRIC_PREFIXES = (
     "resilience.rung.",         # per rung that answered
     "resilience.breaker.skip.",  # per breaker-skipped rung
     "resilience.compile_ms.",   # per-rung XLA compile wall time (observability/spans.py)
+    "parallel.spmd.segsum.",    # per segment-sum mode a sharded launch traced (spmd/core.py)
     "serving.admitted.",        # per admission class
     "serving.rejected.",        # per admission class
     "serving.scheduler.queue_depth.",    # per admission class (gauge)

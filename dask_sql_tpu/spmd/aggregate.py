@@ -14,10 +14,12 @@ Because the cross-device combine happens on the RAW reduction states (sums,
 counts, mins, maxes) and the finalize code is literally shared with the
 single-chip rung, results are bit-equal to the unsharded path whenever the
 partial sums are exact (always for ints/counts/min/max; for floats up to
-addition-order rounding).  ParamRefs stay traced runtime arguments, so the
-second literal variant of a family pays zero foreground compiles, and the
-family batcher's stacked launches vmap over the leading parameter axis of
-the same SPMD program.
+addition-order rounding).  Each shard runs the segment sum the single-chip
+rung would (`choose_segsum_impl`: the blocked one-hot matmul on a TPU for
+small group domains, the scatter elsewhere).  ParamRefs stay traced runtime
+arguments, so the second literal variant of a family pays zero foreground
+compiles, and in scatter mode (`batchable`) the family batcher's stacked
+launches vmap over the leading parameter axis of the same SPMD program.
 """
 from __future__ import annotations
 
@@ -49,13 +51,22 @@ logger = logging.getLogger(__name__)
 class SpmdSegmentReducer(SegmentReducer):
     """SegmentReducer whose reductions combine across the mesh.
 
-    Scatter-mode only (the vmap-clean mode, and the one whose raw states
-    are collective-combinable): every segment sum/count psums, min/max
-    pmin/pmax — so `segment_agg_outputs`' finalize phase runs on GLOBAL
-    states and stays byte-for-byte the single-chip code path."""
+    Every raw state is a plain per-shard sum, min or max, so it combines
+    with one collective and `segment_agg_outputs`' finalize phase runs on
+    GLOBAL states, byte-for-byte the single-chip code path.  In 'scatter'
+    mode each segment sum/count psums as it is registered; in 'matmul' (and
+    'pallas') mode the float sums and counts are deferred into ONE
+    ``[domain, K]`` float64 array of sums, which `finish()` psums in one
+    all-reduce.  Integer sums, min and max scatter in every mode and psum /
+    pmin / pmax where they are registered."""
 
-    def __init__(self, gid, domain: int, n_rows: int):
-        super().__init__(gid, domain, "scatter", n_rows)
+    def __init__(self, gid, domain: int, n_rows: int, mode: str = "scatter"):
+        super().__init__(gid, domain, mode, n_rows)
+
+    def finish(self):
+        super().finish()
+        if self._out is not None:
+            self._out = jax.lax.psum(self._out, AXIS)
 
     def _scatter(self, x):
         return jax.lax.psum(super()._scatter(x), AXIS)
@@ -74,12 +85,12 @@ class SpmdAggregate(CompiledAggregate):
     body, mapped per-shard with explicit collective state combines."""
 
     def __init__(self, mesh, agg: p.Aggregate, table: Table, scan, filters,
-                 group_exprs, agg_exprs):
+                 group_exprs, agg_exprs, config=None):
         self.mesh = mesh
-        # config=None keeps segsum_mode "scatter" — the only mode whose raw
-        # states psum/pmin/pmax-combine (and the batcher-vmappable one)
+        # segsum_mode is chosen as on one chip (`choose_segsum_impl`: config,
+        # platform, group domain); config=None keeps "scatter"
         super().__init__(agg, table, scan, filters, group_exprs, agg_exprs,
-                         config=None)
+                         config=config)
         names = table.column_names
         self._wrap = ColumnSpmdWrap(
             self._fn_raw, mesh,
@@ -93,7 +104,7 @@ class SpmdAggregate(CompiledAggregate):
         self._batched_jit = None
 
     def _make_reducer(self, gid, domain: int, n_rows: int) -> SegmentReducer:
-        return SpmdSegmentReducer(gid, domain, n_rows)
+        return SpmdSegmentReducer(gid, domain, n_rows, self.segsum_mode)
 
     def _wrap_for(self, n_params: int) -> ColumnSpmdWrap:
         w = self._wraps.get(n_params)
@@ -117,7 +128,8 @@ class SpmdAggregate(CompiledAggregate):
         args = wrap.pack_args(datas, valids, table.row_valid, params)
         packed = timed_jit_call(
             "spmd_aggregate", wrap.jitted, *args, may_compile=not self._warm,
-            launch_attrs=launch_attrs(self.mesh, table.padded_rows))
+            launch_attrs=launch_attrs(self.mesh, table.padded_rows,
+                                      self.segsum_mode))
         self._warm = True
         tags = self._pack_tags
         host, present = fetch_packed(packed, self.domain)
@@ -144,7 +156,8 @@ class SpmdAggregate(CompiledAggregate):
         packed = timed_jit_call(
             "spmd_aggregate", self._batched_jit, *args,
             may_compile=bucket not in self._warm_batch,
-            launch_attrs=launch_attrs(self.mesh, table.padded_rows))
+            launch_attrs=launch_attrs(self.mesh, table.padded_rows,
+                                      self.segsum_mode))
         self._warm_batch.add(bucket)
         tags = self._pack_tags
         with d2h_fetch(nbytes=int(packed.nbytes)):
@@ -174,13 +187,13 @@ def _bucket_of(key: Tuple) -> Tuple:
 
 
 def _defer_to_background(ctx, mesh, rel, key, table, scan, filters,
-                         group_exprs, agg_exprs, params=()) -> bool:
+                         group_exprs, agg_exprs, config, params=()) -> bool:
     """Background-recompile hook — the shared `defer_rebuild` policy
     (physical/compiled.py) with this rung's constructor; True = deferred."""
 
     def build_and_warm():
         obj = SpmdAggregate(mesh, rel, table, scan, filters, group_exprs,
-                            agg_exprs)
+                            agg_exprs, config)
         obj.run(table, params)  # compile; result discarded
         obj.table = None
         obj._warm = True
@@ -230,18 +243,22 @@ def try_spmd_aggregate(rel: p.Aggregate, executor) -> Optional[Table]:
             tuple(str(f) for f in filters),
             tuple(str(e) for e in group_exprs),
             tuple(str(a) for a in agg_exprs),
+            # the configured segment-sum mode, as on one chip: two modes
+            # are two programs and two families
+            str(executor.config.get("sql.compile.segsum", "auto")),
             table.num_rows,
             table.padded_rows,
         )
 
         def build():
             if _defer_to_background(ctx, mesh, rel, key, table, scan,
-                                    filters, group_exprs, agg_exprs, params):
+                                    filters, group_exprs, agg_exprs,
+                                    executor.config, params):
                 return None  # served on a lower rung this time
             from ..physical.compiled import _remember_family_locked
 
             obj = SpmdAggregate(mesh, rel, table, scan, filters,
-                                group_exprs, agg_exprs)
+                                group_exprs, agg_exprs, executor.config)
             obj.table = None  # never pin the construction table's HBM
             with ctx._plan_lock:
                 _cache[key] = obj
@@ -261,7 +278,8 @@ def try_spmd_aggregate(rel: p.Aggregate, executor) -> Optional[Table]:
 
             trace_event("family_hit", rung="spmd_aggregate",
                         params=len(params))
-        count_launch(ctx.metrics, mesh, table.num_rows)
+        count_launch(ctx.metrics, mesh, table.num_rows,
+                     compiled.segsum_mode)
         from ..resilience import faults
 
         faults.maybe_inject("oom", executor.config)

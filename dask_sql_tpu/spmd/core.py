@@ -15,7 +15,7 @@ compile-histogram accounting as the single-chip rungs.
 from __future__ import annotations
 
 import logging
-from typing import Callable, Sequence, Tuple
+from typing import Callable, Optional, Sequence, Tuple
 
 import jax
 from jax import shard_map
@@ -61,19 +61,28 @@ def raise_rung_fault(rung: str, exc: BaseException):
     raise CompileError(msg) from exc
 
 
-def count_launch(metrics, mesh, rows: int) -> None:
+def count_launch(metrics, mesh, rows: int,
+                 segsum: Optional[str] = None) -> None:
     """One sharded-rung execution in the registry: ``parallel.spmd.launches``
     / ``.rows`` accumulate, ``parallel.spmd.devices`` is the mesh width of
-    the newest one (a gauge)."""
+    the newest one (a gauge).  A rung that reduces segments names its
+    segment-sum mode: ``parallel.spmd.segsum.<mode>`` counts beside it."""
     metrics.inc("parallel.spmd.launches")
+    if segsum is not None:
+        metrics.inc(f"parallel.spmd.segsum.{segsum}")
     metrics.inc("parallel.spmd.rows", rows)
     metrics.gauge("parallel.spmd.devices", int(mesh.devices.size))
 
 
-def launch_attrs(mesh, padded_rows: int) -> dict:
-    """Attrs of a sharded rung's ``launch`` span (`timed_jit_call`)."""
+def launch_attrs(mesh, padded_rows: int,
+                 segsum: Optional[str] = None) -> dict:
+    """Attrs of a sharded rung's ``launch`` span (`timed_jit_call`);
+    ``segsum`` is the segment-sum mode the rung traced, where it has one."""
     ndev = int(mesh.devices.size)
-    return {"devices": ndev, "rows_per_device": int(padded_rows) // ndev}
+    attrs = {"devices": ndev, "rows_per_device": int(padded_rows) // ndev}
+    if segsum is not None:
+        attrs["segsum"] = segsum
+    return attrs
 
 
 def mesh_of_sharded_table(table):

@@ -73,27 +73,33 @@ def captured():
     domains (the blocked matmul) through the existing config key."""
     import chip_smoke
     from dask_sql_tpu import Context
+    from dask_sql_tpu import config as config_module
     from dask_sql_tpu.physical.compiled import CompiledAggregate
     from dask_sql_tpu.spmd.aggregate import SpmdAggregate
 
     df = chip_smoke.gen_lineitem(SMALL_ROWS, seed=0)
     runs, ctors = [], []
     run, init = CompiledAggregate.run, SpmdAggregate.__init__
+    spmd_run = SpmdAggregate.run  # its own: the sharded Q1's parameters
 
     def spy_run(self, table=None, params=()):
         runs.append((self, table, params))
-        return run(self, table, params)
+        return (spmd_run if isinstance(self, SpmdAggregate) else run)(
+            self, table, params)
 
     def spy_init(self, *args, **kwargs):
         ctors.append(args)
         init(self, *args, **kwargs)
 
     out = {}
-    with pytest.MonkeyPatch.context() as mp:
+    # the result cache off for these contexts only: the config is the
+    # process's, and this worker runs other files after this one
+    with pytest.MonkeyPatch.context() as mp, \
+            config_module.set({"serving.cache.enabled": False}):
         mp.setattr(CompiledAggregate, "run", spy_run)
+        mp.setattr(SpmdAggregate, "run", spy_run)
         mp.setattr(SpmdAggregate, "__init__", spy_init)
         c = Context()
-        c.config.update({"serving.cache.enabled": False})
         c.create_table("lineitem", df)
         table = c.schema[c.schema_name].tables["lineitem"].table
         assert {n: str(col.data.dtype) for n, col in table.columns.items()} \
@@ -104,7 +110,6 @@ def captured():
             out[name] = runs[-1]
             assert out[name][0].segsum_mode == "matmul"
         sharded = Context()
-        sharded.config.update({"serving.cache.enabled": False})
         sharded.create_table("lineitem", df, distributed=True)
         sharded.sql(chip_smoke.QUERIES["q1"]).compute()
         assert sharded.metrics.counter("resilience.rung.spmd_aggregate") == 1
@@ -117,7 +122,6 @@ def captured():
         arrays = tpch_lineitem.generate(SMALL_ROWS, seed=29, scale_factor=10)
         query = traffic.load("queries", "tpch_q1")
         cell = Context()
-        cell.config.update({"serving.cache.enabled": False})
         cell.create_table("lineitem",
                           tpch_lineitem.arrow_tables(arrays)["lineitem"],
                           distributed=True)
@@ -193,29 +197,43 @@ def test_compiled_aggregate_fits_one_chip_at_sf10(one_chip, captured, name):
     assert used - args + resident < HBM_BYTES
 
 
-@pytest.mark.parametrize("name, rows", [("spmd_q1", ROWS),
-                                        ("spmd_q1_cell", CELL_ROWS)])
-def test_spmd_aggregate_compiles_for_four_chips(topo, captured, name, rows):
+@pytest.mark.parametrize("name, rows, segsum", [
+    ("spmd_q1", ROWS, "scatter"),
+    ("spmd_q1_cell", CELL_ROWS, "matmul"),
+    ("spmd_q1_cell", CELL_ROWS, "scatter"),
+])  # spmd_q1 in matmul mode (15M rows a shard) compiles too, in 65-107 s
+def test_spmd_aggregate_compiles_for_four_chips(topo, captured, name, rows,
+                                                segsum):
     """The sharded rung as one program over the 2x2 mesh: row-sharded
     columns in, an all-reduce combining the per-shard partial states.  At
     the smoke's 15M rows a shard, and at the 6M rows a shard of the
     benchmark's four-chip cell (Q1 from `perfbench/queries/tpch_q1.json`
-    over the columns it projects from the sixteen)."""
+    over the columns it projects from the sixteen).  ``matmul`` is what the
+    chip resolves ``auto`` to for Q1's domain (`choose_segsum_impl` sees the
+    CPU here, so the test says it through the existing key): the blocked
+    one-hot matmul a shard and ONE all-reduce of its float64 state;
+    ``scatter`` is the program under ``sql.compile.segsum: scatter``."""
     from dask_sql_tpu.parallel.mesh import AXIS
     from dask_sql_tpu.spmd.aggregate import SpmdAggregate
 
-    (_, rel, table, scan, filters, group_exprs, agg_exprs), params = \
+    (_, rel, table, scan, filters, group_exprs, agg_exprs, _), params = \
         captured[name]
     mesh = Mesh(np.array(topo.devices), (AXIS,))
     assert mesh.devices.size == 4
     row_blocks, replicated = NamedSharding(mesh, P(AXIS)), \
         NamedSharding(mesh, P())
     pipeline = SpmdAggregate(mesh, rel, table, scan, filters, group_exprs,
-                             agg_exprs)
+                             agg_exprs, {"sql.compile.segsum": segsum})
+    assert pipeline.segsum_mode == segsum
     wrap = pipeline._wrap_for(len(params))
     datas, valids = _column_shapes(table, rows, row_blocks)
     args = wrap.pack_args(datas, valids, None,
                           _shapes(tuple(params), replicated))
     compiled = wrap.jitted.lower(*args).compile()
-    assert "all-reduce" in compiled.as_text()
+    text = compiled.as_text()
+    # the combine: the float64 psum is an all-reduce, or (emulated as
+    # float32 pairs) an all-gather and a local reduce
+    assert "shard_map/psum" in text
+    assert " all-reduce(" in text or " all-gather(" in text
+    assert (" scatter(" in text) == (segsum == "scatter")
     assert _device_bytes(compiled) < HBM_BYTES  # per device
