@@ -379,7 +379,7 @@ def main(argv=None) -> int:
     references = {}
     names = ["q1"] if args.chips > 1 else ["q1", "q6"]
     want_rung = "spmd_aggregate" if args.chips > 1 else None
-    pipelines = spmd_aggregate._cache if args.chips > 1 else single_chip._cache
+    pipelines = (spmd_aggregate if args.chips > 1 else single_chip).PROGRAMS
     library_rows = {}
     tolerances = {}
     parser = None
@@ -389,13 +389,13 @@ def main(argv=None) -> int:
         emit(phase=f"reference:{name}", seconds=time.perf_counter() - t0)
         for temp in ("cold", "warm"):
             label = f"library:{name}:{temp}"
-            before = set(pipelines)
+            before = set(pipelines.values())
             TRANSFER_STATS["d2h"] = 0
             t0 = time.perf_counter()
             frame = ctx.sql(QUERIES[name])
             got = frame.compute()
             seconds = time.perf_counter() - t0
-            new = [pipelines[k] for k in set(pipelines) - before]
+            new = [p for p in pipelines.values() if p not in before]
             if temp == "cold":
                 checks.check(bool(new), f"{label}: built no compiled pipeline")
                 if parser is None:  # what bound the first query

@@ -432,7 +432,7 @@ class Context:
         #: OrderedDict move_to_end/popitem pair racing across threads
         #: corrupts the LRU order or KeyErrors (self-lint rule DSQL201).
         #: rank 55: nests inside replica write locks; planning/compiles
-        #: happen OUTSIDE it (singleflight in physical/compiled.py)
+        #: happen OUTSIDE it (singleflight in physical/programs.py)
         self._plan_lock = runtime_locks.named_lock("context.plan_cache")
         #: bumped on every view/function (re)definition or drop
         self._catalog_serial = 0
@@ -515,11 +515,11 @@ class Context:
         #: lazily-created background recompiler (serving/background.py);
         #: guarded by _plan_lock — use background_compiler() to read
         self._bg_compiler = None
-        #: plan family ((rung tag, key-minus-bucket) tuple) -> table bucket
+        #: plan family ((rung, family) of a ProgramCache key) -> table bucket
         #: (uid, rows, padded_rows) last compiled by THIS context: a
-        #: plugin-cache miss whose family maps to a DIFFERENT bucket means
+        #: program-cache miss whose family maps to a DIFFERENT bucket means
         #: the table grew/was replaced — the background-recompile trigger
-        #: (physical/compiled.py).  Guarded by _plan_lock.
+        #: (physical/programs.py).  Guarded by _plan_lock.
         self._compiled_families: dict = {}
         #: (family fingerprint, catalog/config key) -> PlanEstimate: the
         #: estimator's intervals are literal-value-agnostic, so one

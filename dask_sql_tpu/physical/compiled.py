@@ -17,8 +17,6 @@ from __future__ import annotations
 
 import logging
 import re
-import threading
-import uuid
 from typing import Callable, Dict, List, Optional, Tuple
 
 import jax
@@ -57,6 +55,7 @@ from ..planner.expressions import (
     transform,
     walk,
 )
+from .programs import ProgramCache
 
 logger = logging.getLogger(__name__)
 
@@ -1263,174 +1262,9 @@ class CompiledAggregate:
         return Table(out, int(present.shape[0]))
 
 
-# LRU of compiled scan->aggregate pipelines (ADVICE r2: bounded, and table
-# refs dropped after each run so stale table versions don't pin HBM)
-_CACHE_CAP = 32
-_cache: "OrderedDict[Tuple, CompiledAggregate]" = __import__(
-    "collections").OrderedDict()
-#: cap on the per-context compiled-family set (context._compiled_families:
-#: a key miss for a SEEN family means the table grew or was replaced, which
-#: is the background-recompile trigger, ISSUE 7 — the query is served
-#: interpreted while the new bucket compiles off-path)
-_FAMILY_CAP = 256
-
-
-def _family_of(key: Tuple) -> Tuple:
-    # drop (uid, num_rows, padded_rows); keep plan shape + segsum mode
-    return ("compiled_aggregate",) + key[1:-3] + (key[-1],)
-
-
-def _bucket_of(key: Tuple) -> Tuple:
-    # the table-identity part the family drops: (uid, num_rows, padded_rows)
-    return (key[0], key[-3], key[-2])
-
-
-#: in-flight constructions, key -> Event: concurrent same-family misses
-#: wait for the first builder instead of paying duplicate XLA compiles
-#: (cold fan-in of a family is exactly the batcher's target workload)
-_building: Dict[Tuple, threading.Event] = {}
-_building_lock = threading.Lock()
-_BUILD_WAIT_S = 300.0
-
-
-def singleflight_begin(key: Tuple):
-    """(is_builder, event) for a compiled-cache miss; a non-builder should
-    ``event.wait`` then re-check the cache.  Builders MUST call
-    `singleflight_done(key)` in a finally."""
-    with _building_lock:
-        ev = _building.get(key)
-        if ev is None:
-            ev = _building[key] = threading.Event()
-            return True, ev
-        return False, ev
-
-
-def singleflight_done(key: Tuple) -> None:
-    with _building_lock:
-        ev = _building.pop(key, None)
-    if ev is not None:
-        ev.set()
-
-
-def singleflight_get_or_build(ctx, cache: "OrderedDict", key: Tuple, build):
-    """THE miss-handling protocol of every compiled-pipeline cache, shared
-    so the three pipelines cannot drift: lock-guarded lookup; on a miss,
-    one builder constructs while concurrent same-key misses wait and
-    reuse; a waiter whose builder failed or declined falls through and
-    builds under its own query's policy.  `build()` constructs, inserts
-    into `cache` and returns the pipeline — or None to decline (e.g. the
-    background-recompile deferral).  Returns (compiled_or_None,
-    built_here): built_here=False means this query REUSED an executable
-    another query paid for (the family-hit accounting hook)."""
-    with ctx._plan_lock:
-        compiled = cache.get(key)
-        if compiled is not None:
-            cache.move_to_end(key)
-            return compiled, False
-    # builder=False means no token was taken; the builder path settles in
-    # the shared finally below — flag-correlated, invisible to the CFG
-    # dsql: allow-unpaired-effect — settled in the finally when builder
-    builder, build_ev = singleflight_begin(key)
-    if not builder:
-        build_ev.wait(_BUILD_WAIT_S)
-        with ctx._plan_lock:
-            compiled = cache.get(key)
-            if compiled is not None:
-                cache.move_to_end(key)
-                return compiled, False
-        # the builder failed or declined; build here so the failure
-        # surfaces under this query's own policy
-        # dsql: allow-unpaired-effect — settled in the finally when builder
-        builder, build_ev = singleflight_begin(key)
-    try:
-        return build(), True
-    finally:
-        if builder:
-            singleflight_done(key)
-
-
-def defer_rebuild(ctx, rung: str, cache, cache_cap: int, key, family,
-                  bucket, build_and_warm) -> bool:
-    """THE background-recompile deferral shared by every compiled-pipeline
-    cache (single-chip and SPMD rungs alike), colocated with the
-    singleflight protocol so the two halves of the miss-handling policy
-    cannot drift: a SEEN family whose table bucket changed (growth /
-    replacement) rebuilds and compiles on the background thread while the
-    triggering query serves on a lower rung, instead of paying a
-    foreground XLA compile on the serving path.
-
-    ``build_and_warm()`` constructs the pipeline, runs it once to compile,
-    drops its table refs, and returns it; it executes under the captured
-    per-query config view and a metrics compile sink.  Returns True when
-    deferred (the caller's build() then declines the rung)."""
-    bg = ctx.background_compiler()
-    if bg is None:
-        return False
-    with ctx._plan_lock:
-        stored = ctx._compiled_families.get(family)
-    if stored is None or stored == bucket:
-        # first sight of the family, or plain LRU eviction of an unchanged
-        # table: foreground compile as before — deferral is only for
-        # actual growth/replacement
-        return False
-    # thread-local per-query config overlays are invisible on the bg
-    # thread; capture the effective view so the rebuild matches its key
-    effective = dict(ctx.config.effective_items())
-    # causality: the background recompile points back at the query whose
-    # plugin-cache miss triggered it — a flow link from the trigger's
-    # deferral event into the recompile span the bg thread appends, plus
-    # a flight-recorder event carrying the trigger's qid
-    from ..observability import current_trace
-
-    trigger_trace = current_trace()
-    flow_id = f"bg:{rung}:{uuid.uuid4().hex[:12]}"
-
-    def task():
-        import time as _time
-
-        t0 = _time.perf_counter()
-        try:
-            from .. import observability
-
-            with ctx.config.set(effective), \
-                    observability.compile_sink(ctx.metrics):
-                obj = build_and_warm()
-            with ctx._plan_lock:
-                cache[key] = obj
-                while len(cache) > cache_cap:
-                    cache.popitem(last=False)
-                _remember_family_locked(ctx, family, bucket)
-            observability.flight.record(
-                "bg.recompile", rung=rung,
-                qid=trigger_trace.qid if trigger_trace is not None
-                else None)
-            if trigger_trace is not None:
-                # append the recompile to the TRIGGERING query's trace (it
-                # may already be finished — spans still append), with the
-                # flow arrow from its deferral event
-                trigger_trace.add_span(
-                    f"bg_recompile:{rung}", t0, _time.perf_counter(),
-                    kind="detail", parent="execute", rung=rung,
-                    flow_in=flow_id)
-        except BaseException:
-            # un-mark the family: the next query takes the foreground path
-            # where the ladder/breaker apply their normal failure policy
-            with ctx._plan_lock:
-                ctx._compiled_families.pop(family, None)
-            raise
-
-    task_key = (rung, key)
-    # while the compile is pending, every query of the family keeps
-    # declining (still served on a lower rung) instead of compiling anyway
-    if not bg.pending(task_key) and not bg.submit(task_key, task):
-        return False
-    ctx.metrics.inc("serving.bg_compile.deferred")
-    from ..observability import trace_event
-
-    trace_event(f"bg_compile_deferred:{rung}", flow_out=flow_id)
-    logger.debug("%s family bucket changed; compiling in background and "
-                 "serving a lower rung", rung)
-    return True
+# compiled scan->aggregate programs; bounded, and table refs are dropped at
+# construction so stale table versions don't pin HBM (ADVICE r2)
+PROGRAMS = ProgramCache("compiled_aggregate", 32)
 
 
 def try_compiled_aggregate(rel: p.Aggregate, executor) -> Optional[Table]:
@@ -1458,63 +1292,39 @@ def try_compiled_aggregate(rel: p.Aggregate, executor) -> Optional[Table]:
         filters = [pz.rewrite(f) for f in filters]
         agg_exprs = [pz.rewrite_agg(a) for a in agg_exprs]
         params = pz.params
-        key = (
-            dc.uid,
+        family = (
             scan.schema_name, scan.table_name,
             tuple(scan.projection or ()),
             tuple(str(f) for f in filters),
             tuple(str(e) for e in group_exprs),
             tuple(str(a) for a in agg_exprs),
-            table.num_rows,
-            table.padded_rows,
+            # two configured segment-sum modes are two programs
+            str(executor.config.get("sql.compile.segsum", "auto")),
         )
-        mode = str(executor.config.get("sql.compile.segsum", "auto"))
-        key = key + (mode,)
-        # the plugin cache (and the background compiler's swap) are guarded
-        # by the plan-cache lock: server worker threads share these dicts;
-        # concurrent cold misses of one family single-flight the build
-        def build():
-            if _defer_to_background(ctx, rel, key, table, scan, filters,
-                                    group_exprs, agg_exprs,
-                                    executor.config, params):
-                return None  # served on a lower rung this time
+        bucket = (dc.uid, table.num_rows, table.padded_rows)
+
+        def construct():
             obj = CompiledAggregate(rel, table, scan, filters, group_exprs,
                                     agg_exprs, executor.config)
             # cached pipelines must not pin the construction table's HBM
             obj.table = None
-            with ctx._plan_lock:
-                _cache[key] = obj
-                while len(_cache) > _CACHE_CAP:
-                    _cache.popitem(last=False)
-                _remember_family_locked(ctx, _family_of(key),
-                                        _bucket_of(key))
             return obj
 
-        compiled, built_here = singleflight_get_or_build(ctx, _cache, key,
-                                                         build)
+        compiled, built_here = PROGRAMS.get_or_build(
+            ctx, family, bucket, construct,
+            warm=lambda obj: obj.run(table, params), params=params)
         if compiled is None:
             return None  # deferred to the background compiler
-        if not built_here and params:
-            # executable reuse across literals: the family discipline at work
-            ctx.metrics.inc("families.hit")
-            from ..observability import trace_event
-
-            trace_event("family_hit", rung="compiled_aggregate",
-                        params=len(params))
         if built_here and compiled.codespace_preds:
             ctx.metrics.inc("columnar.encoding.codespace_pred",
                             compiled.codespace_preds)
         from ..resilience import faults
 
         faults.maybe_inject("oom", executor.config)
-        batcher = families.batcher_of(ctx)
-        if batcher is not None and params and compiled.batchable:
-            result = batcher.run(
-                ("compiled_aggregate",) + key, params,
-                solo=lambda: compiled.run(table, params),
-                batched=lambda members: compiled.run_batched(table, members))
-        else:
-            result = compiled.run(table, params)
+        result = PROGRAMS.run(
+            ctx, family, bucket, compiled, params,
+            solo=lambda: compiled.run(table, params),
+            batched=lambda members: compiled.run_batched(table, members))
         if compiled.has_encoded:
             # late materialization: only the group table's rows ever decode
             ctx.metrics.inc("columnar.encoding.late_rows", result.num_rows)
@@ -1522,35 +1332,3 @@ def try_compiled_aggregate(rel: p.Aggregate, executor) -> Optional[Table]:
     except _Unsupported as e:
         logger.debug("compiled pipeline unsupported: %s", e)
         return None
-
-
-def _remember_family_locked(ctx, family: Tuple, bucket: Tuple) -> None:
-    """Record a compiled plan family -> table bucket on the context
-    (caller holds the plan lock); bounded crudely — family memory is an
-    optimization hint only.  The bucket is the growth EVIDENCE: a later
-    cache miss defers to background only when the table identity actually
-    changed, so plain LRU eviction of an unchanged plan recompiles in the
-    foreground as before instead of being misread as growth."""
-    if len(ctx._compiled_families) >= _FAMILY_CAP:
-        ctx._compiled_families.clear()
-    ctx._compiled_families[family] = bucket
-
-
-def _defer_to_background(ctx, rel, key, table, scan, filters, group_exprs,
-                         agg_exprs, config, params=()) -> bool:
-    """Background-recompile hook: the shared `defer_rebuild` policy with
-    this rung's constructor.  Returns True when deferred (the query is
-    served interpreted this time)."""
-
-    def build_and_warm():
-        obj = CompiledAggregate(rel, table, scan, filters, group_exprs,
-                                agg_exprs, config)
-        # compiles every kernel with the triggering query's params as
-        # runtime args; result discarded
-        obj.run(table, params)
-        obj.table = None
-        obj._warm = True
-        return obj
-
-    return defer_rebuild(ctx, "compiled_aggregate", _cache, _CACHE_CAP, key,
-                         _family_of(key), _bucket_of(key), build_and_warm)

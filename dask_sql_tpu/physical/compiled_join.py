@@ -56,6 +56,7 @@ from .compiled import (
     decode_radix_group_key,
     segment_agg_outputs,
 )
+from .programs import ProgramCache
 
 logger = logging.getLogger(__name__)
 
@@ -607,19 +608,11 @@ def _plan_nodes(node):
         yield from _plan_nodes(k)
 
 
-# LRU of compiled pipelines; entries keep device-resident LUTs + string
-# dictionaries warm across runs of the same table versions.  Capped so stale
-# table versions can't pin HBM forever (ADVICE r2); probe/build table refs
-# are dropped after every run (re-bound on each call).
-_CACHE_CAP = 16
-_cache: "OrderedDict[tuple, CompiledJoinAggregate]" = __import__(
-    "collections").OrderedDict()
-#: plan shapes known ineligible — checked before any build-side execution.
-#: Keys carry per-version table uids, so long sessions with refreshed tables
-#: would grow it forever; reset wholesale at a small cap (re-declining is
-#: cheap — one plan walk)
-_DECLINED_CAP = 256
-_declined: set = set()
+# entries keep device-resident LUTs + string dictionaries warm across runs
+# of the same table versions; capped so stale table versions can't pin HBM
+# forever (ADVICE r2); probe/build table refs are dropped after every run
+# (re-bound on each call)
+PROGRAMS = ProgramCache("compiled_join_aggregate", 16)
 
 
 def try_compiled_join_aggregate(rel: p.Aggregate, executor) -> Optional[Table]:
@@ -657,7 +650,7 @@ def try_compiled_join_aggregate(rel: p.Aggregate, executor) -> Optional[Table]:
                         return None
                     uids.append(bdc.uid)
         decline_key = (tuple(uids), str(rel))
-        if decline_key in _declined:
+        if PROGRAMS.declined(decline_key):
             return None
         # cheap plan-only checks BEFORE any build-side execution (ADVICE r2:
         # an ineligible query used to pay for its build subtrees twice)
@@ -682,8 +675,7 @@ def try_compiled_join_aggregate(rel: p.Aggregate, executor) -> Optional[Table]:
         # build sides run through the normal recursive converter (they may
         # be filtered scans, nested joins, anything) — compacted eagerly
         build_tables = [executor.execute(j["plan"]) for j in ext.joins]
-        key = (
-            tuple(uids),
+        family = (
             ext.scan.schema_name, ext.scan.table_name,
             tuple(ext.scan.projection or ()),
             tuple(repr(j["plan"]) for j in ext.joins),
@@ -692,34 +684,21 @@ def try_compiled_join_aggregate(rel: p.Aggregate, executor) -> Optional[Table]:
             tuple(str(e) for e in group_exprs),
             tuple(str(a) for a in agg_exprs),
             tuple((f.name, f.sql_type) for f in rel.schema),
-            probe_table.num_rows,
-            probe_table.padded_rows,
-            tuple(bt.num_rows for bt in build_tables),
         )
-        from .compiled import singleflight_get_or_build
-
+        bucket = (tuple(uids), probe_table.num_rows, probe_table.padded_rows,
+                  tuple(bt.num_rows for bt in build_tables))
         ctx = executor.context
-
-        def build():
-            obj = CompiledJoinAggregate(rel, ext, group_exprs, agg_exprs,
-                                        probe_table, build_tables, executor)
-            with ctx._plan_lock:
-                _cache[key] = obj
-                while len(_cache) > _CACHE_CAP:
-                    _cache.popitem(last=False)
-            return obj
-
-        compiled, built_here = singleflight_get_or_build(ctx, _cache, key,
-                                                         build)
+        # the constructor binds the tables this first run reads; the finally
+        # below drops them.  No `warm`: this rung never defers
+        compiled, built_here = PROGRAMS.get_or_build(
+            ctx, family, bucket,
+            lambda: CompiledJoinAggregate(rel, ext, group_exprs, agg_exprs,
+                                          probe_table, build_tables,
+                                          executor),
+            params=params)
         if not built_here:
             compiled.probe_table = probe_table
             compiled.build_tables = build_tables
-            if params:
-                ctx.metrics.inc("families.hit")
-                from ..observability import trace_event
-
-                trace_event("family_hit", rung="compiled_join_aggregate",
-                            params=len(params))
         if built_here and compiled.codespace_preds:
             ctx.metrics.inc("columnar.encoding.codespace_pred",
                             compiled.codespace_preds)
@@ -739,7 +718,5 @@ def try_compiled_join_aggregate(rel: p.Aggregate, executor) -> Optional[Table]:
     except _Unsupported as e:
         logger.debug("compiled join pipeline unsupported: %s", e)
         if "decline_key" in locals():
-            if len(_declined) >= _DECLINED_CAP:
-                _declined.clear()
-            _declined.add(decline_key)
+            PROGRAMS.decline(decline_key)
         return None

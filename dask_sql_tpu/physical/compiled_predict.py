@@ -30,8 +30,7 @@ decline here and keep today's behavior.
 from __future__ import annotations
 
 import logging
-from collections import OrderedDict
-from typing import List, Optional, Tuple
+from typing import List, NamedTuple, Optional, Tuple
 
 import jax.numpy as jnp
 
@@ -39,8 +38,14 @@ from ..columnar.dtypes import STRING_TYPES
 from ..columnar.table import Table
 from ..planner import plan as p
 from ..planner.expressions import ColumnRef
-from .compiled import PARAMS_SLOT, _Unsupported, singleflight_get_or_build
-from .compiled_select import CompiledSelect, _extract, resolve_pipeline_inputs
+from .compiled import PARAMS_SLOT, _Unsupported
+from .compiled_select import (
+    CompiledSelect,
+    _extract,
+    resolve_pipeline_inputs,
+    select_family,
+)
+from .programs import ProgramCache
 
 logger = logging.getLogger(__name__)
 
@@ -145,43 +150,28 @@ class CompiledPredict(CompiledSelect):
         return self._param_base
 
 
-# bounded pipeline cache, keyed on (family identity, model SHAPE) — the
-# same singleflight protocol as the other compiled rungs
-_CACHE_CAP = 16
-_cache: "OrderedDict[Tuple, CompiledPredict]" = OrderedDict()
+class PredictFamily(NamedTuple):
+    """What shapes a fused PREDICT program: the model by NAME and SHAPE
+    (never its weights: a retrain of the same shape rides the same
+    executable) and the select chain under it."""
+    schema: str
+    model: str
+    shape_key: Tuple
+    feature_slots: Tuple[int, ...]
+    select: Tuple
 
 
-def _family_of(key: Tuple) -> Tuple:
-    """Plan family = cache key minus (uid, num_rows, padded_rows) — the
-    compiled_select convention: a miss for a family this context already
-    compiled under a DIFFERENT bucket means the table grew/was replaced
-    (the background-recompile trigger)."""
-    return ("compiled_predict",) + key[2:-2]
-
-
-def _bucket_of(key: Tuple) -> Tuple:
-    return (key[1], key[-2], key[-1])  # (uid, num_rows, padded_rows)
+PROGRAMS = ProgramCache("compiled_predict", 16)
 
 
 def drop_model_pipelines(context, schema_name: str, name: str) -> None:
     """Evict every cached pipeline built for a model (DROP MODEL, via
     inference.invalidate): a dropped model's executables must not outlive
-    its ledger entry.  Key layout: key[2] = schema, key[3] = model.
-    Matching ignores dc.uid, so a same-named model in ANOTHER context
-    over-evicts (costs that context one recompile, never correctness).
-    The snapshot retries if a concurrent insert under a different
-    context's plan lock mutates the dict mid-iteration."""
-    with context._plan_lock:
-        stale: List[Tuple] = []
-        for _ in range(8):
-            try:
-                stale = [k for k in _cache
-                         if k[2] == schema_name and k[3] == name]
-                break
-            except RuntimeError:  # another context's insert raced us
-                continue
-        for k in stale:
-            _cache.pop(k, None)
+    its ledger entry.  Matching ignores the table's uid, so a same-named
+    model in ANOTHER context over-evicts (costs that context one
+    recompile, never correctness)."""
+    PROGRAMS.evict(context, lambda family, bucket:
+                   family.schema == schema_name and family.model == name)
 
 
 def try_compiled_predict(root, executor) -> Optional[Table]:
@@ -223,34 +213,22 @@ def try_compiled_predict(root, executor) -> Optional[Table]:
         # literals in the PREDICT input become runtime parameters, so
         # every literal variant — and every retrain of the same model
         # shape — shares ONE executable
-        from .. import families
-
         resolved = resolve_pipeline_inputs(scan, upper_filters, proj,
                                            executor)
         if resolved is None:
             return None
         dc, table, p_upper, p_scan_flts, p_exprs, params = resolved
-        key = (
-            "predict",
-            dc.uid,
-            schema_name, model_name,
-            program.shape_key,
-            tuple(feature_slots),
-            tuple(scan.projection or ()),
-            tuple(str(f) for f in p_upper),
-            tuple(str(f) for f in p_scan_flts),
-            tuple(str(e) for e in p_exprs),
-            tuple(str(k.expr) + str(k.ascending) + str(k.nulls_first)
-                  for k in sort_keys) if sort_keys else None,
-            sort_fetch,
-            limit,
-            inner_limit,
-            table.num_rows,
-            table.padded_rows,
-        )
+        family = PredictFamily(
+            schema_name, model_name, program.shape_key, tuple(feature_slots),
+            select_family(scan, p_upper, p_scan_flts, p_exprs, sort_keys,
+                          sort_fetch, limit, inner_limit))
+        bucket = (dc.uid, table.num_rows, table.padded_rows)
         target_field = predict.schema[-1]
+        # the CURRENT program's params every launch: a swapped model rides
+        # the same executable with fresh (same-shaped) weights
+        run_params = tuple(params) + tuple(program.params)
 
-        def make():
+        def construct():
             obj = CompiledPredict(table, scan, p_upper, p_scan_flts, proj,
                                   p_exprs, sort_keys, sort_fetch, limit,
                                   inner_limit, params, program,
@@ -258,55 +236,24 @@ def try_compiled_predict(root, executor) -> Optional[Table]:
             obj.table = None  # never pin the construction table's HBM
             return obj
 
-        def build():
-            # bucket growth/replacement of a SEEN family recompiles on the
-            # background thread (this query serves on the host tier this
-            # once) — the same defer_rebuild policy as the sibling rungs
-            from .compiled import _remember_family_locked, defer_rebuild
-
-            def build_and_warm():
-                obj = make()
-                obj.run(table, tuple(params) + tuple(program.params))
-                return obj
-
-            if defer_rebuild(ctx, "compiled_predict", _cache, _CACHE_CAP,
-                             key, _family_of(key), _bucket_of(key),
-                             build_and_warm):
-                return None  # served on the host tier this time
-            obj = make()
-            with ctx._plan_lock:
-                _cache[key] = obj
-                while len(_cache) > _CACHE_CAP:
-                    _cache.popitem(last=False)
-                _remember_family_locked(ctx, _family_of(key),
-                                        _bucket_of(key))
-            return obj
-
-        compiled, built_here = singleflight_get_or_build(ctx, _cache, key,
-                                                         build)
+        # bucket growth/replacement of a SEEN family recompiles on the
+        # background thread (this query serves on the host tier this once)
+        compiled, _ = PROGRAMS.get_or_build(
+            ctx, family, bucket, construct,
+            warm=lambda obj: obj.run(table, run_params), params=params)
         if compiled is None:
             return None
         from ..observability import trace_event
-
-        if not built_here and params:
-            ctx.metrics.inc("families.hit")
-            trace_event("family_hit", rung="compiled_predict",
-                        params=len(params))
         from ..resilience import faults
 
         faults.maybe_inject("oom", config)
-        # the CURRENT program's params every launch: a swapped model rides
-        # the same executable with fresh (same-shaped) weights
-        run_params = tuple(params) + tuple(program.params)
-        batcher = families.batcher_of(ctx)
-        if batcher is not None and params:
-            result = batcher.run(
-                ("compiled_predict",) + key, run_params,
-                solo=lambda: compiled.run(table, run_params),
-                batched=lambda members: compiled.run_batched(table,
-                                                             members))
-        else:
-            result = compiled.run(table, run_params)
+        # batched only when the FAMILY has literals to stack (the weight
+        # tail alone is no reason to rendezvous)
+        result = PROGRAMS.run(
+            ctx, family, bucket, compiled, run_params,
+            solo=lambda: compiled.run(table, run_params),
+            batched=(lambda members: compiled.run_batched(table, members))
+            if params else None)
         if compiled.has_encoded:
             ctx.metrics.inc("columnar.encoding.late_rows", result.num_rows)
         if outer is not None:

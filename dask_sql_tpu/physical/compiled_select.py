@@ -24,7 +24,6 @@ one pandas kernel per operator; this is the TPU-native replacement.
 from __future__ import annotations
 
 import logging
-from collections import OrderedDict
 from typing import Dict, List, Optional, Tuple
 
 import jax
@@ -44,8 +43,8 @@ from .compiled import (
     check_no_rle,
     count_codespace_predicates,
     pack_flat,
-    singleflight_get_or_build,
 )
+from .programs import ProgramCache
 
 logger = logging.getLogger(__name__)
 
@@ -453,19 +452,7 @@ def _dictionary_sorted(dic) -> bool:
     return bool(all(str(a[i]) <= str(a[i + 1]) for i in range(len(a) - 1)))
 
 
-_CACHE_CAP = 32
-_cache: "OrderedDict[Tuple, CompiledSelect]" = OrderedDict()
-def _family_of(key: Tuple) -> Tuple:
-    """Plan family = cache key minus (uid, num_rows, padded_rows): a miss
-    for a family the context already compiled under a DIFFERENT table
-    bucket means the table grew or was replaced — the background-recompile
-    trigger (see physical/compiled.py for the pattern; family -> bucket
-    lives on context._compiled_families)."""
-    return ("compiled_select",) + key[1:-2]
-
-
-def _bucket_of(key: Tuple) -> Tuple:
-    return (key[0], key[-2], key[-1])  # (uid, num_rows, padded_rows)
+PROGRAMS = ProgramCache("compiled_select", 32)
 
 
 def resolve_pipeline_inputs(scan, upper_filters, proj, executor):
@@ -507,6 +494,23 @@ def resolve_pipeline_inputs(scan, upper_filters, proj, executor):
     return dc, table, p_upper, p_scan_flts, p_exprs, pz.params
 
 
+def select_family(scan, p_upper, p_scan_flts, p_exprs, sort_keys, sort_fetch,
+                  limit, inner_limit) -> Tuple:
+    """What shapes a root select chain's program (shared with the fused
+    PREDICT rung): the parameterised plan text and the static windows."""
+    return (
+        tuple(scan.projection or ()),
+        tuple(str(f) for f in p_upper),
+        tuple(str(f) for f in p_scan_flts),
+        tuple(str(e) for e in p_exprs),
+        tuple(str(k.expr) + str(k.ascending) + str(k.nulls_first)
+              for k in sort_keys) if sort_keys else None,
+        sort_fetch,
+        limit,
+        inner_limit,
+    )
+
+
 def try_compiled_select(root, executor) -> Optional[Table]:
     """Attempt the one-kernel/one-transfer path for a ROOT select chain."""
     mode = executor.config.get("sql.compile.select", True)
@@ -517,73 +521,40 @@ def try_compiled_select(root, executor) -> Optional[Table]:
         return None
     scan, upper_filters, proj, sort_keys, sort_fetch, limit, inner_limit = got
     try:
-        from .. import families
-
         resolved = resolve_pipeline_inputs(scan, upper_filters, proj,
                                            executor)
         if resolved is None:
             return None
         dc, table, p_upper, p_scan_flts, p_exprs, params = resolved
-        key = (
-            dc.uid,
-            tuple(scan.projection or ()),
-            tuple(str(f) for f in p_upper),
-            tuple(str(f) for f in p_scan_flts),
-            tuple(str(e) for e in p_exprs),
-            tuple(str(k.expr) + str(k.ascending) + str(k.nulls_first)
-                  for k in sort_keys) if sort_keys else None,
-            sort_fetch,
-            limit,
-            inner_limit,
-            table.num_rows,
-            table.padded_rows,
-        )
+        family = select_family(scan, p_upper, p_scan_flts, p_exprs, sort_keys,
+                               sort_fetch, limit, inner_limit)
+        bucket = (dc.uid, table.num_rows, table.padded_rows)
         ctx = executor.context
 
-        def build():
-            if _defer_to_background(ctx, key, table, scan, p_upper,
-                                    p_scan_flts, proj, p_exprs, sort_keys,
-                                    sort_fetch, limit, inner_limit, params):
-                return None  # served on a lower rung this time
+        def construct():
             obj = CompiledSelect(table, scan, p_upper, p_scan_flts, proj,
                                  p_exprs, sort_keys, sort_fetch, limit,
                                  inner_limit, params)
             # cached pipelines must not pin the construction table's HBM
             obj.table = None
-            from .compiled import _remember_family_locked
-
-            with ctx._plan_lock:
-                _cache[key] = obj
-                while len(_cache) > _CACHE_CAP:
-                    _cache.popitem(last=False)
-                _remember_family_locked(ctx, _family_of(key),
-                                        _bucket_of(key))
             return obj
 
-        compiled, built_here = singleflight_get_or_build(ctx, _cache, key,
-                                                         build)
+        # the warming run compiles the mask + the first gather
+        compiled, built_here = PROGRAMS.get_or_build(
+            ctx, family, bucket, construct,
+            warm=lambda obj: obj.run(table, params), params=params)
         if compiled is None:
             return None  # deferred to the background compiler
-        if not built_here and params:
-            ctx.metrics.inc("families.hit")
-            from ..observability import trace_event
-
-            trace_event("family_hit", rung="compiled_select",
-                        params=len(params))
         if built_here and compiled.codespace_preds:
             ctx.metrics.inc("columnar.encoding.codespace_pred",
                             compiled.codespace_preds)
         from ..resilience import faults
 
         faults.maybe_inject("oom", executor.config)
-        batcher = families.batcher_of(ctx)
-        if batcher is not None and params:
-            result = batcher.run(
-                ("compiled_select",) + key, params,
-                solo=lambda: compiled.run(table, params),
-                batched=lambda members: compiled.run_batched(table, members))
-        else:
-            result = compiled.run(table, params)
+        result = PROGRAMS.run(
+            ctx, family, bucket, compiled, params,
+            solo=lambda: compiled.run(table, params),
+            batched=lambda members: compiled.run_batched(table, members))
         if compiled.has_encoded:
             # late materialization: only surviving rows decoded (in the
             # per-bucket gather), and only at the root
@@ -597,23 +568,3 @@ def try_compiled_select(root, executor) -> Optional[Table]:
         # query — the eager converters are always correct
         logger.debug("compiled select declined: %s", e)
         return None
-
-
-def _defer_to_background(ctx, key, table, scan, upper_filters, scan_filters,
-                         proj, proj_exprs, sort_keys, sort_fetch, limit,
-                         inner_limit, params=()) -> bool:
-    """Background-recompile hook for root select chains: the shared
-    `defer_rebuild` policy (physical/compiled.py) with this rung's
-    constructor.  Returns True when deferred."""
-    from .compiled import defer_rebuild
-
-    def build_and_warm():
-        obj = CompiledSelect(table, scan, upper_filters, scan_filters,
-                             proj, proj_exprs, sort_keys, sort_fetch,
-                             limit, inner_limit, params)
-        obj.run(table, params)  # compiles mask + first gather
-        obj.table = None
-        return obj
-
-    return defer_rebuild(ctx, "compiled_select", _cache, _CACHE_CAP, key,
-                         _family_of(key), _bucket_of(key), build_and_warm)

@@ -87,64 +87,43 @@ class Executor:
         # (family, compiled_predict) breaker entity, stepping down to the
         # host predict path (PredictModelPlugin) below
         predict_root = root_has_predict(rel)
-        if self.config.get("resilience.ladder.enabled", True):
-            if predict_root:
-                out = ladder.attempt(
-                    self, "compiled_predict",
-                    lambda: try_compiled_predict(rel, self),
-                    rel=rel, inject_site="predict")
-                if out is not None:
-                    return out
-            if streamed_mark:
-                from ..streaming import try_streamed_select
-
-                out = ladder.attempt(
-                    self, "streamed_select",
-                    lambda: try_streamed_select(rel, self), rel=rel)
-                if out is not None:
-                    return out
-            if sharded:
-                # the SPMD rung sits above the single-chip one (which
-                # declines sharded tables); its failures degrade and
-                # breaker-charge per (family, spmd_select) without
-                # poisoning the family's single-chip rung
-                out = ladder.attempt(
-                    self, "spmd_select",
-                    lambda: try_spmd_select(rel, self),
-                    rel=rel, inject_site="spmd")
-                if out is not None:
-                    return out
-            out = ladder.attempt(
-                self, "compiled_select",
-                lambda: try_compiled_select(rel, self),
-                rel=rel, inject_site="compile")
-            if out is not None:
-                return out
-            return ladder.execute_interpreted(self, rel)
-        # ladder disabled: injection sites still fire (a forced compile
-        # fault must propagate here — that is what disabling proves)
+        # each rung is named once: with `resilience.ladder.enabled` false
+        # `ladder.attempt` still fires the injection site and calls the
+        # rung, and absorbs nothing (a forced compile fault must propagate
+        # then: that is what disabling proves)
         if predict_root:
-            faults.maybe_inject("predict", self.config)
-            out = try_compiled_predict(rel, self)
+            out = ladder.attempt(
+                self, "compiled_predict",
+                lambda: try_compiled_predict(rel, self),
+                rel=rel, inject_site="predict")
             if out is not None:
                 return out
         if streamed_mark:
             from ..streaming import try_streamed_select
 
-            out = try_streamed_select(rel, self)
+            out = ladder.attempt(
+                self, "streamed_select",
+                lambda: try_streamed_select(rel, self), rel=rel)
             if out is not None:
                 return out
         if sharded:
-            faults.maybe_inject("spmd", self.config)
-            out = try_spmd_select(rel, self)
+            # the SPMD rung sits above the single-chip one (which
+            # declines sharded tables); its failures degrade and
+            # breaker-charge per (family, spmd_select) without
+            # poisoning the family's single-chip rung
+            out = ladder.attempt(
+                self, "spmd_select",
+                lambda: try_spmd_select(rel, self),
+                rel=rel, inject_site="spmd")
             if out is not None:
                 return out
-        faults.maybe_inject("compile", self.config)
-        out = try_compiled_select(rel, self)
+        out = ladder.attempt(
+            self, "compiled_select",
+            lambda: try_compiled_select(rel, self),
+            rel=rel, inject_site="compile")
         if out is not None:
             return out
-        faults.maybe_inject("exec_oom", self.config)
-        return self.execute(rel)
+        return ladder.execute_interpreted(self, rel)
 
     def execute(self, rel: LogicalPlan) -> Table:
         # cooperative cancellation checkpoint: a query past its serving

@@ -310,6 +310,9 @@ def execute_interpreted(executor, rel):
     migrate committed buffers on default_device), so the rung fully
     rescues capacity-ladder/compile-shape failures and partially rescues
     allocation OOMs; if the rerun fails again, that failure propagates."""
+    if not executor.config.get("resilience.ladder.enabled", True):
+        faults.maybe_inject("exec_oom", executor.config)
+        return executor.execute(rel)
     try:
         faults.maybe_inject("exec_oom", executor.config)
         return executor.execute(rel)

@@ -1,14 +1,14 @@
 """Background recompilation: ladder recompiles off the critical path.
 
-The compiled planners cache their pipelines keyed on (table uid, row
-bucket, plan shape) — when a table is replaced or grows past its pow2
-bucket, the key misses and the next query pays a full foreground XLA
-compile on the serving path.  This module moves that recompile off the
-critical path: when a *known plan family* (same shape, new bucket) misses,
-the query is served on the interpreted rung while a bounded background
-thread rebuilds and compiles the new pipeline, then swaps it into the
-plugin cache atomically under the plan-cache lock (`Context._plan_lock`).
-Subsequent queries hit the fresh executable.
+The compiled rungs cache their programs keyed on the pair (family, bucket)
+(physical/programs.py: plan shape; table uid and row bucket) — when a table
+is replaced or grows past its pow2 bucket, the key misses and the next
+query pays a full foreground XLA compile on the serving path.  This module
+moves that recompile off the critical path: when a *known plan family*
+(same shape, new bucket) misses, the query is served on the interpreted
+rung while a bounded background thread rebuilds and compiles the new
+pipeline, then swaps it into the rung's `ProgramCache` under the plan-cache
+lock (`Context._plan_lock`).  Subsequent queries hit the fresh executable.
 
 Discipline: one daemon thread, a bounded pending queue (past the bound
 submissions are dropped and the query simply compiles in the foreground

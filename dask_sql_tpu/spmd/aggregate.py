@@ -24,7 +24,6 @@ launches vmap over the leading parameter axis of the same SPMD program.
 from __future__ import annotations
 
 import logging
-from collections import OrderedDict
 from typing import Dict, List, Optional, Tuple
 
 import jax
@@ -37,10 +36,9 @@ from ..physical.compiled import (
     SegmentReducer,
     _extract_chain,
     _Unsupported,
-    defer_rebuild,
     fetch_packed,
-    singleflight_get_or_build,
 )
+from ..physical.programs import ProgramCache
 from ..planner import plan as p
 from .core import (ColumnSpmdWrap, count_launch, launch_attrs, mesh_key,
                    mesh_of_sharded_table, raise_rung_fault, rung_enabled)
@@ -54,11 +52,14 @@ class SpmdSegmentReducer(SegmentReducer):
     Every raw state is a plain per-shard sum, min or max, so it combines
     with one collective and `segment_agg_outputs`' finalize phase runs on
     GLOBAL states, byte-for-byte the single-chip code path.  In 'scatter'
-    mode each segment sum/count psums as it is registered; in 'matmul' (and
-    'pallas') mode the float sums and counts are deferred into ONE
-    ``[domain, K]`` float64 array of sums, which `finish()` psums in one
-    all-reduce.  Integer sums, min and max scatter in every mode and psum /
-    pmin / pmax where they are registered."""
+    mode each segment sum/count psums as it is registered; in 'matmul' mode
+    (what `auto` resolves to on a TPU for small group domains) the float
+    sums and counts are deferred into ONE ``[domain, K]`` float64 array of
+    sums, which `finish()` psums in one all-reduce.  `auto` never resolves
+    to 'pallas'; set by hand it would take the deferred path too, which no
+    test and no cell runs on a mesh (ROADMAP D1, S3g).  Integer sums, min
+    and max scatter in every mode and psum / pmin / pmax where they are
+    registered."""
 
     def __init__(self, gid, domain: int, n_rows: int, mode: str = "scatter"):
         super().__init__(gid, domain, mode, n_rows)
@@ -171,36 +172,7 @@ class SpmdAggregate(CompiledAggregate):
         return out
 
 
-# bounded cache of compiled SPMD aggregate pipelines, keyed like the
-# single-chip cache plus the mesh device tuple
-_CACHE_CAP = 16
-_cache: "OrderedDict[Tuple, SpmdAggregate]" = OrderedDict()
-
-
-def _family_of(key: Tuple) -> Tuple:
-    # drop table identity: uid (index 2) and the trailing row buckets
-    return key[:2] + key[3:-2]
-
-
-def _bucket_of(key: Tuple) -> Tuple:
-    return (key[2], key[-2], key[-1])  # (uid, num_rows, padded_rows)
-
-
-def _defer_to_background(ctx, mesh, rel, key, table, scan, filters,
-                         group_exprs, agg_exprs, config, params=()) -> bool:
-    """Background-recompile hook — the shared `defer_rebuild` policy
-    (physical/compiled.py) with this rung's constructor; True = deferred."""
-
-    def build_and_warm():
-        obj = SpmdAggregate(mesh, rel, table, scan, filters, group_exprs,
-                            agg_exprs, config)
-        obj.run(table, params)  # compile; result discarded
-        obj.table = None
-        obj._warm = True
-        return obj
-
-    return defer_rebuild(ctx, "spmd_aggregate", _cache, _CACHE_CAP, key,
-                         _family_of(key), _bucket_of(key), build_and_warm)
+PROGRAMS = ProgramCache("spmd_aggregate", 16)
 
 
 def try_spmd_aggregate(rel: p.Aggregate, executor) -> Optional[Table]:
@@ -234,10 +206,8 @@ def try_spmd_aggregate(rel: p.Aggregate, executor) -> Optional[Table]:
         filters = [pz.rewrite(f) for f in filters]
         agg_exprs = [pz.rewrite_agg(a) for a in agg_exprs]
         params = pz.params
-        key = (
-            "spmd_aggregate",
+        family = (
             mesh_key(mesh),
-            dc.uid,
             scan.schema_name, scan.table_name,
             tuple(scan.projection or ()),
             tuple(str(f) for f in filters),
@@ -246,52 +216,29 @@ def try_spmd_aggregate(rel: p.Aggregate, executor) -> Optional[Table]:
             # the configured segment-sum mode, as on one chip: two modes
             # are two programs and two families
             str(executor.config.get("sql.compile.segsum", "auto")),
-            table.num_rows,
-            table.padded_rows,
         )
+        bucket = (dc.uid, table.num_rows, table.padded_rows)
 
-        def build():
-            if _defer_to_background(ctx, mesh, rel, key, table, scan,
-                                    filters, group_exprs, agg_exprs,
-                                    executor.config, params):
-                return None  # served on a lower rung this time
-            from ..physical.compiled import _remember_family_locked
-
+        def construct():
             obj = SpmdAggregate(mesh, rel, table, scan, filters,
                                 group_exprs, agg_exprs, executor.config)
             obj.table = None  # never pin the construction table's HBM
-            with ctx._plan_lock:
-                _cache[key] = obj
-                while len(_cache) > _CACHE_CAP:
-                    _cache.popitem(last=False)
-                _remember_family_locked(ctx, _family_of(key),
-                                        _bucket_of(key))
             return obj
 
-        compiled, built_here = singleflight_get_or_build(ctx, _cache, key,
-                                                         build)
+        compiled, _ = PROGRAMS.get_or_build(
+            ctx, family, bucket, construct,
+            warm=lambda obj: obj.run(table, params), params=params)
         if compiled is None:
-            return None
-        if not built_here and params:
-            ctx.metrics.inc("families.hit")
-            from ..observability import trace_event
-
-            trace_event("family_hit", rung="spmd_aggregate",
-                        params=len(params))
+            return None  # deferred to the background compiler
         count_launch(ctx.metrics, mesh, table.num_rows,
                      compiled.segsum_mode)
         from ..resilience import faults
 
         faults.maybe_inject("oom", executor.config)
-        batcher = families.batcher_of(ctx)
-        if batcher is not None and params and compiled.batchable:
-            result = batcher.run(
-                key, params,
-                solo=lambda: compiled.run(table, params),
-                batched=lambda members: compiled.run_batched(table, members))
-        else:
-            result = compiled.run(table, params)
-        return result
+        return PROGRAMS.run(
+            ctx, family, bucket, compiled, params,
+            solo=lambda: compiled.run(table, params),
+            batched=lambda members: compiled.run_batched(table, members))
     except _Unsupported as e:
         logger.debug("spmd aggregate unsupported: %s", e)
         return None

@@ -17,7 +17,6 @@ the all_to_all hash-shuffle engine (`parallel/dist_plan.py`,
 from __future__ import annotations
 
 import logging
-from collections import OrderedDict
 from typing import Dict, Optional, Tuple
 
 import jax
@@ -32,13 +31,13 @@ from ..physical.compiled import (
     _Unsupported,
     check_agg_static_support,
     fetch_packed,
-    singleflight_get_or_build,
 )
 from ..physical.compiled_join import (
     CompiledJoinAggregate,
     _extract,
     _plan_nodes,
 )
+from ..physical.programs import ProgramCache
 from ..planner import plan as p
 from .aggregate import SpmdSegmentReducer
 from .core import (count_launch, launch_attrs, mesh_key,
@@ -171,10 +170,7 @@ class SpmdJoinAggregate(CompiledJoinAggregate):
         return self._decode_result(host, present, tags, build_tables=bts)
 
 
-_CACHE_CAP = 8
-_cache: "OrderedDict[tuple, SpmdJoinAggregate]" = OrderedDict()
-_DECLINED_CAP = 256
-_declined: set = set()
+PROGRAMS = ProgramCache("spmd_join_aggregate", 8)
 
 
 def try_spmd_join_aggregate(rel: p.Aggregate, executor) -> Optional[Table]:
@@ -210,7 +206,7 @@ def try_spmd_join_aggregate(rel: p.Aggregate, executor) -> Optional[Table]:
         # parallel.spmd.broadcast_rows must re-open a size-declined family
         limit = int(config.get("parallel.spmd.broadcast_rows", 1 << 20))
         decline_key = (tuple(uids), "spmd", limit, str(rel))
-        if decline_key in _declined:
+        if PROGRAMS.declined(decline_key):
             return None
         check_agg_static_support(agg_exprs)
         from .. import families
@@ -235,16 +231,12 @@ def try_spmd_join_aggregate(rel: p.Aggregate, executor) -> Optional[Table]:
             # memoize the decline (keyed by every base-table uid): a repeat
             # of this query must not re-execute the build subtrees here
             # just to re-measure them — the shuffle engine pays them once
-            if len(_declined) >= _DECLINED_CAP:
-                _declined.clear()
-            _declined.add(decline_key)
+            PROGRAMS.decline(decline_key)
             logger.debug("spmd join declining: build side exceeds "
                          "parallel.spmd.broadcast_rows=%d", limit)
             return None
-        key = (
-            "spmd_join_aggregate",
+        family = (
             mesh_key(mesh),
-            tuple(uids),
             ext.scan.schema_name, ext.scan.table_name,
             tuple(ext.scan.projection or ()),
             tuple(repr(j["plan"]) for j in ext.joins),
@@ -253,32 +245,22 @@ def try_spmd_join_aggregate(rel: p.Aggregate, executor) -> Optional[Table]:
             tuple(str(e) for e in group_exprs),
             tuple(str(a) for a in agg_exprs),
             tuple((f.name, f.sql_type) for f in rel.schema),
-            probe_table.num_rows,
-            probe_table.padded_rows,
-            tuple(bt.num_rows for bt in build_tables),
         )
+        bucket = (tuple(uids), probe_table.num_rows, probe_table.padded_rows,
+                  tuple(bt.num_rows for bt in build_tables))
 
-        def build():
+        def construct():
             obj = SpmdJoinAggregate(mesh, rel, ext, group_exprs, agg_exprs,
                                     probe_table, build_tables, executor)
             # the (large) construction tables never pin HBM on the cached
             # object: every run() takes its tables as parameters
             obj.probe_table = None
             obj.build_tables = None
-            with ctx._plan_lock:
-                _cache[key] = obj
-                while len(_cache) > _CACHE_CAP:
-                    _cache.popitem(last=False)
             return obj
 
-        compiled, built_here = singleflight_get_or_build(ctx, _cache, key,
-                                                         build)
-        if not built_here and params:
-            ctx.metrics.inc("families.hit")
-            from ..observability import trace_event
-
-            trace_event("family_hit", rung="spmd_join_aggregate",
-                        params=len(params))
+        # no `warm`: this rung never defers to the background compiler
+        compiled, _ = PROGRAMS.get_or_build(ctx, family, bucket, construct,
+                                            params=params)
         count_launch(ctx.metrics, mesh, probe_table.num_rows)
         from ..resilience import faults
 
@@ -287,9 +269,7 @@ def try_spmd_join_aggregate(rel: p.Aggregate, executor) -> Optional[Table]:
     except _Unsupported as e:
         logger.debug("spmd join pipeline unsupported: %s", e)
         if "decline_key" in locals():
-            if len(_declined) >= _DECLINED_CAP:
-                _declined.clear()
-            _declined.add(decline_key)
+            PROGRAMS.decline(decline_key)
         return None
     except (ValueError, TypeError, NotImplementedError) as e:
         # a fault in the wrap (not an ineligible shape): a counted step

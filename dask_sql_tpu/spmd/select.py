@@ -22,7 +22,6 @@ stacked launches vmap the mask program over the parameter axis.
 from __future__ import annotations
 
 import logging
-from collections import OrderedDict
 from typing import Dict, List, Optional, Tuple
 
 import jax
@@ -31,12 +30,10 @@ from jax.sharding import PartitionSpec as P
 
 from ..columnar.table import Table
 from ..parallel.mesh import AXIS
-from ..physical.compiled import (
-    _Unsupported,
-    defer_rebuild,
-    singleflight_get_or_build,
-)
-from ..physical.compiled_select import CompiledSelect, _extract
+from ..physical.compiled import _Unsupported
+from ..physical.compiled_select import (CompiledSelect, _extract,
+                                        select_family)
+from ..physical.programs import ProgramCache
 from .core import (ColumnSpmdWrap, count_launch, launch_attrs, mesh_key,
                    mesh_of_sharded_table, raise_rung_fault, rung_enabled)
 
@@ -206,34 +203,7 @@ class SpmdSelect(CompiledSelect):
         return self._assemble(cols, valid_arrs, count)
 
 
-_CACHE_CAP = 16
-_cache: "OrderedDict[Tuple, SpmdSelect]" = OrderedDict()
-
-
-def _family_of(key: Tuple) -> Tuple:
-    # drop table identity: uid (index 2) and the trailing row buckets
-    return key[:2] + key[3:-2]
-
-
-def _bucket_of(key: Tuple) -> Tuple:
-    return (key[2], key[-2], key[-1])  # (uid, num_rows, padded_rows)
-
-
-def _defer_to_background(ctx, mesh, key, table, scan, p_upper, p_scan_flts,
-                         proj, p_exprs, limit, inner_limit, params) -> bool:
-    """Background-recompile hook for SPMD root select chains — the shared
-    `defer_rebuild` policy (physical/compiled.py) with this rung's
-    constructor; True = deferred."""
-
-    def build_and_warm():
-        obj = SpmdSelect(mesh, table, scan, p_upper, p_scan_flts, proj,
-                         p_exprs, None, None, limit, inner_limit, params)
-        obj.run(table, params)  # compiles mask + first gather
-        obj.table = None
-        return obj
-
-    return defer_rebuild(ctx, "spmd_select", _cache, _CACHE_CAP, key,
-                         _family_of(key), _bucket_of(key), build_and_warm)
+PROGRAMS = ProgramCache("spmd_select", 16)
 
 
 def try_spmd_select(root, executor) -> Optional[Table]:
@@ -273,63 +243,35 @@ def try_spmd_select(root, executor) -> Optional[Table]:
         p_scan_flts = [pz.rewrite(f) for f in scan.filters]
         p_exprs = [pz.rewrite(e) for e in proj.exprs]
         params = pz.params
-        key = (
-            "spmd_select",
-            mesh_key(mesh),
-            dc.uid,
-            # table NAME stays in the family (only the uid is table-version
-            # identity): same-shaped queries over different tables must not
-            # collide in the background-recompile family map
-            scan.schema_name, scan.table_name,
-            tuple(scan.projection or ()),
-            tuple(str(f) for f in p_upper),
-            tuple(str(f) for f in p_scan_flts),
-            tuple(str(e) for e in p_exprs),
-            limit,
-            inner_limit,
-            table.num_rows,
-            table.padded_rows,
-        )
+        # the table NAME is the family's (only the uid is table-version
+        # identity): same-shaped queries over different tables must not
+        # collide in the background-recompile family map
+        family = (mesh_key(mesh), scan.schema_name, scan.table_name) \
+            + select_family(scan, p_upper, p_scan_flts, p_exprs, sort_keys,
+                            sort_fetch, limit, inner_limit)
+        bucket = (dc.uid, table.num_rows, table.padded_rows)
 
-        def build():
-            if _defer_to_background(ctx, mesh, key, table, scan, p_upper,
-                                    p_scan_flts, proj, p_exprs, limit,
-                                    inner_limit, params):
-                return None  # served on a lower rung this time
-            from ..physical.compiled import _remember_family_locked
-
+        def construct():
             obj = SpmdSelect(mesh, table, scan, p_upper, p_scan_flts, proj,
                              p_exprs, sort_keys, sort_fetch, limit,
                              inner_limit, params)
             obj.table = None
-            with ctx._plan_lock:
-                _cache[key] = obj
-                while len(_cache) > _CACHE_CAP:
-                    _cache.popitem(last=False)
-                _remember_family_locked(ctx, _family_of(key),
-                                        _bucket_of(key))
             return obj
 
-        compiled, built_here = singleflight_get_or_build(ctx, _cache, key,
-                                                         build)
+        # the warming run compiles the mask + the first gather
+        compiled, _ = PROGRAMS.get_or_build(
+            ctx, family, bucket, construct,
+            warm=lambda obj: obj.run(table, params), params=params)
         if compiled is None:
-            return None
-        if not built_here and params:
-            ctx.metrics.inc("families.hit")
-            from ..observability import trace_event
-
-            trace_event("family_hit", rung="spmd_select", params=len(params))
+            return None  # deferred to the background compiler
         count_launch(ctx.metrics, mesh, table.num_rows)
         from ..resilience import faults
 
         faults.maybe_inject("oom", executor.config)
-        batcher = families.batcher_of(ctx)
-        if batcher is not None and params:
-            return batcher.run(
-                key, params,
-                solo=lambda: compiled.run(table, params),
-                batched=lambda members: compiled.run_batched(table, members))
-        return compiled.run(table, params)
+        return PROGRAMS.run(
+            ctx, family, bucket, compiled, params,
+            solo=lambda: compiled.run(table, params),
+            batched=lambda members: compiled.run_batched(table, members))
     except _Unsupported as e:
         logger.debug("spmd select unsupported: %s", e)
         return None

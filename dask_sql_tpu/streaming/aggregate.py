@@ -28,7 +28,6 @@ re-specializes once per new shape.
 from __future__ import annotations
 
 import logging
-from collections import OrderedDict
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -45,8 +44,8 @@ from ..physical.compiled import (
     _TraceEval,
     _Unsupported,
     agg_argument,
-    singleflight_get_or_build,
 )
+from ..physical.programs import ProgramCache
 from ..planner import plan as p
 from .partition import slice_chunk
 from .plan import StreamDecision
@@ -70,9 +69,10 @@ class StreamedAggregate(CompiledAggregate):
     def __init__(self, agg: p.Aggregate, table: Table, scan, filters,
                  group_exprs, agg_exprs):
         # combine ops / finalize plan are filled by _build (called from the
-        # parent constructor); config=None pins segsum_mode "scatter" — the
-        # only mode whose raw states combine elementwise, the same choice
-        # the SPMD rung makes for its collectives
+        # parent constructor); config=None pins segsum_mode "scatter": the
+        # matmul state adds across partitions too (the sharded rung psums
+        # it, PR 30), but this rung has no cell to show what un-pinning it
+        # buys, so it stays pinned (ROADMAP D1)
         self._combine_ops: List[str] = []
         self._finalize_plan: List[Tuple[str, List[int]]] = []
         super().__init__(agg, table, scan, filters, group_exprs, agg_exprs,
@@ -251,17 +251,15 @@ def _push(ops: List[str], op: str) -> int:
     return len(ops) - 1
 
 
-# bounded cache of streamed morsel executables, keyed like the compiled
-# aggregate cache plus nothing chunk-specific: ONE object serves every
-# partitioning of a family (jit re-specializes per chunk shape), so the
-# second streamed run of a family replays warm executables
-_CACHE_CAP = 8
-_cache: "OrderedDict[Tuple, StreamedAggregate]" = OrderedDict()
+# keyed like the compiled aggregate cache plus nothing chunk-specific: ONE
+# object serves every partitioning of a family (jit re-specializes per chunk
+# shape), so the second streamed run of a family replays warm executables
+PROGRAMS = ProgramCache("streamed_aggregate", 8)
 
 
 def reset_cache() -> None:
     """Tests: drop cached morsel executables (warm-shape state included)."""
-    _cache.clear()
+    PROGRAMS.clear()
 
 
 def try_streamed_aggregate(rel: p.Aggregate, executor) -> Optional[Table]:
@@ -304,40 +302,29 @@ def try_streamed_aggregate(rel: p.Aggregate, executor) -> Optional[Table]:
         filters = [pz.rewrite(f) for f in filters]
         agg_exprs = [pz.rewrite_agg(a) for a in agg_exprs]
         params = pz.params
-        key = (
-            "streamed_aggregate",
-            dc.uid,
+        family = (
             scan.schema_name, scan.table_name,
             tuple(scan.projection or ()),
             tuple(str(f) for f in filters),
             tuple(str(e) for e in group_exprs),
             tuple(str(a) for a in agg_exprs),
-            table.num_rows,
         )
+        bucket = (dc.uid, table.num_rows)
 
-        def build():
+        def construct():
             obj = StreamedAggregate(rel, table, scan, filters, group_exprs,
                                     agg_exprs)
             obj.table = None  # never pin the construction table's HBM
-            with ctx._plan_lock:
-                _cache[key] = obj
-                while len(_cache) > _CACHE_CAP:
-                    _cache.popitem(last=False)
             return obj
 
-        compiled, built_here = singleflight_get_or_build(ctx, _cache, key,
-                                                         build)
+        # no `warm`: this rung never defers to the background compiler
+        compiled, _ = PROGRAMS.get_or_build(ctx, family, bucket, construct,
+                                            params=params)
     except (_Unsupported, ValueError, TypeError, NotImplementedError) as e:
         from .plan import shed_ineligible
 
         shed_ineligible(decision, ctx.metrics, reason=str(e))
         raise  # unreachable: shed_ineligible always raises
-    if compiled is None:
-        return None
-    if not built_here and params:
-        ctx.metrics.inc("families.hit")
-        trace_event("family_hit", rung="streamed_aggregate",
-                    params=len(params))
     ctx.metrics.inc("serving.stream.queries")
     # -- pipelined partition drive ----------------------------------------
     # failures in here keep the ladder's semantics: transient errors retry,

@@ -18,13 +18,14 @@ here (streaming/plan.py declines them at decision time).
 from __future__ import annotations
 
 import logging
-from collections import OrderedDict
-from typing import List, Optional, Tuple
+from typing import List, Optional
 
 from ..columnar.table import Table
 from ..observability import trace_event
-from ..physical.compiled import _Unsupported, singleflight_get_or_build
-from ..physical.compiled_select import CompiledSelect, _extract
+from ..physical.compiled import _Unsupported
+from ..physical.compiled_select import (CompiledSelect, _extract,
+                                        select_family)
+from ..physical.programs import ProgramCache
 from .partition import slice_chunk
 from .plan import StreamDecision
 from .runner import drive_partitions
@@ -70,13 +71,12 @@ class _StreamableSelect(CompiledSelect):
         return self._finish(datas, valids, mask, count, tuple(params))
 
 
-_CACHE_CAP = 8
-_cache: "OrderedDict[Tuple, CompiledSelect]" = OrderedDict()
+PROGRAMS = ProgramCache("streamed_select", 8)
 
 
 def reset_cache() -> None:
     """Tests: drop cached streamed select executables."""
-    _cache.clear()
+    PROGRAMS.clear()
 
 
 def try_streamed_select(root, executor) -> Optional[Table]:
@@ -119,39 +119,25 @@ def try_streamed_select(root, executor) -> Optional[Table]:
         p_scan_flts = [pz.rewrite(f) for f in scan.filters]
         p_exprs = [pz.rewrite(e) for e in proj.exprs]
         params = pz.params
-        key = (
-            "streamed_select",
-            dc.uid,
-            tuple(scan.projection or ()),
-            tuple(str(f) for f in p_upper),
-            tuple(str(f) for f in p_scan_flts),
-            tuple(str(e) for e in p_exprs),
-            table.num_rows,
-        )
+        # sort and limit windows are None here (declined above)
+        family = select_family(scan, p_upper, p_scan_flts, p_exprs, sort_keys,
+                               sort_fetch, limit, inner_limit)
+        bucket = (dc.uid, table.num_rows)
 
-        def build():
+        def construct():
             obj = _StreamableSelect(table, scan, p_upper, p_scan_flts, proj,
                                     p_exprs, None, None, None, None, params)
             obj.table = None
-            with ctx._plan_lock:
-                _cache[key] = obj
-                while len(_cache) > _CACHE_CAP:
-                    _cache.popitem(last=False)
             return obj
 
-        compiled, built_here = singleflight_get_or_build(ctx, _cache, key,
-                                                         build)
+        # no `warm`: this rung never defers to the background compiler
+        compiled, _ = PROGRAMS.get_or_build(ctx, family, bucket, construct,
+                                            params=params)
     except (_Unsupported, ValueError, TypeError, NotImplementedError) as e:
         from .plan import shed_ineligible
 
         shed_ineligible(decision, ctx.metrics, reason=str(e))
         raise  # unreachable: shed_ineligible always raises
-    if compiled is None:
-        return None
-    if not built_here and params:
-        ctx.metrics.inc("families.hit")
-        trace_event("family_hit", rung="streamed_select",
-                    params=len(params))
     ctx.metrics.inc("serving.stream.queries")
     # -- pipelined partition drive (ladder semantics preserved) -----------
     parts: List[Table] = []

@@ -180,29 +180,32 @@ def test_auto_stays_scatter_off_the_chip(lineitem):
 
 @pytest.mark.parametrize("mode", MODES)
 def test_two_literals_of_a_family_share_one_program(lineitem, mode):
-    """The key's new element is the family's, not the bucket's: a second
-    DELTA hits the first's compiled object; the other mode is another."""
+    """The configured mode is part of the FAMILY, the table's rows are the
+    BUCKET: a second DELTA hits the first's compiled object; the other
+    mode is another program."""
     from dask_sql_tpu.spmd import aggregate
 
     _, arrow_table = lineitem(ROWS[1])
     query = traffic.load("queries", "tpch_q1")
     c = _context(arrow_table)
-    before = set(aggregate._cache)
+    before = set(aggregate.PROGRAMS.values())
     for delta in (60, 120):
         _compute(c, traffic.render(query, {"DELTA": delta}), mode)
-    built = [k for k in aggregate._cache if k not in before]
+    built = [(key, program) for key, program in aggregate.PROGRAMS.items()
+             if program not in before]
     assert len(built) == 1, built
-    assert aggregate._cache[built[0]].segsum_mode == mode
+    (family, bucket), program = built[0]
+    assert program.segsum_mode == mode
     assert c.metrics.counter("families.hit") == 1
-    assert mode in aggregate._family_of(built[0])
-    table = c.schema["root"].tables["lineitem"].table
-    assert aggregate._bucket_of(built[0])[1:] == (table.num_rows,
-                                                  table.padded_rows)
+    assert mode in family
+    dc = c.schema["root"].tables["lineitem"]
+    assert bucket == (dc.uid, dc.table.num_rows, dc.table.padded_rows)
     assert not [s for s in c.last_trace.spans
                 if s.name.startswith("compile:")]
     _compute(c, traffic.render(query, {"DELTA": 90}), _other(mode))
     assert _launch(c).attrs["segsum"] == _other(mode)
-    assert len([k for k in aggregate._cache if k not in before]) == 2
+    assert len([program for program in aggregate.PROGRAMS.values()
+                if program not in before]) == 2
 
 
 @pytest.mark.parametrize("mode", MODES)

@@ -238,7 +238,7 @@ def test_family_batched_stacked_spmd_launch():
     sharded.sql("SELECT g, k, x, q FROM t WHERE k < 4").compute()
     table = sharded.schema["root"].tables["t"].table
 
-    aobj = list(sa._cache.values())[-1]  # most recent (module LRU persists)
+    aobj = sa.PROGRAMS.values()[-1]  # most recent (module LRU persists)
     params_list = [(np.int64(20),), (np.int64(10),), (np.int64(5),)]
     outs = aobj.run_batched(table, params_list)
     for p, out in zip(params_list, outs):
@@ -247,7 +247,7 @@ def test_family_batched_stacked_spmd_launch():
         for col in got.columns:
             assert (got[col].to_numpy() == exp[col].to_numpy()).all(), col
 
-    sobj = list(ss._cache.values())[-1]
+    sobj = ss.PROGRAMS.values()[-1]
     params_list = [(np.int64(4),), (np.int64(2),)]
     outs = sobj.run_batched(table, params_list)
     for p, out in zip(params_list, outs):

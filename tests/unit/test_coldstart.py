@@ -490,7 +490,7 @@ def test_plain_cache_eviction_is_not_misread_as_growth(config_keys):
     c.sql(AGG_QUERY, return_futures=False)
     assert c.metrics.counter("resilience.rung.compiled_aggregate") == 1
     with c._plan_lock:  # simulate LRU churn evicting the entry
-        compiled_mod._cache.clear()
+        compiled_mod.PROGRAMS.clear()
     c.sql(AGG_QUERY, return_futures=False)
     assert c.metrics.counter("serving.bg_compile.deferred") == 0
     assert c.metrics.counter("resilience.rung.compiled_aggregate") == 2

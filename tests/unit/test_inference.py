@@ -443,8 +443,8 @@ def test_fused_predict_batched_members_share_one_stacked_launch():
     _create_forest(c)
     c.sql("SELECT * FROM PREDICT(MODEL m, SELECT x, y FROM t "
           "WHERE x < 0.3)", return_futures=False)  # builds + caches
-    compiled = next(v for k, v in reversed(list(cp._cache.items()))
-                    if k[3] == "m")
+    compiled = next(program for (family, _), program
+                    in reversed(cp.PROGRAMS.items()) if family.model == "m")
     model, cols = c.get_model(c.schema_name, "m")
     program, _ = inference.program_for(c, c.schema_name, "m", model,
                                        commit=True)
@@ -524,9 +524,14 @@ def test_drop_model_evicts_fused_pipelines():
     c.sql("SELECT * FROM PREDICT(MODEL m, SELECT x, y FROM t "
           "WHERE x < 0.5)", return_futures=False)
     schema = c.schema_name
-    assert any(k[2] == schema and k[3] == "m" for k in cp._cache)
+
+    def cached():
+        return [family for (family, _), _ in cp.PROGRAMS.items()
+                if family.schema == schema and family.model == "m"]
+
+    assert cached()
     c.sql("DROP MODEL m", return_futures=False)
-    assert not any(k[2] == schema and k[3] == "m" for k in cp._cache)
+    assert not cached()
     assert c.ledger.snapshot()["modelBytes"] == 0
 
 

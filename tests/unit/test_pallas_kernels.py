@@ -60,8 +60,8 @@ def test_compiled_pipeline_matmul_mode(c):
     # and the compiled matmul path really ran (not an eager fallback)
     from dask_sql_tpu.physical import compiled as comp
 
-    assert any(k[-1] == "matmul" and v.segsum_mode == "matmul"
-               for k, v in comp._cache.items())
+    assert any("matmul" in family and program.segsum_mode == "matmul"
+               for (family, _), program in comp.PROGRAMS.items())
 
 
 def test_segsum_double_float_accuracy():

@@ -208,10 +208,10 @@ def test_streamed_select_repartition_compiles_under_watchdog(monkeypatch):
     assert c.metrics.counter("serving.stream.repartitions") == 1
     # white-box: drive the cached streamed executable over fresh chunk
     # shapes and record the hint each mask launch carries
-    from dask_sql_tpu.streaming.select import _cache
+    from dask_sql_tpu.streaming.select import PROGRAMS
     import dask_sql_tpu.observability as obs
 
-    obj = next(iter(_cache.values()))
+    obj = PROGRAMS.values()[0]
     real = obs.timed_jit_call
     hints = []
 
