@@ -109,37 +109,88 @@ def check_no_rle(table) -> None:
             raise _Unsupported("rle-encoded column in compiled pipeline")
 
 
-def count_codespace_predicates(exprs, table) -> int:
-    """Static count of predicates a pipeline over `table` evaluates in CODE
-    space (comparison/IN against a raw DICT-column ref): the
-    ``columnar.encoding.codespace_pred`` accounting, computed from the plan
-    so the metric is trace-independent."""
+#: the calls a row-invariant comparand may hold: each answers a 0-d input
+#: with a 0-d value that is never NULL and never differs between two rows
+#: (so nothing volatile); integer `div` is kept out below, since it answers
+#: a zero divisor with NULL
+_ROW_INVARIANT_OPS = frozenset({
+    "add", "sub", "mul", "div", "neg", "datetime_add", "datetime_sub",
+    "datetime_sub_interval", "int_to_interval_days"})
+
+
+def _is_number(value) -> bool:
+    """A Python / numpy int or float, and no bool."""
+    return not isinstance(value, bool) and isinstance(
+        value, (int, float, np.integer, np.floating))
+
+
+def row_invariant(expr: Expr) -> bool:
+    """True when `expr` is ONE never-NULL number per query: runtime
+    parameters and numeric literals under casts and the calls of
+    `_ROW_INVARIANT_OPS`, and nothing else — so no column reference of any
+    kind, no subquery, aggregate or window expression, no NULL, boolean or
+    string literal, no volatile call.  What `_encoded_compare` takes as the
+    other side of a code-space comparison and what
+    `count_codespace_predicates` counts as one: both ask here."""
+    for sub in walk(expr):
+        if isinstance(sub, Literal):
+            if not _is_number(sub.value):
+                return False
+        elif isinstance(sub, ScalarFunc):
+            if sub.op not in _ROW_INVARIANT_OPS or (
+                    sub.op == "div" and all(a.sql_type in INTEGER_TYPES
+                                            for a in sub.args)):
+                return False
+        elif not isinstance(sub, (ParamRef, Cast)):
+            return False
+        if sub.sql_type in STRING_TYPES:
+            return False
+    return True
+
+
+def count_codespace_predicates(exprs, table) -> Tuple[int, int]:
+    """Static ``(code space, value space)`` count of the predicates a
+    pipeline over `table` evaluates on a raw numeric DICT-column ref: the
+    comparisons and IN tests it rewrites onto the codes
+    (``columnar.encoding.codespace_pred``) and those it does not, which
+    decode the column per row (``columnar.encoding.valuespace_pred``).
+    Computed from the plan, through the evaluator's own `_codespace_operands`
+    / `_codespace_members`, so the metrics are trace-independent and cannot
+    disagree with the kernel."""
     ev = _TraceEval(table)
-    n = 0
+    code = value = 0
     for e in exprs:
         if e is None:
             continue
         for sub in walk(e):
-            if isinstance(sub, ScalarFunc) and sub.op in (
-                    "eq", "ne", "lt", "le", "gt", "ge") \
-                    and len(sub.args) == 2:
-                a, b = sub.args
-                for colarg, litarg in ((a, b), (b, a)):
-                    try:
-                        c = ev._dict_source(colarg)
-                    except (IndexError, KeyError):
-                        c = None
-                    if c is not None and isinstance(litarg,
-                                                    (Literal, ParamRef)):
-                        n += 1
-                        break
-            elif isinstance(sub, (InListExpr, InArrayExpr)):
-                try:
-                    if ev._dict_source(sub.arg) is not None:
-                        n += 1
-                except (IndexError, KeyError):
-                    pass
-    return n
+            try:
+                if isinstance(sub, ScalarFunc) and sub.op in FLIP_CMP \
+                        and len(sub.args) == 2:
+                    if ev._codespace_operands(sub.op, sub.args) is not None:
+                        code += 1
+                    elif any(ev._dict_source(a) is not None
+                             for a in sub.args):
+                        value += 1
+                elif isinstance(sub, (InListExpr, InArrayExpr)) \
+                        and ev._dict_source(sub.arg) is not None:
+                    if ev._codespace_members(sub) is not None:
+                        code += 1
+                    else:
+                        value += 1
+            except (IndexError, KeyError):
+                pass
+    return code, value
+
+
+def record_predicate_spaces(ctx, compiled) -> None:
+    """A freshly built pipeline's `count_codespace_predicates` pair into the
+    ``columnar.encoding.*`` counters (once per build, not per query)."""
+    if compiled.codespace_preds:
+        ctx.metrics.inc("columnar.encoding.codespace_pred",
+                        compiled.codespace_preds)
+    if compiled.valuespace_preds:
+        ctx.metrics.inc("columnar.encoding.valuespace_pred",
+                        compiled.valuespace_preds)
 
 
 def check_agg_static_support(agg_exprs):
@@ -616,66 +667,75 @@ class _TraceEval:
                 return c
         return None
 
+    def _codespace_operands(self, op: str, args):
+        """``(column ref, comparand, op)`` with the column on the left when
+        one side of the comparison is a raw numeric DICT column and the
+        other is `row_invariant`; else None."""
+        a, b = args
+        for colarg, other, o in ((a, b, op), (b, a, FLIP_CMP[op])):
+            if self._dict_source(colarg) is not None \
+                    and row_invariant(other):
+                return colarg, other, o
+        return None
+
     def _encoded_compare(self, op: str, args, slots):
-        """``dict_col CMP literal/param`` rewritten into CODE space.
+        """``dict_col CMP row-invariant comparand`` rewritten into CODE
+        space, in either operand order.
 
         The dictionary is sorted, so order predicates translate through a
-        searchsorted boundary — host-side for literals (a static int enters
-        the program), in-kernel over the (tiny) value-constant for runtime
-        params, which keeps ONE executable per plan family.  Returns None
-        when the shape doesn't match (caller evaluates in value space)."""
-        a, b = args
-        for colarg, litarg, o in ((a, b, op), (b, a, FLIP_CMP[op])):
-            c = self._dict_source(colarg)
-            if c is None:
-                continue
-            codes, valid = slots[colarg.index]
-            vals = c.enc_values
-            if isinstance(litarg, Literal) and not isinstance(
-                    litarg.value, bool) and isinstance(
-                    litarg.value, (int, float, np.integer, np.floating)):
-                kind, code = dict_literal_bounds(vals, o, litarg.value)
-                if kind == "lt":
-                    hit = codes < code
-                elif kind == "ge":
-                    hit = codes >= code
-                elif kind == "eq":
-                    hit = codes == code
-                elif kind == "ne":
-                    hit = codes != code
-                elif kind == "all":
-                    hit = jnp.ones(codes.shape, dtype=bool)
-                else:  # "none"
-                    hit = jnp.zeros(codes.shape, dtype=bool)
-                return (hit, valid)
-            if isinstance(litarg, ParamRef):
-                vj = jnp.asarray(vals)
-                p = slots[PARAMS_SLOT][litarg.index]
-                if np.dtype(vj.dtype).kind != np.dtype(p.dtype).kind:
-                    # cross-kind literal (float vs int dictionary): compare
-                    # in f64 — exact for every dictionary this path serves
-                    vj = vj.astype(jnp.float64)
-                    p = p.astype(jnp.float64)
-                left = jnp.searchsorted(vj, p, side="left")
-                if o in ("lt", "ge"):
-                    bound = left
-                else:
-                    bound = jnp.searchsorted(vj, p, side="right")
-                if o == "lt":
-                    hit = codes < bound
-                elif o == "le":
-                    hit = codes < bound
-                elif o == "gt":
-                    hit = codes >= bound
-                elif o == "ge":
-                    hit = codes >= bound
-                else:  # eq / ne: exact-member test
-                    present = (left < len(vals)) & \
-                        (vj[jnp.clip(left, 0, len(vals) - 1)] == p)
-                    eq = present & (codes == left)
-                    hit = eq if o == "eq" else ~eq
-                return (hit, valid)
-        return None
+        searchsorted boundary — host-side for a bare literal (a static int
+        enters the program); for any other `row_invariant` comparand (a
+        runtime param, or params and literals under casts and date / number
+        arithmetic, as in ``DATE '1998-12-01' - INTERVAL '90' DAY``) the
+        comparand is evaluated ONCE, to a 0-d value, and searched in-kernel
+        over the (tiny) value-constant, which keeps ONE executable per plan
+        family and no per-row decode.  Returns None when the shape doesn't
+        match or the scalar can be NULL (caller evaluates in value space)."""
+        found = self._codespace_operands(op, args)
+        if found is None:
+            return None
+        colarg, other, o = found
+        codes, valid = slots[colarg.index]
+        vals = self.col(colarg.index).enc_values
+        if isinstance(other, Literal):
+            kind, code = dict_literal_bounds(vals, o, other.value)
+            if kind == "lt":
+                hit = codes < code
+            elif kind == "ge":
+                hit = codes >= code
+            elif kind == "eq":
+                hit = codes == code
+            elif kind == "ne":
+                hit = codes != code
+            elif kind == "all":
+                hit = jnp.ones(codes.shape, dtype=bool)
+            else:  # "none"
+                hit = jnp.zeros(codes.shape, dtype=bool)
+            return (hit, valid)
+        p, p_valid = self.eval(other, slots)
+        if p_valid is not None:
+            return None
+        vj = jnp.asarray(vals)
+        if np.dtype(vj.dtype).kind != np.dtype(p.dtype).kind:
+            # cross-kind comparand (float vs int dictionary): compare
+            # in f64 — exact for every dictionary this path serves
+            vj = vj.astype(jnp.float64)
+            p = p.astype(jnp.float64)
+        left = jnp.searchsorted(vj, p, side="left")
+        if o in ("lt", "ge"):
+            bound = left
+        else:
+            bound = jnp.searchsorted(vj, p, side="right")
+        if o in ("lt", "le"):
+            hit = codes < bound
+        elif o in ("gt", "ge"):
+            hit = codes >= bound
+        else:  # eq / ne: exact-member test
+            present = (left < len(vals)) & \
+                (vj[jnp.clip(left, 0, len(vals) - 1)] == p)
+            eq = present & (codes == left)
+            hit = eq if o == "eq" else ~eq
+        return (hit, valid)
 
     # -- compile-time string handling --------------------------------------
     def _string_source(self, expr: Expr) -> Optional[Column]:
@@ -685,20 +745,33 @@ class _TraceEval:
                 return c
         return None
 
-    def _dict_membership(self, expr, slots, values):
+    def _codespace_members(self, expr) -> Optional[list]:
+        """The value list (NULLs dropped) of an IN over a raw numeric DICT
+        column when every item is a number: what `_dict_membership` tests in
+        code space and `count_codespace_predicates` counts there.  Else
+        None."""
+        if self._dict_source(expr.arg) is None:
+            return None
+        if isinstance(expr, InArrayExpr):
+            values = list(np.asarray(expr.values))
+        elif all(isinstance(it, Literal) for it in expr.items):
+            values = [it.value for it in expr.items if it.value is not None]
+        else:
+            return None
+        return values if all(_is_number(v) for v in values) else None
+
+    def _dict_membership(self, expr, slots):
         """IN over a numeric DICT column: map the value list through the
         sorted dictionary on the host (absent values drop out) and test
         CODE membership on device."""
-        c = self._dict_source(expr.arg)
-        if c is None:
+        values = self._codespace_members(expr)
+        if values is None:
             return None
+        enc_values = self.col(expr.arg.index).enc_values
         code_list = []
         for v in values:
-            if isinstance(v, bool) or not isinstance(
-                    v, (int, float, np.integer, np.floating)):
-                return None
-            i = int(np.searchsorted(c.enc_values, v))
-            if i < len(c.enc_values) and c.enc_values[i] == v:
+            i = int(np.searchsorted(enc_values, v))
+            if i < len(enc_values) and enc_values[i] == v:
                 code_list.append(i)
         codes, valid = slots[expr.arg.index]
         if code_list:
@@ -719,12 +792,9 @@ class _TraceEval:
             if expr.negated:
                 hit = ~hit
             return (hit, valid)
-        if all(isinstance(it, Literal) for it in expr.items):
-            got = self._dict_membership(
-                expr, slots, [it.value for it in expr.items
-                              if it.value is not None])
-            if got is not None:
-                return got
+        got = self._dict_membership(expr, slots)
+        if got is not None:
+            return got
         ad, av = self.eval(expr.arg, slots)
         if not all(isinstance(it, Literal) for it in expr.items):
             raise _Unsupported("non-literal IN list")
@@ -759,7 +829,7 @@ class _TraceEval:
             codes, valid = slots[expr.arg.index]
             hit = dictionary_membership(codes, src.dictionary, expr.values)
             return (~hit if expr.negated else hit, valid)
-        got = self._dict_membership(expr, slots, list(np.asarray(expr.values)))
+        got = self._dict_membership(expr, slots)
         if got is not None:
             return got
         ad, av = self.eval(expr.arg, slots)
@@ -1051,11 +1121,13 @@ class CompiledAggregate:
         self.has_encoded = any(
             getattr(c, "encoding", Encoding.PLAIN) is not Encoding.PLAIN
             for c in table.columns.values())
-        self.codespace_preds = count_codespace_predicates(
-            list(filters) + [x for a in agg_exprs
-                             for x in list(a.args)
-                             + ([a.filter] if a.filter is not None else [])],
-            table) if self.has_encoded else 0
+        self.codespace_preds, self.valuespace_preds = \
+            count_codespace_predicates(
+                list(filters) + [x for a in agg_exprs
+                                 for x in list(a.args)
+                                 + ([a.filter] if a.filter is not None
+                                    else [])],
+                table) if self.has_encoded else (0, 0)
         #: (kind, np.dtype) per packed output row; rebound atomically each
         #: time a variant traces (solo and batched traces on concurrent
         #: threads produce identical tags — rebinding instead of clearing
@@ -1315,9 +1387,8 @@ def try_compiled_aggregate(rel: p.Aggregate, executor) -> Optional[Table]:
             warm=lambda obj: obj.run(table, params), params=params)
         if compiled is None:
             return None  # deferred to the background compiler
-        if built_here and compiled.codespace_preds:
-            ctx.metrics.inc("columnar.encoding.codespace_pred",
-                            compiled.codespace_preds)
+        if built_here:
+            record_predicate_spaces(ctx, compiled)
         from ..resilience import faults
 
         faults.maybe_inject("oom", executor.config)

@@ -53,6 +53,7 @@ from .compiled import (
     check_agg_static_support,
     check_no_rle,
     count_codespace_predicates,
+    record_predicate_spaces,
     decode_radix_group_key,
     segment_agg_outputs,
 )
@@ -287,11 +288,12 @@ class CompiledJoinAggregate:
             meta_cols.append(_ColMeta(bt.columns[bt.column_names[col]]))
             meta_names.append(f"__b{k}_{col}")
         self._ev = _TraceEval(_SlotMeta(meta_cols, meta_names))
-        self.codespace_preds = count_codespace_predicates(
-            list(self.conjuncts)
-            + [x for a in self.agg_exprs for x in list(a.args)
-               + ([a.filter] if a.filter is not None else [])],
-            self._ev.table) if self.has_encoded else 0
+        self.codespace_preds, self.valuespace_preds = \
+            count_codespace_predicates(
+                list(self.conjuncts)
+                + [x for a in self.agg_exprs for x in list(a.args)
+                   + ([a.filter] if a.filter is not None else [])],
+                self._ev.table) if self.has_encoded else (0, 0)
         # segment-reduction strategy: one mode per pipeline, chosen from the
         # (static) group domain — radix product, or the gid build table's
         # row count for pointer gids
@@ -699,9 +701,8 @@ def try_compiled_join_aggregate(rel: p.Aggregate, executor) -> Optional[Table]:
         if not built_here:
             compiled.probe_table = probe_table
             compiled.build_tables = build_tables
-        if built_here and compiled.codespace_preds:
-            ctx.metrics.inc("columnar.encoding.codespace_pred",
-                            compiled.codespace_preds)
+        if built_here:
+            record_predicate_spaces(ctx, compiled)
         try:
             from ..resilience import faults
 

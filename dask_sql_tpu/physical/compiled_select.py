@@ -42,6 +42,7 @@ from .compiled import (
     _Unsupported,
     check_no_rle,
     count_codespace_predicates,
+    record_predicate_spaces,
     pack_flat,
 )
 from .programs import ProgramCache
@@ -124,9 +125,10 @@ class CompiledSelect:
         #: reads codes and the survivor gather late-materializes values
         self.has_encoded = any(
             c.encoding is not Encoding.PLAIN for c in table.columns.values())
-        self.codespace_preds = count_codespace_predicates(
-            list(upper_filters) + list(scan_filters) + list(proj_exprs),
-            table) if self.has_encoded else 0
+        self.codespace_preds, self.valuespace_preds = \
+            count_codespace_predicates(
+                list(upper_filters) + list(scan_filters) + list(proj_exprs),
+                table) if self.has_encoded else (0, 0)
         self.out_meta: List[Tuple[str, SqlType, Optional[object]]] = []
         for e, f in zip(proj_exprs, proj.schema):
             if f.sql_type in STRING_TYPES:
@@ -545,9 +547,8 @@ def try_compiled_select(root, executor) -> Optional[Table]:
             warm=lambda obj: obj.run(table, params), params=params)
         if compiled is None:
             return None  # deferred to the background compiler
-        if built_here and compiled.codespace_preds:
-            ctx.metrics.inc("columnar.encoding.codespace_pred",
-                            compiled.codespace_preds)
+        if built_here:
+            record_predicate_spaces(ctx, compiled)
         from ..resilience import faults
 
         faults.maybe_inject("oom", executor.config)

@@ -42,11 +42,13 @@ DOCUMENTED_METRICS = frozenset({
     "analysis.estimate.rung_proof",
     "analysis.estimate.internal_error",
     "analysis.estimate.feedback",
-    # columnar/ — compressed column encodings (encodings.py, docs/columnar.md)
+    # columnar/ — compressed column encodings (encodings.py, docs/columnar.md);
+    # codespace_pred / valuespace_pred: physical/compiled.py, per built program
     "columnar.encoding.encoded_columns",
     "columnar.encoding.encoded_bytes",
     "columnar.encoding.decoded_bytes",
     "columnar.encoding.codespace_pred",
+    "columnar.encoding.valuespace_pred",
     "columnar.encoding.late_rows",
     "columnar.encoding.decode",
     # inference/ — model lowering + fused PREDICT (docs/ml.md)

@@ -186,6 +186,186 @@ def test_codespace_predicates_match_decoded_property():
     assert c_enc.metrics.counter("columnar.encoding.decode") == 0
 
 
+# ---- code space for a row-invariant comparand (ISSUE 32): the other side of
+# the comparison has no column in it and neither planner folds it, so it
+# reaches the kernel as params and literals under casts and calls
+_CMP_OPS = ["<", "<=", ">", ">=", "=", "<>"]
+
+
+def _codes_frame():
+    """`_lineitem` with its ship dates drawn from 500 of the 2,526 days (few
+    enough for DICT at this size, as SF10's 2,526 are at 24M rows) and an
+    int64 column of 40 distinct values (an integer dictionary)."""
+    df = _lineitem()
+    rng = np.random.RandomState(11)
+    days = np.sort(rng.choice(2526, 500, replace=False))
+    return df.assign(
+        l_shipdate=np.datetime64("1992-01-01")
+        + days[rng.randint(0, 500, len(df))].astype("timedelta64[D]"),
+        l_code=(rng.randint(0, 40, len(df)) * 1001 + 7).astype(np.int64))
+
+
+@pytest.fixture(scope="module")
+def decoded():
+    """(frame, encodings-off context): what every case is held to."""
+    df = _codes_frame()
+    return df, _context(df, **{"columnar.encoding": "off"})
+
+
+def _ship_day(df, where):
+    """A day `d` (ISO text) such that ``d + 1 day`` lies `where` relative
+    to l_shipdate's dictionary: on an entry, between two, or outside."""
+    days = np.sort(df["l_shipdate"].unique()).astype("datetime64[D]")
+    one = np.timedelta64(1, "D")
+    if where == "on":
+        target = days[len(days) // 2]
+    elif where == "between":
+        inner = np.arange(days[0], days[-1], one)
+        target = inner[~np.isin(inner, days)][7]
+    else:
+        target = days[0] - 30 * one if where == "below" else days[-1] + 30 * one
+    return str(target - one)
+
+
+def _check_against_decoded(decoded, predicate, codespace, valuespace):
+    """One fresh encoded context answers ``WHERE predicate`` on the
+    compiled rung exactly as the encodings-off context does, and its
+    counters say where the predicate ran."""
+    df, c_off = decoded
+    c_enc = _context(df)
+    t = c_enc.schema["root"].tables["lineitem"].table
+    for name in ("l_shipdate", "l_discount", "l_code"):
+        assert t.columns[name].encoding is Encoding.DICT, name
+    sql = ("SELECT COUNT(*) AS n, SUM(l_quantity) AS s FROM lineitem "
+           f"WHERE {predicate}")
+    got = c_enc.sql(sql, return_futures=False)
+    ref = c_off.sql(sql, return_futures=False)
+    assert int(got["n"][0]) == int(ref["n"][0]), predicate
+    assert np.array_equal(got["s"].to_numpy(np.float64),
+                          ref["s"].to_numpy(np.float64),
+                          equal_nan=True), predicate
+    counter = c_enc.metrics.counter
+    assert counter("resilience.rung.compiled_aggregate") == 1, predicate
+    assert counter("columnar.encoding.codespace_pred") == codespace
+    assert counter("columnar.encoding.valuespace_pred") == valuespace
+    assert counter("columnar.encoding.decode") == 0
+    return int(got["n"][0])
+
+
+@pytest.mark.parametrize("flipped", [False, True], ids=["col_op_x", "x_op_col"])
+@pytest.mark.parametrize("op", _CMP_OPS)
+@pytest.mark.parametrize("column,comparand", [
+    ("l_shipdate", "DATE '1995-06-17' - INTERVAL '90' DAY"),
+    ("l_discount", "0.12 / 2"),  # `div` is not in the planners' _FOLDABLE
+], ids=["date_minus_days", "number_div"])
+def test_codespace_row_invariant_comparand(decoded, column, comparand, op,
+                                           flipped):
+    """All six operators, both operand orders: a date column against
+    ``DATE - INTERVAL 'n' DAY`` and a numeric one against a quotient."""
+    predicate = (f"{comparand} {op} {column}" if flipped
+                 else f"{column} {op} {comparand}")
+    n = _check_against_decoded(decoded, predicate, codespace=1, valuespace=0)
+    assert 0 < n < len(decoded[0])  # the case selects, it does not pass all
+
+
+@pytest.mark.parametrize("predicate", [
+    "l_shipdate < DATE '1994-01-01' + INTERVAL '1' YEAR",  # TPC-H Q6's text
+    "l_shipdate >= DATE '1994-01-31' + INTERVAL '1' MONTH",  # clamps to 02-28
+    "l_shipdate <= DATE '1996-02-29' + INTERVAL '1' YEAR",  # clamps to 02-28
+    "l_shipdate < DATE '1994-03-15' - INTERVAL '2' MONTH",
+    "l_shipdate > DATE '1994-01-01' + 45",  # integer days
+    "DATE '1994-01-01' + 45 >= l_shipdate",
+    "l_shipdate <= CAST(DATE '1994-01-01' + INTERVAL '10' DAY AS TIMESTAMP)",
+    "l_shipdate > TIMESTAMP '1995-06-01 12:00:00' - INTERVAL '1' HOUR",
+    "l_discount > 0.11 / 2",  # between two dictionary entries
+    "l_discount <> 0.11 / 2",
+    "l_discount >= 0.5 / 2",  # above every entry
+    "l_discount = 0.5 / 2",
+    "l_code <= CAST(28042.0 / 2 AS BIGINT)",  # integer dictionary: an entry
+    "l_code = CAST(28042.0 / 2 AS BIGINT)",
+    "l_code > CAST(28050.0 / 2 AS BIGINT)",  # and between two
+])
+def test_codespace_comparand_shapes(decoded, predicate):
+    """Year-month intervals (month-end clamps among them), integer days,
+    explicit CASTs, a TIMESTAMP less hours, and numeric comparands on,
+    between and outside the entries of a float and an integer dictionary."""
+    _check_against_decoded(decoded, predicate, codespace=1, valuespace=0)
+
+
+@pytest.mark.parametrize("op", ["=", "<>", "<=", ">"])
+@pytest.mark.parametrize("where", ["on", "between", "below", "above"])
+def test_codespace_date_on_between_outside_entries(decoded, where, op):
+    day = _ship_day(decoded[0], where)
+    _check_against_decoded(decoded, f"l_shipdate {op} DATE '{day}' + 1",
+                           codespace=1, valuespace=0)
+
+
+@pytest.mark.parametrize("predicate", [
+    "l_shipdate <= CAST(NULL AS DATE) + INTERVAL '1' DAY",  # a NULL literal
+    "l_discount <= 0.12 / 2 + l_quantity * 0",  # a column in the comparand
+    "l_code < 30037 / 2",  # integer division answers a zero divisor by NULL
+], ids=["null_literal", "column", "integer_div"])
+def test_comparand_not_row_invariant_falls_back_to_value_space(decoded,
+                                                               predicate):
+    _check_against_decoded(decoded, predicate, codespace=0, valuespace=1)
+
+
+def test_family_zero_recompile_on_interval_comparand(decoded):
+    """The second DELTA of ``DATE - INTERVAL 'n' DAY`` pays ZERO foreground
+    compiles: both parameters are evaluated and searched in-kernel."""
+    df, c_off = decoded
+    c = _context(df)
+
+    def q(delta):
+        return ("SELECT COUNT(*) AS n FROM lineitem WHERE l_shipdate <= "
+                f"DATE '1998-12-01' - INTERVAL '{delta}' DAY")
+
+    def compiles(tr):
+        return [s.name for s in tr.spans if s.name.startswith("compile:")]
+
+    first = c.sql(q(90), return_futures=False)
+    assert len(compiles(c.last_trace)) >= 1
+    second = c.sql(q(400), return_futures=False)
+    assert compiles(c.last_trace) == []
+    for got, delta in ((first, 90), (second, 400)):
+        assert int(got["n"][0]) == int(
+            c_off.sql(q(delta), return_futures=False)["n"][0])
+    assert int(second["n"][0]) < int(first["n"][0])
+    assert c.metrics.counter("columnar.encoding.codespace_pred") == 1
+    assert c.metrics.counter("columnar.encoding.valuespace_pred") == 0
+
+
+@pytest.fixture(scope="module")
+def bench_lineitem():
+    """perfbench's LINEITEM (all sixteen columns, pyarrow) at 40,000 rows:
+    enough for l_shipdate's 2,526 days to be DICT-encoded as at SF10."""
+    from perfbench.datagen import tpch_lineitem
+
+    arrays = tpch_lineitem.generate(40_000, seed=31, scale_factor=10)
+    return tpch_lineitem.arrow_tables(arrays)["lineitem"]
+
+
+@pytest.mark.parametrize("query", ["tpch_q1", "tpch_q1_interval", "tpch_q6"])
+def test_benchmark_texts_leave_no_predicate_in_value_space(bench_lineitem,
+                                                           query):
+    """The benchmark's three query texts, as its cells send them: every
+    predicate on a DICT column runs on the codes (`valuespace_pred` 0)."""
+    import random
+
+    from perfbench import traffic
+
+    c = _context(bench_lineitem)
+    t = c.schema["root"].tables["lineitem"].table
+    assert t.columns["l_shipdate"].encoding is Encoding.DICT
+    text = traffic.load("queries", query)
+    c.sql(traffic.render(text, traffic.draw_params(text, random.Random(3))),
+          return_futures=False)
+    assert c.metrics.counter("resilience.rung.compiled_aggregate") == 1
+    assert c.metrics.counter("columnar.encoding.codespace_pred") >= 1
+    assert c.metrics.counter("columnar.encoding.valuespace_pred") == 0
+    assert c.metrics.counter("columnar.encoding.decode") == 0
+
+
 def test_groupby_on_encoded_keys_matches_decoded():
     df = _lineitem()
     c_enc = _context(df)

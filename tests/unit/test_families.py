@@ -428,3 +428,79 @@ def test_family_fingerprint_is_deterministic():
     # separate Contexts/processes-worth of state, same statement shape:
     # same family fingerprint (the pre-warm/checkpoint contract)
     assert c.last_trace.fingerprint == c2.last_trace.fingerprint
+
+
+# ------------------------- the benchmark's Q1 texts: identity is a contract
+#: `physical/compiled.py`'s program-cache family for perfbench's literal-date
+#: Q1 over chip_smoke's lineitem, as every PR up to 31 built it.  The
+#: benchmark's other cells find their executables in the persistent XLA cache
+#: through this identity (and an unchanged traced body): a change here
+#: recompiles them.
+_Q1_LITERAL_FAMILY = (
+    "root", "lineitem", (), ("le(#6:l_shipdate, ?0:TIMESTAMP)",),
+    ("#0:l_returnflag", "#1:l_linestatus"),
+    ("sum(#2:l_quantity)", "sum(#3:l_extendedprice)",
+     "sum(mul(#3:l_extendedprice, sub(?1:DOUBLE, #4:l_discount)))",
+     "sum(mul(mul(#3:l_extendedprice, sub(?2:DOUBLE, #4:l_discount)), "
+     "add(?3:DOUBLE, #5:l_tax)))",
+     "avg(#2:l_quantity)", "avg(#3:l_extendedprice)", "avg(#4:l_discount)",
+     "count_star()"),
+    "auto")
+_Q1_INTERVAL_FILTER = ("le(#6:l_shipdate, CAST(datetime_sub_interval("
+                       "?0:DATE, ?1:INTERVAL_DAY_TIME) AS TIMESTAMP))")
+
+
+@pytest.fixture
+def q1_families(monkeypatch):
+    """A context over chip_smoke's lineitem, and every ``(family, bucket)``
+    `try_compiled_aggregate` asks its program cache for."""
+    import chip_smoke
+    from dask_sql_tpu.physical import compiled
+
+    asked = []
+    get_or_build = compiled.PROGRAMS.get_or_build
+
+    def spy(ctx, family, bucket, construct, **kwargs):
+        asked.append((family, bucket))
+        return get_or_build(ctx, family, bucket, construct, **kwargs)
+
+    monkeypatch.setattr(compiled.PROGRAMS, "get_or_build", spy)
+    c = Context()
+    c.config.update({"serving.cache.enabled": False})
+    c.create_table("lineitem", chip_smoke.gen_lineitem(4096, seed=0))
+    return c, asked
+
+
+def test_q1_literal_text_keeps_its_family_and_program_key(q1_families):
+    from perfbench import traffic
+
+    c, asked = q1_families
+    c.sql(traffic.render(traffic.load("queries", "tpch_q1"), {"DELTA": 90}),
+          return_futures=False)
+    assert [family for family, _ in asked] == [_Q1_LITERAL_FAMILY]
+
+
+def test_q1_interval_text_is_one_family_one_program_one_compile(q1_families):
+    """Q1 as the specification prints it, over two DELTAs: ONE family (the
+    date and the interval are two runtime scalars), one program-cache
+    entry, one ``compile:`` span."""
+    from dask_sql_tpu.physical import compiled
+    from perfbench import traffic
+
+    c, asked = q1_families
+    query = traffic.load("queries", "tpch_q1_interval")
+    compiles, prints = [], set()
+    for delta in (90, 61):
+        c.sql(traffic.render(query, {"DELTA": delta}), return_futures=False)
+        compiles += _compiles(c.last_trace)
+        prints.add(c.last_trace.fingerprint)
+    assert compiles == ["compile:compiled_aggregate"]
+    assert len(prints) == 1
+    assert len(asked) == 2 and asked[0] == asked[1]
+    family, bucket = asked[0]
+    assert family[3] == (_Q1_INTERVAL_FILTER,)
+    assert family[:3] + family[4:5] == \
+        _Q1_LITERAL_FAMILY[:3] + _Q1_LITERAL_FAMILY[4:5]
+    assert [key for key, _ in compiled.PROGRAMS.items()
+            if key[1] == bucket] == [(family, bucket)]
+    assert c.metrics.counter("families.hit") == 1

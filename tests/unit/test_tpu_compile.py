@@ -16,6 +16,7 @@ rows, in the dtypes `Context.create_table` encodes the smoke's frame to.
 No sort-based program is compiled here (64-bit sorts take minutes).
 """
 import os
+import re
 
 import numpy as np
 import pytest
@@ -68,7 +69,9 @@ def one_chip(topo):
 def captured():
     """Run the smoke's Q1 and Q6 at a small size on the CPU — single-chip
     and row-sharded — and keep what the compiled rungs were built from:
-    ``{"q1": (pipeline, table, params), ..., "spmd_q1": (ctor args, params)}``.
+    ``{"q1": (pipeline, table, params), ..., "spmd_q1": (ctor args, params)}``;
+    ``"q1_interval"`` is Q1 as the specification prints it (perfbench's
+    ``tpch_q1_interval`` text, DELTA 90) over the same table.
     The segment sum is steered to what ``auto`` picks on a TPU for these
     domains (the blocked matmul) through the existing config key."""
     import chip_smoke
@@ -109,6 +112,13 @@ def captured():
                   config_options={"sql.compile.segsum": "matmul"}).compute()
             out[name] = runs[-1]
             assert out[name][0].segsum_mode == "matmul"
+        from perfbench import traffic
+
+        c.sql(traffic.render(traffic.load("queries", "tpch_q1_interval"),
+                             {"DELTA": 90}),
+              config_options={"sql.compile.segsum": "matmul"}).compute()
+        out["q1_interval"] = runs[-1]
+        assert out["q1_interval"][0] is not out["q1"][0]
         sharded = Context()
         sharded.create_table("lineitem", df, distributed=True)
         sharded.sql(chip_smoke.QUERIES["q1"]).compute()
@@ -116,7 +126,6 @@ def captured():
         out["spmd_q1"] = (ctors[-1], runs[-1][2])
         # the benchmark's four-chip cell (sf10_q1_sharded_4chip): all
         # sixteen columns of perfbench's LINEITEM from pyarrow, its own Q1
-        from perfbench import traffic
         from perfbench.datagen import tpch_lineitem
 
         arrays = tpch_lineitem.generate(SMALL_ROWS, seed=29, scale_factor=10)
@@ -179,10 +188,48 @@ def test_pallas_segsum_compiles(one_chip, domain):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-@pytest.mark.parametrize("name", ["q1", "q6"])
+def _row_gathers(text, rows):
+    """``(operand types, result type)`` of every gather in a lowered
+    (StableHLO) text that has `rows` as a dimension on either side."""
+    found = re.findall(r'"?stablehlo\.gather"?\(.*?:\s*\((.*?)\)\s*->\s*(\S+)',
+                       text)
+    return [(ins, out) for ins, out in found
+            if re.search(rf"<(\d+x)*{rows}x", ins + " " + out)]
+
+
+def test_q1_interval_decodes_no_ship_date_per_row(captured):
+    """Structural, off-chip: Q1 as the specification prints it
+    (``l_shipdate <= DATE '1998-12-01' - INTERVAL '90' DAY``) compares the
+    DICT-encoded ship date in CODE space: lowered for the CPU at
+    `SMALL_ROWS`, the program gathers nothing per row through the date's
+    int64 dictionary (on a TPU: two ``u32[rows]`` gathers, 4/5 of the
+    device's time before ISSUE 32).  The per-row gathers it does hold are the
+    literal text's, type for type: the float64 dictionaries of l_quantity,
+    l_discount and l_tax, which the sums read as values."""
+    lut = np.arange(2526, dtype=np.int64)
+    control = jax.jit(lambda codes: jnp.asarray(lut)[codes]).lower(
+        jax.ShapeDtypeStruct((SMALL_ROWS,), jnp.int16)).as_text()
+    assert [out for _, out in _row_gathers(control, SMALL_ROWS)] \
+        == [f"tensor<{SMALL_ROWS}xi64>"]
+
+    def lowered(name):
+        pipeline, table, params = captured[name]
+        datas, valids = _column_shapes(table, SMALL_ROWS, None)
+        return jax.jit(pipeline._fn_raw).lower(
+            datas, valids, None, _shapes(tuple(params), None)).as_text()
+
+    gathers = _row_gathers(lowered("q1_interval"), SMALL_ROWS)
+    assert gathers and not [g for g in gathers if "i64>" in g[0] + g[1]]
+    assert sorted(gathers) == sorted(_row_gathers(lowered("q1"), SMALL_ROWS))
+
+
+@pytest.mark.parametrize("name", ["q1", "q6", "q1_interval"])
 def test_compiled_aggregate_fits_one_chip_at_sf10(one_chip, captured, name):
     """The jitted pipeline `physical/compiled.py` builds for the query, at
-    60M rows of the encoded dtypes, with the table resident beside it."""
+    60M rows of the encoded dtypes, with the table resident beside it.
+    ``q1_interval`` is Q1 in the specification's ``DATE - INTERVAL`` text
+    (two runtime scalars searched in the date dictionary in-kernel); the
+    case costs the suite 3.2 s at 60M rows, as ``q1`` does (ROADMAP D11)."""
     pipeline, table, params = captured[name]
     datas, valids = _column_shapes(table, ROWS, one_chip)
     compiled = jax.jit(pipeline._fn_raw).lower(
