@@ -23,7 +23,13 @@ Eligibility is deliberately conservative — a literal stays baked whenever
 the compiled evaluators consume it at *trace* time:
 
 - string literals (dictionary lookup tables are built per value at
-  compile time), and NULL literals (validity shape is structural);
+  compile time), and NULL literals (validity shape is structural).  One
+  kind of string literal does parameterize, where the caller hands
+  `rewrite` the columns' dictionaries (the compiled join pipeline, for
+  the conjuncts of a build side it keeps whole): ``col = 'v'`` /
+  ``col <> 'v'`` on a dictionary-coded string column becomes a comparison
+  of the codes with a runtime code, looked up in the dictionary at bind
+  time (-1, a code no row holds, where the dictionary lacks the value);
 - LIKE / ILIKE / SIMILAR patterns and escapes (host-compiled regexes);
 - DATE_TRUNC / CEIL unit arguments (static truncation unit);
 - plan-node integer fields (LIMIT windows, sort fetch, sample fraction,
@@ -74,6 +80,10 @@ logger = logging.getLogger(__name__)
 _PARAM_TYPES = frozenset(
     NUMERIC_TYPES | DATETIME_TYPES | INTERVAL_TYPES | {SqlType.BOOLEAN,
                                                        SqlType.DECIMAL})
+
+#: dictionaries up to this many entries are searched for a string literal's
+#: code at bind time; a longer one keeps its literals baked
+_CODE_LOOKUP_ENTRIES = 1 << 16
 
 #: ops whose TRAILING arguments the compiled evaluators read at trace time
 #: (regex compilation, truncation units) — only args[0] may parameterize
@@ -141,20 +151,34 @@ class Parameterizer:
         self.values: List[np.ndarray] = []
         #: hashable mirror of `values` for result-cache keys
         self.key_values: List[Any] = []
+        #: column index -> string dictionary, for the span of one `rewrite`
+        self._dictionary_of = None
 
     @property
     def params(self) -> Tuple[np.ndarray, ...]:
         return tuple(self.values)
 
     # -------------------------------------------------------- expressions
-    def rewrite(self, expr: Expr) -> Expr:
+    def rewrite(self, expr: Expr, dictionary_of=None) -> Expr:
+        """``dictionary_of(column index)`` gives the string dictionary of a
+        column `expr`'s plain refs index, or None: with it a string literal
+        compared for (in)equality with such a column rides as its code."""
         if not self.enabled or expr is None:
             return expr
-        return self._rewrite(expr)
+        self._dictionary_of = dictionary_of
+        try:
+            return self._rewrite(expr)
+        finally:
+            self._dictionary_of = None
 
     def _rewrite(self, e: Expr) -> Expr:
         if isinstance(e, Literal):
             return self._maybe_param(e)
+        if self._dictionary_of is not None and isinstance(e, ScalarFunc) \
+                and e.op in ("eq", "ne") and len(e.args) == 2:
+            coded = self._string_code_compare(e)
+            if coded is not None:
+                return coded
         if isinstance(e, InListExpr):
             return self._rewrite_in_list(e)
         if isinstance(e, ScalarFunc) and e.op in _STATIC_TAIL_OPS and e.args:
@@ -191,6 +215,30 @@ class Parameterizer:
         self.values.append(value)
         self.key_values.append(value.item())
         return ParamRef(index, lit.sql_type)
+
+    def _string_code_compare(self, e: ScalarFunc) -> Optional[Expr]:
+        """``string column (=|<>) 'literal'`` as the column against the
+        literal's dictionary code, a runtime INTEGER parameter; None where
+        the shape is another or the column's dictionary is not at hand."""
+        from ..columnar.dtypes import STRING_TYPES
+        from ..planner.expressions import ColumnRef
+
+        for col, lit in (e.args, e.args[::-1]):
+            if not (type(col) is ColumnRef and col.sql_type in STRING_TYPES
+                    and isinstance(lit, Literal)
+                    and isinstance(lit.value, str)):
+                continue
+            dictionary = self._dictionary_of(col.index)
+            if dictionary is None or len(dictionary) > _CODE_LOOKUP_ENTRIES:
+                return None
+            found = np.flatnonzero(np.asarray(dictionary) == lit.value)
+            code = int(found[0]) if len(found) else -1
+            index = len(self.values)
+            self.values.append(np.asarray(code, dtype=np.int32))
+            self.key_values.append(code)
+            return dataclasses.replace(
+                e, args=(col, ParamRef(index, SqlType.INTEGER)))
+        return None
 
     def _rewrite_in_list(self, e: InListExpr) -> Expr:
         from ..columnar.dtypes import STRING_TYPES

@@ -163,13 +163,21 @@ def _dense_match(lgid, rgid):
     return matched, ri_cand
 
 
-def dense_unique_lut(key: jnp.ndarray, valid=None):
-    """(rmin, lut) for a unique-dense-int key column, or None if ineligible.
+def dense_unique_lut(key: jnp.ndarray, valid=None,
+                     max_bytes: Optional[int] = None):
+    """(rmin, lut) for a unique-int key column, or None if ineligible.
 
     lut[v - rmin] = row index holding key v, -1 where no row does.  NULL
-    rows (valid=False) never enter the table.  Shares the eligibility rules
-    of _dense_match; used by the compiled join pipeline, which builds LUTs
-    eagerly per build table and probes inside one jit."""
+    rows (valid=False) never enter the table.  Duplicate and non-integer
+    keys decline.  How wide a key range may be depends on who pays:
+
+    * ``max_bytes=None``: a table built for ONE query (the broadcast join,
+      an eagerly executed build side) shares `_dense_match`'s density rule,
+      a small multiple of the rows it was built from;
+    * ``max_bytes=n``: a table built once per table version and kept (the
+      compiled join pipeline's whole build sides) is admitted by what it
+      costs to hold, 4 bytes a key of the range, whatever a later filter
+      selects of its rows: TPC-H's order keys use 8 of every 32."""
     nr = int(key.shape[0])
     if nr == 0 or not jnp.issubdtype(key.dtype, jnp.integer):
         return None
@@ -185,7 +193,9 @@ def dense_unique_lut(key: jnp.ndarray, valid=None):
     else:
         rmin, rmax = host_ints(*_minmax(k))
     size = rmax - rmin + 1
-    if size <= 0 or size > max(_DENSE_RANGE_SLACK * nr, _DENSE_RANGE_FLOOR):
+    widest = max(_DENSE_RANGE_SLACK * nr, _DENSE_RANGE_FLOOR) \
+        if max_bytes is None else int(max_bytes) // 4
+    if size <= 0 or size > widest:
         return None
     idx = k - rmin
     if valid is not None:

@@ -844,6 +844,14 @@ class _TraceEval:
         if op in ("eq", "ne", "like", "ilike", "similar") and len(args) >= 2:
             src = self._string_source(args[0])
             lit = args[1]
+            if src is not None and isinstance(lit, ParamRef) \
+                    and op in ("eq", "ne"):
+                # the literal's dictionary code as a runtime parameter
+                # (families/parameterize.py::_string_code_compare)
+                codes, valid = slots[args[0].index]
+                hit = codes == slots[PARAMS_SLOT][lit.index].astype(
+                    codes.dtype)
+                return (~hit if op == "ne" else hit, valid)
             if src is not None and isinstance(lit, Literal) and isinstance(lit.value, str):
                 d = src.dictionary if src.dictionary is not None else np.array([""], dtype=object)
                 if op in ("eq", "ne"):

@@ -9,8 +9,19 @@ at most ONE build row, so the entire pipeline — scan filters, N pointer
 joins, projection arithmetic, segment aggregation — is static-shaped and
 fuses into a single XLA program over the probe table:
 
-    build sides  : executed eagerly (small after filters), value-indexed
-                   LUTs scattered once per table version
+    build sides  : a filtered base-table scan stays WHOLE: its value-indexed
+                   LUT is scattered once per table version over the
+                   UNFILTERED key column and kept (`LUTS`), its conjuncts
+                   are parameterised like the probe's (a string literal on
+                   a dictionary-coded column rides as its code) and
+                   evaluated in the program as a mask over the build
+                   table's rows, read through the pointer.  So neither the
+                   program's shapes nor its identity depend on the
+                   literals: one executable per plan family and table
+                   version.  Any other build side (a nested join, an
+                   aggregate, computed columns, a key the LUT rule
+                   declines) is executed eagerly per request as before,
+                   its LUT built from the filtered rows
     probe side   : filters become masks (nothing compacts), joins become
                    `lut[key - rmin]` gathers carrying a matched mask,
                    build columns materialize as gathers through the pointer
@@ -18,12 +29,20 @@ fuses into a single XLA program over the probe table:
                    join's key) make the build-row pointer itself the segment
                    id — no factorize, no sort; segment reductions land at
                    HBM bandwidth
+    tail         : under ORDER BY .. LIMIT k (`TopK`, handed down by the
+                   Sort above) the k first groups are selected inside the
+                   program over the `[domain]` state, exactly, and only
+                   `[rows, k]` leaves the device: static shapes whatever
+                   the parameters select.  Otherwise the present groups are
+                   compacted on the device before the pull
 
-One device sync for the whole query (the group-presence compaction).
+One device sync for the whole query (the pull of the packed rows).
 """
 from __future__ import annotations
 
 import logging
+import threading
+from collections import OrderedDict
 from dataclasses import dataclass, replace as _rp
 from typing import Dict, List, Optional, Tuple
 
@@ -48,6 +67,7 @@ from ..columnar.encodings import Encoding
 from .compiled import (
     PARAMS_SLOT,
     _ColMeta,
+    _TableMeta,
     _TraceEval,
     _Unsupported,
     check_agg_static_support,
@@ -62,6 +82,12 @@ from .programs import ProgramCache
 logger = logging.getLogger(__name__)
 
 _MAX_JOINS = 6
+#: ORDER BY .. LIMIT k is selected inside the program up to this k (one
+#: round of masked reductions over the group domain per row)
+_MAX_TOPK = 64
+#: widest LUT (bytes) a whole build side may keep resident; a configured
+#: device budget (``analysis.estimate.device_budget_bytes``) below it holds
+_LUT_MAX_BYTES = 1 << 30
 
 
 @dataclass(frozen=True)
@@ -82,7 +108,10 @@ class _Extraction:
     def __init__(self):
         self.scan: Optional[p.TableScan] = None
         self.conjuncts: List[Expr] = []  # over global space (probe + _BuildRef)
-        self.joins: List[dict] = []  # {"plan": right subplan, "lkey", "rkey"}
+        #: {"plan": right subplan, "lkey", "rkey"}; `_plan_whole_builds`
+        #: adds "whole": the build side's own conjuncts where it is a
+        #: filtered base-table scan, else None
+        self.joins: List[dict] = []
 
 
 def _rewrite(expr: Expr, slots: List[Expr]) -> Expr:
@@ -156,6 +185,132 @@ def _extract(agg: p.Aggregate):
     return ext, group_exprs, agg_exprs
 
 
+def _plan_whole_builds(ext: _Extraction, group_exprs, agg_exprs):
+    """Mark the build sides that are a filtered scan of ONE base table
+    (Filter / SubqueryAlias / column-picking Projection over a TableScan):
+    ``join["whole"]`` gets their conjuncts over the scan's schema and
+    ``join["plan"]`` becomes that scan with the conjuncts as its filters,
+    so that "build table k" is the scan's own (projected) table whether the
+    join keeps it whole or, the LUT rule declining, executes it eagerly.
+    `_BuildRef`s and the build key are re-based from the subplan's output
+    onto the scan's columns.  Returns the re-based (group_exprs, agg_exprs);
+    other build sides are left as they are."""
+    rebase: Dict[int, List[int]] = {}
+    for k, j in enumerate(ext.joins):
+        j["whole"] = None
+        sub = _Extraction()
+        slots = _walk_left_spine(j["plan"], sub)
+        if slots is None or sub.scan is None or sub.joins \
+                or not all(type(x) is ColumnRef for x in slots):
+            continue
+        rebase[k] = [x.index for x in slots]
+        j["whole"] = list(sub.conjuncts)
+        j["rkey"] = _rewrite(j["rkey"], slots)
+        j["plan"] = _rp(sub.scan, filters=list(sub.conjuncts))
+
+    def fn(x):
+        if isinstance(x, _BuildRef) and x.k in rebase:
+            return _rp(x, col=rebase[x.k][x.col])
+        return x
+
+    ext.conjuncts = [transform(e, fn) for e in ext.conjuncts]
+    for j in ext.joins:
+        j["lkey"] = transform(j["lkey"], fn)
+    group_exprs = [transform(e, fn) for e in group_exprs]
+    agg_exprs = [
+        _rp(a, args=tuple(transform(x, fn) for x in a.args),
+            filter=transform(a.filter, fn) if a.filter is not None else None)
+        for a in agg_exprs]
+    return group_exprs, agg_exprs
+
+
+@dataclass(frozen=True)
+class TopK:
+    """ORDER BY .. LIMIT above an Aggregate, as the Sort plugin hands it
+    down (`Executor.topk_hints`): the first `k` rows by `keys`, each
+    ``(aggregate output column, ascending, nulls first)``.  A rung that
+    takes the hint returns those rows alone, in that order; one that does
+    not returns every group and the Sort above does the work."""
+
+    k: int
+    keys: Tuple[Tuple[int, bool, bool], ...]
+
+
+def select_topk(alive, keys, k: int):
+    """The `k` first of the rows where `alive`, by `keys` = ``[(data,
+    validity or None, ascending, nulls first)]`` and then by position, under
+    trace: ``(positions int32[k], found bool[k])``.  Exact in the keys' own
+    dtypes: one round per row narrows the candidates key by key with masked
+    min / max reductions, so no 64-bit sort and no shape that depends on
+    how many rows are alive.  NaN orders as the largest value, as
+    `ops/sorting.py` has it."""
+
+    def narrow(cand, d, v, asc, nulls_first):
+        if d.dtype == jnp.bool_:
+            d = d.astype(jnp.int32)
+        if jnp.issubdtype(d.dtype, jnp.floating):
+            d = jnp.where(jnp.isnan(d), jnp.inf, d)
+            lo, hi = -jnp.inf, jnp.inf
+        else:
+            lo, hi = jnp.iinfo(d.dtype).min, jnp.iinfo(d.dtype).max
+        vals = cand if v is None else cand & v
+        best = jnp.min(jnp.where(vals, d, hi)) if asc \
+            else jnp.max(jnp.where(vals, d, lo))
+        on_value = vals & (d == best)
+        if v is None:
+            return on_value
+        nulls = cand & ~v
+        take_nulls = jnp.any(nulls) if nulls_first else ~jnp.any(vals)
+        return jnp.where(take_nulls, nulls, on_value)
+
+    def pick(i, state):
+        alive, at, found = state
+        cand = alive
+        for d, v, asc, nulls_first in keys:
+            cand = narrow(cand, d, v, asc, nulls_first)
+        first = jnp.argmax(cand).astype(jnp.int32)
+        return (alive.at[first].set(False), at.at[i].set(first),
+                found.at[i].set(cand[first]))
+
+    _, at, found = jax.lax.fori_loop(
+        0, k, pick, (alive, jnp.zeros(k, dtype=jnp.int32),
+                     jnp.zeros(k, dtype=bool)))
+    return at, found
+
+
+class LutCache:
+    """The LUTs of whole build sides, one per (table version, key column,
+    byte budget), built on first use and kept: bounded, least recently used
+    first out, so a replaced table's LUT cannot pin device memory for good.
+    A key the rule declines is remembered as None."""
+
+    def __init__(self, cap: int):
+        self.cap = cap
+        self._entries: "OrderedDict[Tuple, Optional[Tuple]]" = OrderedDict()
+        self._lock = threading.Lock()
+
+    def get_or_build(self, key: Tuple, build):
+        """``(entry, built_here)``; concurrent first uses may both build,
+        the later insert wins (the tables are equal)."""
+        with self._lock:
+            if key in self._entries:
+                self._entries.move_to_end(key)
+                return self._entries[key], False
+        entry = build()
+        with self._lock:
+            self._entries[key] = entry
+            while len(self._entries) > self.cap:
+                self._entries.popitem(last=False)
+        return entry, True
+
+    def clear(self) -> None:
+        with self._lock:
+            self._entries.clear()
+
+
+LUTS = LutCache(16)
+
+
 def _choose_gid_join(ext, group_exprs) -> Optional[Tuple[int, List[int]]]:
     """Find a join k whose build-row pointer can serve as the segment id.
 
@@ -191,6 +346,16 @@ def _choose_gid_join(ext, group_exprs) -> Optional[Tuple[int, List[int]]]:
     return None
 
 
+def build_lut(executor, rkey: Expr, table: Table,
+              max_bytes: Optional[int] = None):
+    """``(rmin, lut)`` of `table`'s join key `rkey` (`dense_unique_lut`,
+    which says what `max_bytes` means), or None where the key declines."""
+    kc = executor.eval_expr(rkey, table).decode()
+    if kc.sql_type in STRING_TYPES:
+        return None
+    return dense_unique_lut(kc.data, kc.validity, max_bytes=max_bytes)
+
+
 class _SlotMeta:
     """Duck-typed stand-in for Table inside _TraceEval: column metadata for
     the extended slot space (probe scan columns + gathered build columns)."""
@@ -205,11 +370,17 @@ class CompiledJoinAggregate:
 
     def __init__(self, rel: p.Aggregate, ext: _Extraction, group_exprs,
                  agg_exprs, probe_table: Table, build_tables: List[Table],
-                 executor):
+                 executor, whole: Optional[List[Optional[dict]]] = None,
+                 topk: Optional[TopK] = None):
+        """``whole[k]``: None where build table k was executed eagerly (its
+        LUT is built here, from the rows it has left), else ``{"conjuncts":
+        the build side's own parameterised conjuncts, "lut": (rmin, lut)}``
+        with build table k the base table's scan, unfiltered."""
         self.rel = rel
         self.ext = ext
         self.probe_table = probe_table
         self.build_tables = build_tables
+        whole = whole if whole is not None else [None] * len(build_tables)
 
         check_agg_static_support(agg_exprs)
         check_no_rle(probe_table)
@@ -230,28 +401,34 @@ class CompiledJoinAggregate:
             self.radix_spec = self._plan_radix(group_exprs, probe_table,
                                                build_tables)
 
-        # eager per-build prep: key column + LUT (reused across runs of the
-        # same table version via the plugin-level cache)
+        # per-build prep: a whole build side brings the LUT of its table
+        # version (`LUTS`); an eagerly executed one gets its own here
         self.luts: List[Tuple[int, jnp.ndarray]] = []
-        rkeys = []
-        for j, bt in zip(ext.joins, build_tables):
-            kc = executor.eval_expr(j["rkey"], bt)
-            if kc.sql_type in STRING_TYPES:
-                raise _Unsupported("string join key")
-            prep = dense_unique_lut(kc.data, kc.validity)
+        for j, bt, w in zip(ext.joins, build_tables, whole):
+            prep = w["lut"] if w is not None else build_lut(
+                executor, j["rkey"], bt)
             if prep is None:
                 raise _Unsupported("build keys not unique-dense ints")
             self.luts.append(prep)
-            rkeys.append(kc)
+        #: per join: the conjuncts evaluated over the WHOLE build table in
+        #: the program (None: the build side came filtered)
+        self.build_conjuncts: List[Optional[List[Expr]]] = [
+            None if w is None else list(w["conjuncts"]) for w in whole]
+        self._build_evs = [
+            None if w is None else _TraceEval(_TableMeta(bt))
+            for bt, w in zip(build_tables, whole)]
 
         # global slot space: probe scan columns, then every _BuildRef used
         n_probe = len(probe_table.column_names)
         used: Dict[Tuple[int, int], int] = {}
-        all_exprs = (ext.conjuncts + [j["lkey"] for j in ext.joins]
-                     + [x for a in agg_exprs for x in a.args]
-                     + [a.filter for a in agg_exprs if a.filter is not None])
+        rest = (ext.conjuncts + [x for a in agg_exprs for x in a.args]
+                + [a.filter for a in agg_exprs if a.filter is not None])
         if self.radix_spec is not None:
-            all_exprs = all_exprs + list(group_exprs)
+            rest = rest + list(group_exprs)
+        #: join k -> the WHOLE build side j whose rows it is probed from
+        self.folded = self._plan_folds(ext, whole, rest)
+        all_exprs = rest + [j["lkey"] for k, j in enumerate(ext.joins)
+                            if k not in self.folded]
         for e in all_exprs:
             for sub in walk(e):
                 if isinstance(sub, _BuildRef):
@@ -267,8 +444,16 @@ class CompiledJoinAggregate:
 
             return transform(expr, fn)
 
+        def onto_build(expr):
+            """A folded join's key over ITS parent build table's columns."""
+            return transform(expr, lambda x: ColumnRef(
+                x.col, f"__b{x.k}_{x.col}", x.sql_type, x.nullable)
+                if isinstance(x, _BuildRef) else x)
+
         self.conjuncts = [finalize(e) for e in ext.conjuncts]
-        self.lkeys = [finalize(j["lkey"]) for j in ext.joins]
+        self.lkeys = [onto_build(j["lkey"]) if k in self.folded
+                      else finalize(j["lkey"])
+                      for k, j in enumerate(ext.joins)]
         if self.radix_spec is not None:
             self.radix_spec = [dict(s, ref=finalize(s["ref"]),
                                     col=_ColMeta(s["col"]))
@@ -294,6 +479,11 @@ class CompiledJoinAggregate:
                 + [x for a in self.agg_exprs for x in list(a.args)
                    + ([a.filter] if a.filter is not None else [])],
                 self._ev.table) if self.has_encoded else (0, 0)
+        for conj, bev in zip(self.build_conjuncts, self._build_evs):
+            if conj:
+                code, value = count_codespace_predicates(conj, bev.table)
+                self.codespace_preds += code
+                self.valuespace_preds += value
         # segment-reduction strategy: one mode per pipeline, chosen from the
         # (static) group domain — radix product, or the gid build table's
         # row count for pointer gids
@@ -309,11 +499,92 @@ class CompiledJoinAggregate:
 
         self.domain = domain_est
         self.segsum_mode = choose_segsum_impl(executor.config, domain_est)
+        self.topk = self._plan_topk(topk, build_tables)
+        #: every build column the program is handed: gathered through a
+        #: pointer, read by a build side's own conjuncts, or a group key the
+        #: top-k tail orders by and returns
+        keys = set(used)
+        for k, conj in enumerate(self.build_conjuncts):
+            for e in conj or ():
+                keys.update((k, sub.index) for sub in walk(e)
+                            if type(sub) is ColumnRef)
+        for k, j in self.folded.items():
+            keys.update((j, sub.index) for sub in walk(self.lkeys[k])
+                        if type(sub) is ColumnRef)
+        if self.topk is not None:
+            keys.update((self.gid_join, col) for col in self.topk["cols"])
+        self.build_col_keys = sorted(keys)
         #: (kind, np.dtype) per packed output row; filled when _fn traces
         self._pack_tags: List[Tuple[str, np.dtype]] = []
         self._fn = jax.jit(self._build())
         #: compile-watchdog hint: True after _fn compiled for these shapes
         self._warm = False
+
+    def _plan_folds(self, ext, whole, rest) -> Dict[int, int]:
+        """Joins to probe from an earlier build side's rows instead of the
+        probe table's: join k whose key reads columns of ONE whole build
+        side j alone (ORDERS -> CUSTOMER under LINEITEM -> ORDERS), where
+        nothing but such joins reads build k's columns.  Its match then
+        narrows build j's row mask, at j's row count, and the probe pays
+        neither the gather of the key through j's pointer nor the second
+        LUT's.  `rest`: every expression evaluated at the probe's rows
+        other than the join keys."""
+        folded: Dict[int, int] = {}
+
+        def reads(exprs, k):
+            return any(isinstance(sub, _BuildRef) and sub.k == k
+                       for e in exprs for sub in walk(e))
+
+        for k in range(len(ext.joins) - 1, 0, -1):
+            subs = list(walk(ext.joins[k]["lkey"]))
+            parents = {sub.k for sub in subs if isinstance(sub, _BuildRef)}
+            if len(parents) != 1 or any(type(sub) is ColumnRef
+                                        for sub in subs):
+                continue
+            (j,) = parents
+            later = [ext.joins[m]["lkey"] for m in range(k + 1,
+                                                         len(ext.joins))
+                     if folded.get(m) != k]
+            if whole[j] is not None and k != self.gid_join \
+                    and not reads(rest + later, k):
+                folded[k] = j
+        return folded
+
+    def _plan_topk(self, topk: Optional[TopK], build_tables):
+        """The top-k tail this program runs, or None where the hint is
+        absent or names what the tail cannot order: ``{"k", "keys": [(kind,
+        index, ascending, nulls first)], "cols"}`` with kind ``"agg"`` (an
+        aggregate's output) or ``"group"`` (a group key: a column of the
+        pointer-gid build table, ordered on its stored integers, which DICT,
+        FOR and PLAIN all keep in value order), and ``cols`` the group-key
+        columns whose rows the program gathers for the result."""
+        if topk is None or not 0 < topk.k <= _MAX_TOPK:
+            return None
+        if self.gid_join is None or self.gid_join < 0:
+            return None  # a radix domain is small: pulled whole as before
+        bt = build_tables[self.gid_join]
+        n_groups = len(self.group_cols)
+
+        def sortable(col) -> bool:
+            enc = getattr(col, "encoding", Encoding.PLAIN)
+            if enc is Encoding.RLE or col.sql_type in STRING_TYPES:
+                return False
+            return enc is not Encoding.FOR or col.enc_scale > 0
+
+        keys = []
+        for index, asc, nulls_first in topk.keys:
+            if index >= n_groups:
+                keys.append(("agg", index - n_groups, asc, nulls_first))
+                continue
+            bcol = self.group_cols[index]
+            if not sortable(bt.columns[bt.column_names[bcol]]):
+                return None
+            keys.append(("group", bcol, asc, nulls_first))
+        cols = sorted({
+            c for c in self.group_cols
+            if getattr(bt.columns[bt.column_names[c]], "encoding",
+                       Encoding.PLAIN) is not Encoding.RLE})
+        return {"k": topk.k, "keys": keys, "cols": cols}
 
     @staticmethod
     def _plan_radix(group_exprs, probe_table, build_tables):
@@ -385,6 +656,10 @@ class CompiledJoinAggregate:
         radix_spec = self.radix_spec
         n_joins = len(self.ext.joins)
         rmins = [rmin for rmin, _ in self.luts]
+        build_conjuncts = self.build_conjuncts
+        build_evs = self._build_evs
+        folded = self.folded
+        topk = self.topk
 
         def fn(probe_datas, probe_valids, luts, build_cols, row_valid,
                params=()):
@@ -397,10 +672,8 @@ class CompiledJoinAggregate:
             # join match, filter, and reduction (exact-spec sharding)
             mask = jnp.ones(n_rows, dtype=bool) if row_valid is None \
                 else row_valid
-            ri_safe: List[jnp.ndarray] = []
-            for k in range(n_joins):
-                kd, kv = ev.eval(lkeys[k], slots)
-                lut = luts[k]
+            def pointer(k, kd, kv, lut):
+                """Build-row index per key of join `k` (-1: no row)."""
                 size = lut.shape[0]
                 # widen sub-int32 keys before subtracting (narrow dtypes can
                 # overflow under `key - rmin`); if rmin itself doesn't fit
@@ -431,12 +704,45 @@ class CompiledJoinAggregate:
                     inb = (idx >= 0) & (idx < size)
                 idx32 = jnp.clip(idx, 0, size - 1).astype(jnp.int32)
                 ri = jnp.where(inb, lut[idx32].astype(jnp.int32), jnp.int32(-1))
-                if kv is not None:
-                    ri = jnp.where(kv, ri, -1)
+                return ri if kv is None else jnp.where(kv, ri, -1)
+
+            def kept_lut(k):
+                """LUT `k` without the rows that build side k's own filters
+                reject, or a join probed from its rows (`folded`) leaves
+                unmatched: the mask is evaluated over ITS rows and folded
+                into the pointers the LUT holds (gathers of the build's and
+                the LUT's size, none of the probe's), so a key whose row
+                falls out finds no row."""
+                lut = luts[k]
+                if build_evs[k] is None:
+                    return lut  # an eagerly executed build side came filtered
+                bslots = {col: build_cols[(bk, col)]
+                          for (bk, col) in build_cols if bk == k}
+                bslots[PARAMS_SLOT] = params
+                keep = None
+                for f in build_conjuncts[k]:
+                    d, v = build_evs[k].eval(f, bslots)
+                    d = d if v is None else (d & v)
+                    keep = d if keep is None else (keep & d)
+                for m, parent in folded.items():
+                    if parent == k:
+                        kd, kv = build_evs[k].eval(lkeys[m], bslots)
+                        hit = pointer(m, kd, kv, kept_lut(m)) >= 0
+                        keep = hit if keep is None else (keep & hit)
+                if keep is None:
+                    return lut
+                return jnp.where(keep[jnp.clip(lut, 0, None)], lut, -1)
+
+            ri_safe: Dict[int, jnp.ndarray] = {}
+            for k in range(n_joins):
+                if k in folded:
+                    continue
+                kd, kv = ev.eval(lkeys[k], slots)
+                ri = pointer(k, kd, kv, kept_lut(k))
                 matched = ri >= 0
                 mask = mask & matched
                 safe = jnp.clip(ri, 0, None)
-                ri_safe.append(safe)
+                ri_safe[k] = safe
                 # materialize this build table's used columns into the slot
                 # space so later keys/aggs/filters can reference them
                 for (bk, col), slot in used.items():
@@ -489,11 +795,26 @@ class CompiledJoinAggregate:
             outs = segment_agg_outputs(ev, slots, agg_exprs, mask, gid, domain,
                                        reducer)
             hit = reducer.get(hit_h) > 0
-            flat = [hit]
-            for d, v in outs:
-                flat.append(d)
-                flat.append(v if v is not None else jnp.ones_like(hit))
             tags: List[Tuple[str, np.dtype]] = []
+            if topk is None:
+                flat = [hit]
+                for d, v in outs:
+                    flat.append(d)
+                    flat.append(v if v is not None else jnp.ones_like(hit))
+            else:
+                # the tail: the k first present groups by the sort keys,
+                # then only their rows of every output and group-key column
+                keys = [(outs[i] if kind == "agg"
+                         else build_cols[(gid_join, i)]) + (asc, nulls_first)
+                        for kind, i, asc, nulls_first in topk["keys"]]
+                at, found = select_topk(hit, keys, topk["k"])
+                groups = jnp.sum(hit, dtype=jnp.int32)
+                flat = [found, at, jnp.broadcast_to(groups, at.shape)]
+                for d, v in outs + [build_cols[(gid_join, c)]
+                                    for c in topk["cols"]]:
+                    flat.append(d[at])
+                    flat.append(v[at] if v is not None
+                                else jnp.ones_like(found))
             out = pack_flat(flat, tags)
             self._pack_tags = tags
             return out
@@ -518,7 +839,7 @@ class CompiledJoinAggregate:
         probe_valids = tuple(pt.columns[n].validity for n in pt.column_names)
         luts = tuple(lut for _, lut in self.luts)
         build_cols = {}
-        for (k, col), _slot in self.used_build_slots.items():
+        for (k, col) in self.build_col_keys:
             bt = self.build_tables[k]
             c = bt.columns[bt.column_names[col]]
             build_cols[(k, col)] = (c.data, c.validity)
@@ -535,14 +856,73 @@ class CompiledJoinAggregate:
             _dp.STATS["sharded_join_agg"] += 1
         from ..observability import timed_jit_call
 
-        packed = timed_jit_call("compiled_join_aggregate", self._fn, *args,
-                                may_compile=not self._warm)
+        packed = timed_jit_call(
+            "compiled_join_aggregate", self._fn, *args,
+            may_compile=not self._warm,
+            launch_attrs={"joins": len(self.luts), "domain": self.domain,
+                          "segsum": self.segsum_mode})
         self._warm = True
+        from ..observability import detail
         from .compiled import fetch_packed
 
         tags = self._pack_tags
-        host, present = fetch_packed(packed, self.domain)
-        return self._decode_result(host, present, tags)
+        # the tail as the host sees it: the pull of the packed rows (its
+        # `fetch` child also holds the wait for the device) and the decode
+        with detail("join:tail") as attrs:
+            if self.topk is not None:
+                result, groups = self._decode_topk(packed, tags)
+            else:
+                host, present = fetch_packed(packed, self.domain)
+                result = self._decode_result(host, present, tags)
+                groups = int(present.shape[0])
+            attrs.update(groups=groups, rows=result.num_rows)
+        return result
+
+    def _decode_topk(self, packed, tags) -> Tuple[Table, int]:
+        """The host's half of the top-k tail: one pull of the ``[rows, k]``
+        pack, then the found rows as a host-resident table in the tail's
+        order, and the count of present groups."""
+        from ..utils import d2h_fetch
+        from .compiled import unpack_row
+        from .rel.base import unique_names
+
+        with d2h_fetch(nbytes=int(packed.nbytes)):
+            host = np.asarray(jax.device_get(packed))
+        n = int(np.count_nonzero(host[0]))  # found rows come first
+        at = unpack_row(host, 1, tags)
+        groups = int(unpack_row(host, 2, tags)[0])
+        pairs = iter(range(3, host.shape[0], 2))
+
+        def pulled(i):
+            v = unpack_row(host, i + 1, tags)[:n] != 0
+            return unpack_row(host, i, tags)[:n], None if v.all() else v
+
+        names = unique_names([f.name for f in self.rel.schema])
+        n_groups = len(self.group_cols)
+        out: Dict[str, Column] = {}
+        for a, name in zip(self.rel.agg_exprs, names[n_groups:]):
+            d, validity = pulled(next(pairs))
+            target = sql_to_np(a.sql_type)
+            out[name] = Column(d.astype(target) if d.dtype != target else d,
+                               a.sql_type, validity)
+        bt = self.build_tables[self.gid_join]
+        keys: Dict[int, Column] = {}
+        for col in self.topk["cols"]:
+            d, validity = pulled(next(pairs))
+            keys[col] = decode_radix_group_key(
+                _ColMeta(bt.columns[bt.column_names[col]]), d, 0, validity)
+        for col in set(self.group_cols) - set(keys):
+            # an RLE key has no row to gather in the program: k rows of it
+            # (a static shape), cut to the found ones on the host
+            c = bt.columns[bt.column_names[col]].take(jnp.asarray(at))
+            with d2h_fetch():
+                d, v = jax.device_get((c.data, c.validity))
+            keys[col] = Column(
+                np.asarray(d)[:n], c.sql_type,
+                None if v is None else np.asarray(v)[:n], c.dictionary)
+        group_out = {name: keys[col]
+                     for name, col in zip(names, self.group_cols)}
+        return Table({**group_out, **out}, n), groups
 
     def _decode_result(self, host, present, tags, build_tables=None) -> Table:
         from .compiled import unpack_row
@@ -617,6 +997,41 @@ def _plan_nodes(node):
 PROGRAMS = ProgramCache("compiled_join_aggregate", 16)
 
 
+def _whole_lut(executor, join: dict, bdc, table: Table):
+    """The kept LUT of a whole build side's table version, built on first
+    use: ``((rmin, lut) or None, built here)``."""
+    from ..analysis.estimator import device_budget_bytes
+
+    budget = min(device_budget_bytes(executor.config) or _LUT_MAX_BYTES,
+                 _LUT_MAX_BYTES)
+    return LUTS.get_or_build(
+        (bdc.uid, str(join["rkey"]), budget),
+        lambda: build_lut(executor, join["rkey"], table, max_bytes=budget))
+
+
+def _stays_whole(k: int, join: dict, table: Table, ext, group_exprs,
+                 agg_exprs) -> bool:
+    """Whether every column of whole build candidate `k` that the PROGRAM
+    would read is stored one value a row: an RLE column is run-aligned, so
+    it may only be a key of the pointer gid, which the host decodes."""
+    rle = {i for i, n in enumerate(table.column_names)
+           if getattr(table.columns[n], "encoding",
+                      Encoding.PLAIN) is Encoding.RLE}
+    if not rle:
+        return True
+    read = {sub.index for e in join["whole"] for sub in walk(e)
+            if type(sub) is ColumnRef}
+    exprs = (ext.conjuncts + [j["lkey"] for j in ext.joins]
+             + [x for a in agg_exprs for x in a.args]
+             + [a.filter for a in agg_exprs if a.filter is not None])
+    choice = _choose_gid_join(ext, group_exprs)
+    if choice is None or choice[0] != k:
+        exprs = exprs + list(group_exprs)
+    read |= {sub.col for e in exprs for sub in walk(e)
+             if isinstance(sub, _BuildRef) and sub.k == k}
+    return not (read & rle)
+
+
 def try_compiled_join_aggregate(rel: p.Aggregate, executor) -> Optional[Table]:
     """Attempt the one-jit join pipeline for an Aggregate subtree; None to
     fall back to the generic (eager) converters."""
@@ -630,9 +1045,10 @@ def try_compiled_join_aggregate(rel: p.Aggregate, executor) -> Optional[Table]:
     ext, group_exprs, agg_exprs = extraction
     try:
         from ..datacontainer import LazyParquetContainer
+        from ..observability import detail
 
-        dc = executor.context.schema[ext.scan.schema_name].tables.get(
-            ext.scan.table_name)
+        ctx = executor.context
+        dc = ctx.schema[ext.scan.schema_name].tables.get(ext.scan.table_name)
         if dc is None:
             return None  # view-backed probe scans take the eager path
         if isinstance(dc, LazyParquetContainer):
@@ -646,7 +1062,7 @@ def try_compiled_join_aggregate(rel: p.Aggregate, executor) -> Optional[Table]:
         for j in ext.joins:
             for node in _plan_nodes(j["plan"]):
                 if isinstance(node, p.TableScan):
-                    bdc = executor.context.schema[node.schema_name].tables.get(
+                    bdc = ctx.schema[node.schema_name].tables.get(
                         node.table_name)
                     if bdc is None:
                         return None
@@ -657,52 +1073,101 @@ def try_compiled_join_aggregate(rel: p.Aggregate, executor) -> Optional[Table]:
         # cheap plan-only checks BEFORE any build-side execution (ADVICE r2:
         # an ineligible query used to pay for its build subtrees twice)
         check_agg_static_support(agg_exprs)
-        # parameterize (families/): literals in the PROBE-side conjuncts
-        # and aggregate arguments become runtime parameters.  Build-side
-        # literals stay baked — they shape the eagerly-executed build
-        # tables and their LUTs — and key the cache via the build plans'
-        # reprs, so a build-side literal change is a different family.
+        group_exprs, agg_exprs = _plan_whole_builds(ext, group_exprs,
+                                                    agg_exprs)
+        # parameterize (families/): literals in the probe-side conjuncts,
+        # the aggregate arguments and the conjuncts of WHOLE build sides
+        # become runtime parameters.  The literals of an eagerly executed
+        # build side stay baked: they shape its table and its LUT, and key
+        # the cache through the build plan's repr.
         from .. import families
 
         pz = families.pipeline_parameterizer(executor.config)
         ext.conjuncts = [pz.rewrite(e) for e in ext.conjuncts]
         agg_exprs = [pz.rewrite_agg(a) for a in agg_exprs]
-        params = pz.params
         probe_table = executor.get_table(ext.scan.schema_name,
                                          ext.scan.table_name)
         if ext.scan.projection is not None:
             probe_table = probe_table.select(ext.scan.projection)
         if not probe_table.column_names:
             return None
-        # build sides run through the normal recursive converter (they may
-        # be filtered scans, nested joins, anything) — compacted eagerly
-        build_tables = [executor.execute(j["plan"]) for j in ext.joins]
+        # all the host does per request to have the build sides ready
+        build_tables: List[Table] = []
+        whole: List[Optional[dict]] = []
+        with detail("join:build") as attrs:
+            built = lut_bytes = 0
+            for k, j in enumerate(ext.joins):
+                w = None
+                scan = j["plan"]
+                if j["whole"] is not None and (
+                        scan.schema_name, scan.table_name
+                ) not in executor.table_overrides:
+                    bdc = ctx.schema[scan.schema_name].tables[scan.table_name]
+                    bt = executor.get_table(scan.schema_name, scan.table_name)
+                    if scan.projection is not None:
+                        bt = bt.select(scan.projection)
+                    if bt.column_names and _stays_whole(
+                            k, j, bt, ext, group_exprs, agg_exprs):
+                        lut, built_here = _whole_lut(executor, j, bdc, bt)
+                        if lut is not None:
+                            ctx.metrics.inc("join.lut.built" if built_here
+                                            else "join.lut.reused")
+                            built += int(built_here)
+                            lut_bytes += int(lut[1].nbytes)
+
+                            def dictionary_of(i, bt=bt):
+                                return bt.columns[
+                                    bt.column_names[i]].dictionary
+
+                            w = {"lut": lut, "conjuncts": [
+                                pz.rewrite(e, dictionary_of)
+                                for e in j["whole"]]}
+                if w is None:
+                    # any other build side runs through the normal recursive
+                    # converter (nested joins, aggregates, anything) and
+                    # comes compacted
+                    bt = executor.execute(j["plan"])
+                build_tables.append(bt)
+                whole.append(w)
+            attrs.update(tables=len(build_tables), lut_bytes=lut_bytes,
+                         built=built)
+        params = pz.params
+        topk = executor.topk_hints.get(id(rel))
         family = (
             ext.scan.schema_name, ext.scan.table_name,
             tuple(ext.scan.projection or ()),
-            tuple(repr(j["plan"]) for j in ext.joins),
+            tuple(repr(j["plan"]) if w is None else
+                  (j["plan"].schema_name, j["plan"].table_name,
+                   tuple(j["plan"].projection or ()),
+                   tuple(str(e) for e in w["conjuncts"]))
+                  for j, w in zip(ext.joins, whole)),
             tuple(str(j["lkey"]) + "=" + str(j["rkey"]) for j in ext.joins),
             tuple(str(e) for e in ext.conjuncts),
             tuple(str(e) for e in group_exprs),
             tuple(str(a) for a in agg_exprs),
             tuple((f.name, f.sql_type) for f in rel.schema),
+            topk,
         )
         bucket = (tuple(uids), probe_table.num_rows, probe_table.padded_rows,
                   tuple(bt.num_rows for bt in build_tables))
-        ctx = executor.context
         # the constructor binds the tables this first run reads; the finally
         # below drops them.  No `warm`: this rung never defers
         compiled, built_here = PROGRAMS.get_or_build(
             ctx, family, bucket,
             lambda: CompiledJoinAggregate(rel, ext, group_exprs, agg_exprs,
                                           probe_table, build_tables,
-                                          executor),
+                                          executor, whole=whole, topk=topk),
             params=params)
         if not built_here:
             compiled.probe_table = probe_table
             compiled.build_tables = build_tables
-        if built_here:
+        else:
             record_predicate_spaces(ctx, compiled)
+            kept = sum(w is not None for w in whole)
+            if kept:
+                ctx.metrics.inc("join.build.whole", kept)
+            if kept < len(whole):
+                ctx.metrics.inc("join.build.eager", len(whole) - kept)
         try:
             from ..resilience import faults
 

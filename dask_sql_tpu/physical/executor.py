@@ -37,6 +37,13 @@ class Executor:
         #: — per-execution state, never on the shared cached plan object,
         #: so concurrent executions under different budgets cannot race
         self.stream_decisions: Dict[int, object] = {}
+        #: id(Aggregate node) -> compiled_join.TopK for THIS execution: the
+        #: Sort above an Aggregate tells the rungs under it that only its
+        #: first `fetch` rows are wanted (SortPlugin.convert)
+        self.topk_hints: Dict[int, object] = {}
+        #: ids of the nodes such a hint may have cut short: their tables are
+        #: one parent's view, so a shared subtree's memo must not hold them
+        self.unmemoized: set = set()
 
     @classmethod
     def add_plugin_class(cls, plugin_class):
@@ -144,7 +151,8 @@ class Executor:
                 ctx.rows = out.num_rows
         else:
             out = plugin.convert(rel, self)
-        self._memo[key] = out
+        if key not in self.unmemoized:
+            self._memo[key] = out
         return out
 
     # -- services for plugins ----------------------------------------------

@@ -126,8 +126,49 @@ class SortPlugin(BaseRelPlugin):
 
     class_name = "Sort"
 
+    @staticmethod
+    def _topk_hint(rel: p.Sort, executor):
+        """ORDER BY .. LIMIT straight above an Aggregate (column-picking
+        Projections between them allowed), every key a plain column: tell
+        the Aggregate's rungs which `fetch` rows are wanted
+        (`Executor.topk_hints`) and return the Aggregate's id, else None.
+        A rung that takes the hint returns those rows in order and this Sort
+        re-sorts a handful of host rows; one that does not returns every
+        group, as without the hint.  The nodes in between are kept out of
+        the executor's memo: what they return is this Sort's view."""
+        from ....planner.expressions import ColumnRef
+        from ...compiled_join import TopK
+
+        if rel.fetch is None or not rel.keys:
+            return None
+        index = []
+        for key in rel.keys:
+            if type(key.expr) is not ColumnRef:
+                return None
+            index.append(key.expr.index)
+        node, chain = rel.input, []
+        while isinstance(node, (p.Projection, p.SubqueryAlias)):
+            if isinstance(node, p.Projection):
+                picked = [node.exprs[i] for i in index]
+                if not all(type(e) is ColumnRef for e in picked):
+                    return None
+                index = [e.index for e in picked]
+            chain.append(id(node))
+            node = node.inputs()[0]
+        if not isinstance(node, p.Aggregate):
+            return None
+        executor.unmemoized.update(chain + [id(node)])
+        executor.topk_hints[id(node)] = TopK(int(rel.fetch), tuple(
+            (i, bool(key.ascending), bool(key.nulls_first_resolved()))
+            for i, key in zip(index, rel.keys)))
+        return id(node)
+
     def convert(self, rel: p.Sort, executor) -> Table:
-        (inp,) = self.assert_inputs(rel, 1, executor)
+        hinted = self._topk_hint(rel, executor)
+        try:
+            (inp,) = self.assert_inputs(rel, 1, executor)
+        finally:
+            executor.topk_hints.pop(hinted, None)
         if inp.num_rows == 0:
             return inp
         cols = [executor.eval_expr(k.expr, inp) for k in rel.keys]
@@ -156,7 +197,8 @@ class SortPlugin(BaseRelPlugin):
                 if sorted_t is not None:
                     return self.fix_column_to_row_type(sorted_t, rel.schema)
         limit = executor.config.get("sql.sort.topk-nelem-limit", 1_000_000)
-        if (rel.fetch is not None and len(cols) >= 1
+        on_host = all(isinstance(c.data, np.ndarray) for c in cols)
+        if (rel.fetch is not None and len(cols) >= 1 and not on_host
                 and rel.fetch * max(len(inp.columns), 1) <= limit):
             # top-k on the primary key then exact sort of the k survivors —
             # parity: reference topk_sort utils/sort.py:78 eligibility

@@ -11,6 +11,12 @@ reference reads parquet directly at plan time, the same plan/execute blur),
 and the distinct key values become a bulk InArrayExpr on the fact TableScan —
 which the lazy-parquet scan path then converts into a pyarrow row-group
 filter (physical/utils/filter.py), completing the IO pruning.
+
+Only a fact table that is still on disk is pruned.  A registered, device-
+resident table has no IO to skip: there the pass would run the dim side at
+plan time on every request (data-dependent shapes, a new plan per literal)
+and put the key list into the compiled join rung's family, one executable
+per parameter set, to pre-filter rows the join's own probe rejects anyway.
 """
 from __future__ import annotations
 
@@ -66,6 +72,19 @@ def _has_filters(node) -> bool:
     return isinstance(node, p.TableScan) and bool(node.filters)
 
 
+def _on_disk(scan: Optional[p.TableScan], context) -> bool:
+    """Whether the scan reads a lazy (not yet loaded) location table."""
+    from ...datacontainer import LazyParquetContainer
+
+    if scan is None:
+        return False
+    try:
+        dc = context.schema[scan.schema_name].tables[scan.table_name]
+    except KeyError:
+        return False
+    return isinstance(dc, LazyParquetContainer)
+
+
 def _try_prune(join: p.Join, catalog, context, ratio):
     lscan, rscan = _scan_of(join.left), _scan_of(join.right)
     lrows, rrows = _rows(lscan, catalog), _rows(rscan, catalog)
@@ -76,14 +95,14 @@ def _try_prune(join: p.Join, catalog, context, ratio):
         lkey, rkey = key_pair
         # fact = the big side; dim = the small *filtered* side
         if rrows / lrows <= (1 - ratio) and _has_filters(join.right) \
-                and isinstance(lkey, ColumnRef) and lscan is not None:
+                and isinstance(lkey, ColumnRef) and _on_disk(lscan, context):
             new_left = _inject(join.left, lscan, lkey, join.right, rkey, nleft,
                                context, side="right")
             if new_left is not None:
                 return p.Join(new_left, join.right, join.join_type, join.on,
                               join.filter, join.schema, join.null_aware)
         if lrows / rrows <= (1 - ratio) and _has_filters(join.left) \
-                and isinstance(rkey, ColumnRef) and rscan is not None:
+                and isinstance(rkey, ColumnRef) and _on_disk(rscan, context):
             new_right = _inject(join.right, rscan, rkey, join.left, lkey, nleft,
                                 context, side="left")
             if new_right is not None:

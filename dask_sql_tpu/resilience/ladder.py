@@ -111,8 +111,10 @@ def cost_skip(executor, rung: str, rel) -> bool:
 
     - the family must have OBSERVED exec history (it already ran on a lower
       rung) — a first-seen family always gets its compile attempt;
-    - the rung must not have compiled for this family yet (an existing
-      executable is nearly free to run: never skip it);
+    - the rung must not have compiled for this family yet, nor answered it
+      (an existing executable is nearly free to run: never skip it; one
+      executable may serve several fingerprints, as the join rung's does
+      for every string literal of a build side it keeps whole);
     - a per-rung compile-cost prior must exist — the p50 of the context's
       ``resilience.compile_ms.<rung>`` history (PR 5's compile histograms);
       no prior, no claim.
@@ -136,7 +138,7 @@ def cost_skip(executor, rung: str, rel) -> bool:
         entry = profiles.get(_fingerprint_of(executor, rel))
         if entry is None:
             return False
-        if entry["compile"].get(rung):
+        if entry["compile"].get(rung) or entry.get("rungs", {}).get(rung):
             return False
         exec_hist = entry.get("exec_ms") or []
         if not exec_hist:

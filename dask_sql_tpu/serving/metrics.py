@@ -51,6 +51,13 @@ DOCUMENTED_METRICS = frozenset({
     "columnar.encoding.valuespace_pred",
     "columnar.encoding.late_rows",
     "columnar.encoding.decode",
+    # physical/compiled_join.py — the join rung's build sides
+    # (docs/observability.md "Join rung"): LUTs built / found kept per
+    # request, build sides kept whole / executed eagerly per built program
+    "join.lut.built",
+    "join.lut.reused",
+    "join.build.whole",
+    "join.build.eager",
     # inference/ — model lowering + fused PREDICT (docs/ml.md)
     "inference.model.registered",
     "inference.model.lowered",

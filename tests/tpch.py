@@ -34,6 +34,13 @@ def _dates(rng, n, start="1992-01-01", days=2526):
     return base + rng.randint(0, days, n).astype("timedelta64[D]")
 
 
+def _sparse_keys(n):
+    """Order keys as clause 4.2.3 populates them: the first 8 of every 32.
+    Dense keys hid that a LUT sized by the FILTERED build's rows declines."""
+    index = np.arange(n, dtype=np.int64)
+    return (index // 8) * 32 + index % 8 + 1
+
+
 def generate(scale_rows: int = 2000, seed: int = 7):
     """All 8 TPC-H tables; `scale_rows` ~ lineitem row count."""
     rng = np.random.RandomState(seed)
@@ -94,7 +101,7 @@ def generate(scale_rows: int = 2000, seed: int = 7):
         "c_comment": ["" for _ in range(n_cust)],
     })
     orders = pd.DataFrame({
-        "o_orderkey": np.arange(1, n_ord + 1, dtype=np.int64),
+        "o_orderkey": _sparse_keys(n_ord),
         "o_custkey": rng.randint(1, n_cust + 1, n_ord).astype(np.int64),
         "o_orderstatus": rng.choice(["F", "O", "P"], n_ord),
         "o_totalprice": np.round(1000 + rng.rand(n_ord) * 400000, 2),
@@ -104,7 +111,7 @@ def generate(scale_rows: int = 2000, seed: int = 7):
         "o_shippriority": np.zeros(n_ord, dtype=np.int64),
         "o_comment": rng.choice(["", "special requests", "deposits"], n_ord),
     })
-    okeys = rng.randint(1, n_ord + 1, n_li).astype(np.int64)
+    okeys = orders.o_orderkey.to_numpy()[rng.randint(1, n_ord + 1, n_li) - 1]
     odate_by_key = orders.set_index("o_orderkey").o_orderdate
     shipbase = odate_by_key.loc[okeys].to_numpy()
     lineitem = pd.DataFrame({

@@ -284,3 +284,103 @@ def test_spmd_aggregate_compiles_for_four_chips(topo, captured, name, rows,
     assert " all-reduce(" in text or " all-gather(" in text
     assert (" scatter(" in text) == (segsum == "scatter")
     assert _device_bytes(compiled) < HBM_BYTES  # per device
+
+
+#: the benchmark's join cell (sf10_q3_library): rows of LINEITEM, ORDERS and
+#: CUSTOMER, the key ranges their LUTs span (8 of every 32 order keys are
+#: used), and what create_table encodes the program's columns to at that
+#: size (the run's `load` line prints them)
+Q3_ROWS = {"lineitem": 24_000_000, "orders": 6_000_000, "customer": 1_500_000}
+Q3_LUT_KEYS = (24_000_000, 1_500_000)
+Q3_DTYPES = {"l_orderkey": "int32", "l_extendedprice": "float64",
+             "l_discount": "int16", "l_shipdate": "int16",
+             "o_orderkey": "int32", "o_custkey": "int32",
+             "o_orderdate": "int16", "c_custkey": "int32",
+             "c_mktsegment": "int32"}
+
+
+def test_join_aggregate_q3_fits_one_chip_at_cell_size(one_chip):
+    """`sf10_q3_library`'s ONE program (two pointer joins through kept LUTs,
+    the build sides' own filters as masks over their rows, a float64 scatter
+    segment sum over 6M groups, the top-10 tail) compiled for a v5e at the
+    cell's shapes: 24M / 6M / 1.5M rows.  Captured from Q3 as
+    `perfbench.traffic` renders it over the cell's generator at 200,000
+    lineitems, then traced anew with the cell's domains.  What is read here
+    (ROADMAP S3f's first step): compile seconds and temporaries; a 64-bit
+    sort or gather that compiles for minutes shows here, not on the chip.
+    Costs the suite 3 s of set-up and the compile (printed with -s)."""
+    import time
+    from types import SimpleNamespace
+
+    from dask_sql_tpu import Context
+    from dask_sql_tpu import config as config_module
+    from dask_sql_tpu.physical.compiled_join import CompiledJoinAggregate
+    from perfbench import traffic
+    from perfbench.datagen import tpch_q3_tables
+
+    arrays = tpch_q3_tables.generate(SMALL_ROWS, seed=33, scale_factor=10)
+    frames = tpch_q3_tables.arrow_tables(arrays)
+    seen = []
+    run = CompiledJoinAggregate.run
+
+    def spy_run(self, params=()):
+        seen.append((self, self.probe_table, self._run_args(params)))
+        return run(self, params)
+
+    with pytest.MonkeyPatch.context() as mp, \
+            config_module.set({"serving.cache.enabled": False}):
+        mp.setattr(CompiledJoinAggregate, "run", spy_run)
+        c = Context()
+        for name in ("customer", "orders", "lineitem"):
+            c.create_table(name, frames[name])
+        c.sql(traffic.render(traffic.load("queries", "tpch_q3_building"),
+                             {"DAY": 16, "SEGMENT": 1})).compute()
+    (pipeline, probe_table, args), = seen
+    assert pipeline.topk is not None and pipeline.segsum_mode == "scatter"
+    assert all(conj is not None for conj in pipeline.build_conjuncts)
+    probe_datas, probe_valids, luts, build_cols, row_valid, params = args
+    assert row_valid is None and not any(v is not None for v in probe_valids)
+
+    def shaped(rows, name, data):
+        return jax.ShapeDtypeStruct((rows,), Q3_DTYPES.get(name, data.dtype),
+                                    sharding=one_chip)
+
+    tables = [c.schema[c.schema_name].tables[n].table
+              for n in ("orders", "customer")]
+    rows = [Q3_ROWS["orders"], Q3_ROWS["customer"]]
+    scans = [j["plan"] for j in pipeline.ext.joins]
+    big_probe = tuple(shaped(Q3_ROWS["lineitem"], n, d) for n, d in
+                      zip(probe_table.column_names, probe_datas))
+    big_luts = tuple(jax.ShapeDtypeStruct((n,), jnp.int32, sharding=one_chip)
+                     for n in Q3_LUT_KEYS)
+    big_build = {}
+    for (k, col), (data, valid) in build_cols.items():
+        assert valid is None
+        name = (scans[k].projection or tables[k].column_names)[col]
+        big_build[(k, col)] = (shaped(rows[k], name, data), None)
+    # the trace binds the build tables' row counts (the group domain)
+    pipeline.probe_table = probe_table
+    pipeline.build_tables = [SimpleNamespace(num_rows=n) for n in rows]
+    pipeline.domain = rows[0]
+    try:
+        lowered = jax.jit(pipeline._build()).lower(
+            big_probe, probe_valids, big_luts, big_build, None,
+            _shapes(tuple(params), one_chip))
+    finally:
+        pipeline.probe_table = pipeline.build_tables = None
+    t0 = time.perf_counter()
+    compiled = lowered.compile()
+    seconds = time.perf_counter() - t0
+    m = compiled.memory_analysis()
+    print(f"q3 at cell size: compile {seconds:.1f} s, arguments "
+          f"{m.argument_size_in_bytes}, temporaries {m.temp_size_in_bytes}, "
+          f"output {m.output_size_in_bytes}")
+    assert seconds < 240, seconds
+    # the sorts in it are the TPU's own lowering of scatter-add (32-bit keys
+    # over the probe's rows); the tail brings none
+    sorts = re.findall(r" sort\(.*?op_name=\"([^\"]*)\"", compiled.as_text())
+    assert all(name.endswith("scatter-add") for name in sorts), sorts
+    # LINEITEM resident at 54 B/row, ORDERS and CUSTOMER beside it
+    resident = 54 * Q3_ROWS["lineitem"] + 40 * Q3_ROWS["orders"] \
+        + 40 * Q3_ROWS["customer"]
+    assert _device_bytes(compiled) + resident < HBM_BYTES
