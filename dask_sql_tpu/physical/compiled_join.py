@@ -22,9 +22,18 @@ fuses into a single XLA program over the probe table:
                    aggregate, computed columns, a key the LUT rule
                    declines) is executed eagerly per request as before,
                    its LUT built from the filtered rows
-    probe side   : filters become masks (nothing compacts), joins become
-                   `lut[key - rmin]` gathers carrying a matched mask,
-                   build columns materialize as gathers through the pointer
+    probe side   : filters become masks, joins become `lut[key - rmin]`
+                   gathers carrying a matched mask, build columns
+                   materialize as gathers through the pointer
+    compaction   : where the reducer's cost is per row (a scatter) and the
+                   probe is large and on one chip, the rows that pass every
+                   join and filter are compacted into a buffer of fixed
+                   capacity (`compact_capacity`: 1/16 of the probe) and only
+                   the aggregates' arguments of those rows are gathered,
+                   evaluated and reduced; when more rows pass than the
+                   buffer holds, a `lax.cond` in the SAME executable
+                   reduces the probe whole under the mask, so the answer is
+                   exact whatever the parameters select
     aggregation  : group keys that live on one build table (or are that
                    join's key) make the build-row pointer itself the segment
                    id — no factorize, no sort; segment reductions land at
@@ -72,6 +81,7 @@ from .compiled import (
     _Unsupported,
     check_agg_static_support,
     check_no_rle,
+    compact_positions,
     count_codespace_predicates,
     record_predicate_spaces,
     decode_radix_group_key,
@@ -88,6 +98,19 @@ _MAX_TOPK = 64
 #: widest LUT (bytes) a whole build side may keep resident; a configured
 #: device budget (``analysis.estimate.device_budget_bytes``) below it holds
 _LUT_MAX_BYTES = 1 << 30
+#: probes below this many rows keep the uncompacted program: a scatter over
+#: so few rows costs less than the sort that would spare it
+_COMPACT_MIN_ROWS = 1 << 20
+#: the compact buffer's rows come in the blocks the engine pads tables to
+_COMPACT_BLOCK = 32_768
+
+
+def compact_capacity(n_rows: int) -> int:
+    """Rows of the compact buffer of a probe of `n_rows`: a sixteenth of the
+    probe in whole blocks, a function of the program's shapes alone (TPC-H
+    Q3 passes 0.5% of LINEITEM, 2.5% without its SEGMENT).  More rows than
+    that pass: the program's other branch reduces the probe whole."""
+    return -(-(n_rows // 16) // _COMPACT_BLOCK) * _COMPACT_BLOCK
 
 
 @dataclass(frozen=True)
@@ -380,6 +403,9 @@ class CompiledJoinAggregate:
         self.ext = ext
         self.probe_table = probe_table
         self.build_tables = build_tables
+        #: the registry of the context whose request this program is serving
+        #: (rebound with the tables): counts `join.compact.*` per request
+        self.metrics = executor.context.metrics
         whole = whole if whole is not None else [None] * len(build_tables)
 
         check_agg_static_support(agg_exprs)
@@ -500,6 +526,7 @@ class CompiledJoinAggregate:
         self.domain = domain_est
         self.segsum_mode = choose_segsum_impl(executor.config, domain_est)
         self.topk = self._plan_topk(topk, build_tables)
+        self.compact_cap = self._plan_compaction(probe_table)
         #: every build column the program is handed: gathered through a
         #: pointer, read by a build side's own conjuncts, or a group key the
         #: top-k tail orders by and returns
@@ -549,6 +576,24 @@ class CompiledJoinAggregate:
                     and not reads(rest + later, k):
                 folded[k] = j
         return folded
+
+    def _plan_compaction(self, probe_table) -> int:
+        """The compact buffer's rows, or 0 where the program reduces the
+        probe whole as ever: a segment sum that is not a scatter (its cost
+        is not per row), a probe sharded over a mesh or padded (the sharded
+        rung, spmd/join.py, traces this class's body per shard), a probe
+        under `_COMPACT_MIN_ROWS`."""
+        from ..parallel import dist_plan as _dp
+
+        datas = [c.data for c in probe_table.columns.values()]
+        n_rows = int(datas[0].shape[0])
+        if (type(self) is not CompiledJoinAggregate
+                or self.segsum_mode != "scatter"
+                or probe_table.row_valid is not None
+                or any(_dp.array_is_sharded(d) for d in datas)
+                or not _COMPACT_MIN_ROWS <= n_rows < (1 << 31)):
+            return 0
+        return compact_capacity(n_rows)
 
     def _plan_topk(self, topk: Optional[TopK], build_tables):
         """The top-k tail this program runs, or None where the hint is
@@ -660,6 +705,13 @@ class CompiledJoinAggregate:
         build_evs = self._build_evs
         folded = self.folded
         topk = self.topk
+        compact_cap = self.compact_cap
+        #: the slots the aggregates read: all the compact branch gathers
+        agg_slots = sorted({
+            sub.index for a in agg_exprs
+            for e in list(a.args) + ([a.filter] if a.filter is not None
+                                     else [])
+            for sub in walk(e) if type(sub) is ColumnRef})
 
         def fn(probe_datas, probe_valids, luts, build_cols, row_valid,
                params=()):
@@ -790,11 +842,34 @@ class CompiledJoinAggregate:
                 domain = build_domains[gid_join]
             from .compiled import pack_flat
 
-            reducer = self._make_reducer(gid, domain, n_rows)
-            hit_h = reducer.count(mask)
-            outs = segment_agg_outputs(ev, slots, agg_exprs, mask, gid, domain,
-                                       reducer)
-            hit = reducer.get(hit_h) > 0
+            def reduce_rows(slots, mask, gid, rows):
+                """Per group: the count of `mask`'s rows, and every
+                aggregate's ``(values, validity)``, over `rows` rows."""
+                reducer = self._make_reducer(gid, domain, rows)
+                hit_h = reducer.count(mask)
+                outs = segment_agg_outputs(ev, slots, agg_exprs, mask, gid,
+                                           domain, reducer)
+                return reducer.get(hit_h), outs
+
+            if not compact_cap:
+                hits, outs = reduce_rows(slots, mask, gid, n_rows)
+            else:
+                passed = jnp.sum(mask, dtype=jnp.int32)
+
+                def compacted():
+                    # within a group the rows keep their order, so a float
+                    # sum adds the terms the whole probe's would
+                    at = compact_positions(mask, compact_cap)
+                    some = {i: (slots[i][0][at], None if slots[i][1] is None
+                                else slots[i][1][at]) for i in agg_slots}
+                    some[PARAMS_SLOT] = params
+                    live = jnp.arange(compact_cap, dtype=jnp.int32) < passed
+                    return reduce_rows(some, live, gid[at], compact_cap)
+
+                hits, outs = jax.lax.cond(
+                    passed <= compact_cap, compacted,
+                    lambda: reduce_rows(slots, mask, gid, n_rows))
+            hit = hits > 0
             tags: List[Tuple[str, np.dtype]] = []
             if topk is None:
                 flat = [hit]
@@ -810,6 +885,8 @@ class CompiledJoinAggregate:
                 at, found = select_topk(hit, keys, topk["k"])
                 groups = jnp.sum(hit, dtype=jnp.int32)
                 flat = [found, at, jnp.broadcast_to(groups, at.shape)]
+                if compact_cap:
+                    flat.append(jnp.broadcast_to(passed, at.shape))
                 for d, v in outs + [build_cols[(gid_join, c)]
                                     for c in topk["cols"]]:
                     flat.append(d[at])
@@ -817,7 +894,10 @@ class CompiledJoinAggregate:
                                 else jnp.ones_like(found))
             out = pack_flat(flat, tags)
             self._pack_tags = tags
-            return out
+            # `passed` rides in the top-k pack; the plain pack is the one
+            # the other rungs pull (`fetch_packed`), so there it is a
+            # second, scalar output
+            return (out, passed) if compact_cap and topk is None else out
 
         # domains are python ints (build table row counts) — bind them now
         build_domains = [bt.num_rows for bt in self.build_tables]
@@ -856,11 +936,14 @@ class CompiledJoinAggregate:
             _dp.STATS["sharded_join_agg"] += 1
         from ..observability import timed_jit_call
 
+        cap = self.compact_cap
+        launch_attrs = {"joins": len(self.luts), "domain": self.domain,
+                        "segsum": self.segsum_mode}
+        if cap:
+            launch_attrs["compact"] = cap
         packed = timed_jit_call(
             "compiled_join_aggregate", self._fn, *args,
-            may_compile=not self._warm,
-            launch_attrs={"joins": len(self.luts), "domain": self.domain,
-                          "segsum": self.segsum_mode})
+            may_compile=not self._warm, launch_attrs=launch_attrs)
         self._warm = True
         from ..observability import detail
         from .compiled import fetch_packed
@@ -870,18 +953,29 @@ class CompiledJoinAggregate:
         # `fetch` child also holds the wait for the device) and the decode
         with detail("join:tail") as attrs:
             if self.topk is not None:
-                result, groups = self._decode_topk(packed, tags)
+                result, groups, passed = self._decode_topk(packed, tags)
             else:
-                host, present = fetch_packed(packed, self.domain)
+                if cap:  # the program's outputs: (pack, passed)
+                    host, present, passed = _fetch_packed_and_passed(
+                        *packed, self.domain)
+                else:
+                    host, present = fetch_packed(packed, self.domain)
                 result = self._decode_result(host, present, tags)
                 groups = int(present.shape[0])
             attrs.update(groups=groups, rows=result.num_rows)
+            if cap:
+                attrs.update(passed=passed, cap=cap)
+        if cap:
+            self.metrics.inc("join.compact.engaged")
+            if passed > cap:
+                self.metrics.inc("join.compact.overflow")
         return result
 
-    def _decode_topk(self, packed, tags) -> Tuple[Table, int]:
+    def _decode_topk(self, packed, tags) -> Tuple[Table, int, Optional[int]]:
         """The host's half of the top-k tail: one pull of the ``[rows, k]``
         pack, then the found rows as a host-resident table in the tail's
-        order, and the count of present groups."""
+        order, the count of present groups and, from a compacting program,
+        of the probe rows that passed."""
         from ..utils import d2h_fetch
         from .compiled import unpack_row
         from .rel.base import unique_names
@@ -891,7 +985,12 @@ class CompiledJoinAggregate:
         n = int(np.count_nonzero(host[0]))  # found rows come first
         at = unpack_row(host, 1, tags)
         groups = int(unpack_row(host, 2, tags)[0])
-        pairs = iter(range(3, host.shape[0], 2))
+        head = 3  # found, at, groups; a compacting program adds `passed`
+        passed = None
+        if self.compact_cap:
+            passed = int(unpack_row(host, head, tags)[0])
+            head += 1
+        pairs = iter(range(head, host.shape[0], 2))
 
         def pulled(i):
             v = unpack_row(host, i + 1, tags)[:n] != 0
@@ -922,7 +1021,7 @@ class CompiledJoinAggregate:
                 None if v is None else np.asarray(v)[:n], c.dictionary)
         group_out = {name: keys[col]
                      for name, col in zip(names, self.group_cols)}
-        return Table({**group_out, **out}, n), groups
+        return Table({**group_out, **out}, n), groups, passed
 
     def _decode_result(self, host, present, tags, build_tables=None) -> Table:
         from .compiled import unpack_row
@@ -995,6 +1094,25 @@ def _plan_nodes(node):
 # forever (ADVICE r2); probe/build table refs are dropped after every run
 # (re-bound on each call)
 PROGRAMS = ProgramCache("compiled_join_aggregate", 16)
+
+
+def _fetch_packed_and_passed(packed, passed, domain: int):
+    """`compiled.fetch_packed` for a compacting program without a top-k
+    tail, whose second output is the scalar `passed`: the same ONE pull,
+    the scalar in it: ``(host_matrix[:, present], present, passed)``."""
+    from ..utils import d2h_fetch
+    from .compiled import HOST_PULL_DOMAIN
+
+    if domain <= HOST_PULL_DOMAIN:
+        with d2h_fetch(nbytes=int(packed.nbytes)):
+            host, passed = jax.device_get((packed, passed))
+        present = np.nonzero(host[0] != 0.0)[0]
+        return host[:, present], present, int(passed)
+    present_dev = jnp.nonzero(packed[0] != 0.0)[0]
+    with d2h_fetch():
+        host, present, passed = jax.device_get(
+            (packed[:, present_dev], present_dev, passed))
+    return np.asarray(host), np.asarray(present), int(passed)
 
 
 def _whole_lut(executor, join: dict, bdc, table: Table):
@@ -1161,8 +1279,11 @@ def try_compiled_join_aggregate(rel: p.Aggregate, executor) -> Optional[Table]:
         if not built_here:
             compiled.probe_table = probe_table
             compiled.build_tables = build_tables
+            compiled.metrics = ctx.metrics
         else:
             record_predicate_spaces(ctx, compiled)
+            if compiled.compact_cap:
+                ctx.metrics.inc("join.compact.programs")
             kept = sum(w is not None for w in whole)
             if kept:
                 ctx.metrics.inc("join.build.whole", kept)

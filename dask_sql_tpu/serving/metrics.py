@@ -58,6 +58,12 @@ DOCUMENTED_METRICS = frozenset({
     "join.lut.reused",
     "join.build.whole",
     "join.build.eager",
+    # the join rung's compaction of the passing rows: programs built with
+    # it, requests they served, and of those the ones whose passing rows
+    # outran the buffer (the same executable then reduces the probe whole)
+    "join.compact.programs",
+    "join.compact.engaged",
+    "join.compact.overflow",
     # inference/ — model lowering + fused PREDICT (docs/ml.md)
     "inference.model.registered",
     "inference.model.lowered",

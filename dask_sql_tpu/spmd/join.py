@@ -77,6 +77,12 @@ class SpmdJoinAggregate(CompiledJoinAggregate):
         self._mapped: Dict[int, object] = {}
 
     def _make_reducer(self, gid, domain: int, n_rows: int) -> SegmentReducer:
+        # every shard reduces ITS row block whole under the mask: the
+        # one-chip rung's compaction of the passing rows
+        # (`CompiledJoinAggregate._plan_compaction`) is off for any subclass
+        # and any sharded or padded probe.  Under `shard_map` it would want
+        # a per-shard capacity and a `psum` of `passed` to pick ONE branch on
+        # every chip (the branches' collectives must agree): its own design
         return SpmdSegmentReducer(gid, domain, n_rows)
 
     def _mapped_for(self, n_params: int):

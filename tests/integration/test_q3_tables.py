@@ -9,6 +9,10 @@ lineitems, the text from `perfbench.traffic`, the answers are held to the
 plain reference (`perfbench/references/tpch_q3_topk.py`) through
 `perfbench.compare.answer_gap`.
 
+The compaction of the passing rows (PR 34) engages from 2^20 probe rows;
+its tests lower that floor (`compacting`) and hold BOTH branches of the one
+executable to the interpreted converters' answer.
+
 Cost (ROADMAP D11): the module's tables 1.5 s once, the 155 parameter sets
 4 s (one compile), the other cases under 1 s each.
 """
@@ -281,3 +285,200 @@ def test_string_literal_on_a_build_side_is_a_runtime_code():
             else:
                 assert pd.isna(got.s[0])
     assert compiles == 2  # one executable per operator, none per value
+
+
+# ------------------------------------------------ compaction of passing rows
+COMPACT_COUNTERS = ("join.compact.programs", "join.compact.engaged",
+                    "join.compact.overflow")
+Q3_SHAPED = (
+    "SELECT l_orderkey, SUM(l_extendedprice * (1 - l_discount)) AS revenue, "
+    "o_orderdate, o_shippriority FROM customer, orders, lineitem "
+    "WHERE c_custkey = o_custkey AND l_orderkey = o_orderkey{where} "
+    "GROUP BY l_orderkey, o_orderdate, o_shippriority "
+    "ORDER BY revenue DESC, o_orderdate LIMIT 10")
+
+
+def counters(c):
+    return {k: c.metrics.counter(k) for k in COMPACT_COUNTERS}
+
+
+def moved(c, before):
+    return tuple(c.metrics.counter(k) - before[k] for k in COMPACT_COUNTERS)
+
+
+def eager(c, sql):
+    """The interpreted converters' answer: filter, then join."""
+    want = c.sql(sql, config_options={"sql.compile.join_pipeline": False}
+                 ).compute()
+    assert "rung:compiled_join_aggregate" not in span_names(c)
+    return want
+
+
+def tail_attrs(c):
+    return {s.name: s for s in c.last_trace.spans}["join:tail"].attrs
+
+
+def first_rows(arrays, count):
+    """A conjunct that exactly the first `count` lineitems in (order, line)
+    order pass: every lineitem finds its order and its customer."""
+    key = line = 0  # no row: keys and line numbers start at 1
+    if count:
+        last = np.lexsort((arrays["linenumber"],
+                           arrays["orderkey"]))[count - 1]
+        key = int(arrays["orderkey"][last])
+        line = int(arrays["linenumber"][last])
+    return (f" AND (l_orderkey < {key} OR (l_orderkey = {key} "
+            f"AND l_linenumber <= {line}))")
+
+
+def same_top_rows(got, want):
+    """The rows and their order exactly, `revenue` to 1e-15 relative."""
+    assert len(got) == len(want)
+    for name in ("l_orderkey", "o_orderdate", "o_shippriority"):
+        assert got[name].tolist() == want[name].tolist()
+    np.testing.assert_allclose(got.revenue.to_numpy(),
+                               want.revenue.to_numpy(), rtol=1e-15, atol=0)
+
+
+class TestCompaction:
+    """The compaction of the passing rows (PR 34), with the row floor
+    lowered under this file's tables: the programs of this class are built
+    WITH it (one per family for the whole class: the eager answers are
+    what costs, 3-5 s each), and none outlives the class."""
+
+    @pytest.fixture(scope="class", autouse=True)
+    def compacting(self):
+        with pytest.MonkeyPatch.context() as mp:
+            mp.setattr(cj, "_COMPACT_MIN_ROWS", 1_000)
+            cj.PROGRAMS.clear()
+            yield
+            cj.PROGRAMS.clear()
+
+    @pytest.mark.parametrize("day", [1, 16, 31])
+    @pytest.mark.parametrize("segment", SEGMENTS)
+    def test_compact_branch_equals_eager_filter_then_join(self, q3, segment,
+                                                          day):
+        """Q3 on the compacting program against the interpreted converters:
+        the compacted rows keep their order, so each group's float64 sum
+        adds the same terms."""
+        c, _, _ = q3
+        query = traffic.load("queries", f"tpch_q3_{segment}")
+        (params,) = [x for x in traffic.all_params(query)
+                     if x["DAY"] == day - 1]
+        sql = traffic.render(query, params)
+        before = counters(c)
+        got = c.sql(sql).compute()
+        assert "rung:compiled_join_aggregate" in span_names(c)
+        assert moved(c, before)[1:] == (1, 0)
+        attrs = tail_attrs(c)
+        assert attrs["cap"] == cj.compact_capacity(ROWS) == 32_768
+        assert 0 < attrs["passed"] < ROWS // 50 and attrs["rows"] == 10
+        launch = [s for s in c.last_trace.spans if s.name == "launch"][-1]
+        assert launch.attrs["compact"] == attrs["cap"]
+        same_top_rows(got, eager(c, sql))
+
+    @pytest.mark.parametrize("case", ["none_pass", "exactly_cap",
+                                      "one_over_cap", "no_filter_at_all"])
+    def test_overflow_takes_the_whole_probe_in_the_same_executable(self, q3,
+                                                                   case):
+        """`passed` against `cap`: up to `cap` rows the compact branch
+        answers; one row more and the SAME executable reduces the probe
+        whole under the mask (`join.compact.overflow`), with the eager
+        path's answer and no second `compile:` span."""
+        c, arrays, _ = q3
+        cap = cj.compact_capacity(ROWS)
+        passed = {"none_pass": 0, "exactly_cap": cap, "one_over_cap": cap + 1,
+                  "no_filter_at_all": ROWS}[case]
+        if case == "no_filter_at_all":
+            sql = warm = Q3_SHAPED.format(where="")
+        else:
+            sql = Q3_SHAPED.format(where=first_rows(arrays, passed))
+            warm = Q3_SHAPED.format(where=first_rows(arrays, 100))
+        cj.PROGRAMS.clear()
+        before = counters(c)
+        c.sql(warm).compute()  # builds and compiles the family's ONE program
+        assert sum(n.startswith("compile:") for n in span_names(c)) == 1
+        assert moved(c, before) == (1, 1, int(warm == sql and passed > cap))
+        before = counters(c)
+        got = c.sql(sql).compute()
+        names = span_names(c)
+        assert "rung:compiled_join_aggregate" in names
+        assert "family_hit" in names
+        assert not [n for n in names if n.startswith("compile:")]
+        assert moved(c, before) == (0, 1, int(passed > cap))
+        attrs = tail_attrs(c)
+        assert (attrs["passed"], attrs["cap"]) == (passed, cap)
+        assert len(got) == (10 if passed else 0)
+        same_top_rows(got, eager(c, sql))
+
+    @pytest.mark.parametrize("gid, sql", [
+        ("pointer", "SELECT d_key, SUM(f_opt) AS s, COUNT(f_opt) AS n, "
+         "COUNT(*) AS m, MIN(f_n) AS lo, AVG(f_val) AS a FROM fact, dim "
+         "WHERE f_key = d_key AND d_day < 25 GROUP BY d_key"),
+        ("radix", "SELECT d_tag, SUM(f_opt) AS s, COUNT(f_opt) AS n, "
+         "COUNT(*) AS m, MAX(f_n) AS hi FROM fact, dim "
+         "WHERE f_key = d_key AND d_tag <> 'red' GROUP BY d_tag"),
+        ("global", "SELECT SUM(f_opt) AS s, COUNT(f_opt) AS n, COUNT(*) AS m "
+         "FROM fact, dim WHERE f_key = d_key AND f_n < 40"),
+    ], ids=["pointer", "radix", "global"])
+    def test_null_keys_and_null_arguments_survive_the_gather(self, gid, sql):
+        """NULL probe keys match nothing and a NULL aggregate argument
+        counts for nothing, gathered into the compact buffer (here wider
+        than the 5,000-row probe: `cap` above the rows) as read in place."""
+        import pyarrow as pa
+
+        tables = star_tables()
+        rng = np.random.default_rng(6)
+        tables["fact"] = tables["fact"].append_column("f_opt", pa.array(
+            rng.random(5_000), mask=rng.random(5_000) < 0.1))
+        c = Context()
+        for name, frame in tables.items():
+            c.create_table(name, frame)
+        before = counters(c)
+        got = c.sql(sql).compute()
+        assert "rung:compiled_join_aggregate" in span_names(c)
+        assert moved(c, before) == (1, 1, 0)
+        attrs = tail_attrs(c)
+        assert attrs["cap"] == 32_768 and 0 < attrs["passed"] < 5_000
+        want = eager(c, sql)
+        if gid != "global":
+            by = list(got.columns[:1])
+            got, want = (f.sort_values(by).reset_index(drop=True)
+                         for f in (got, want))
+        assert int(got.n.sum()) < int(got.m.sum())  # NULL arguments exist
+        pd.testing.assert_frame_equal(got, want, check_dtype=False,
+                                      rtol=1e-15)
+
+    @pytest.mark.parametrize("pull", ["whole_pack", "present_groups"])
+    def test_without_topk_passed_rides_beside_the_plain_pack(
+            self, q3, monkeypatch, pull):
+        """No ORDER BY / LIMIT: the program's pack is the one-table rungs'
+        own and `passed` its second, scalar output, in the same ONE pull:
+        of the whole pack, or above `HOST_PULL_DOMAIN` of the present
+        groups."""
+        from dask_sql_tpu.physical import compiled
+
+        c, _, _ = q3
+        if pull == "present_groups":
+            monkeypatch.setattr(compiled, "HOST_PULL_DOMAIN", 1_000)
+        query = traffic.load("queries", "tpch_q3_household")
+        sql = traffic.render(query, {"DAY": 7, "SEGMENT": 3})
+        sql = sql[:sql.upper().index("ORDER BY")]
+        cj.PROGRAMS.clear()
+        before = counters(c)
+        got = c.sql(sql).compute()
+        assert "rung:compiled_join_aggregate" in span_names(c)
+        fetches = [s for s in c.last_trace.spans if s.name == "fetch"
+                   and s.attrs.get("rung") == "compiled_join_aggregate"]
+        assert moved(c, before) == (1, 1, 0)
+        (program,) = cj.PROGRAMS.values()
+        assert program.topk is None and program.compact_cap == 32_768
+        attrs = tail_attrs(c)
+        assert attrs["groups"] == attrs["rows"] == len(got) > 10
+        assert attrs["groups"] < attrs["passed"] < ROWS // 50
+        assert len(fetches) == 1  # the pack and `passed` in ONE pull
+        by = ["l_orderkey"]
+        got, want = (f.sort_values(by).reset_index(drop=True)
+                     for f in (got, eager(c, sql)))
+        pd.testing.assert_frame_equal(got, want, check_dtype=False,
+                                      rtol=1e-15)

@@ -189,3 +189,42 @@ class TestSortKernels:
         col = _col(np.array([5.0, 1.0, 9.0, 3.0]))
         idx = topk_permutation(col, ascending=True, k=2)
         assert sorted(np.asarray(idx).tolist()) == [1, 3]
+
+
+class TestCompaction:
+    """`physical/compiled.py::compact_positions`, the join rung's
+    fixed-capacity compaction, alone: the first `cap` True positions in row
+    order, under jit, whatever the mask holds."""
+
+    ROWS, BLOCK = 100_000, 32_768
+
+    @pytest.mark.parametrize("case, cap", [
+        ("all_false", 4096), ("all_true", 4096), ("cap_larger_than_rows",
+                                                  131_072),
+        ("true_only_in_last_block", 4096), ("sparse", 4096),
+        ("exactly_cap", 4096), ("one_over_cap", 4096)])
+    def test_compact_positions(self, case, cap):
+        import jax
+
+        from dask_sql_tpu.physical.compiled import compact_positions
+
+        rng = np.random.default_rng(11)
+        mask = np.zeros(self.ROWS, dtype=bool)
+        if case in ("all_true", "cap_larger_than_rows"):
+            mask[:] = True
+        elif case == "true_only_in_last_block":
+            mask[3 * self.BLOCK + 17::5] = True
+        elif case == "sparse":
+            mask = rng.random(self.ROWS) < 0.02
+        elif case in ("exactly_cap", "one_over_cap"):
+            picks = cap + (case == "one_over_cap")
+            mask[rng.choice(self.ROWS, picks, replace=False)] = True
+        got = np.asarray(jax.jit(compact_positions, static_argnums=1)(
+            jnp.asarray(mask), cap))
+        want = np.nonzero(mask)[0]
+        assert got.shape == (cap,) and got.dtype == np.int32
+        kept = min(len(want), cap)
+        # order preserved: ascending positions, the first `cap` of them
+        assert np.array_equal(got[:kept], want[:kept])
+        # the rest is a position a gather may read (the caller masks it)
+        assert ((got[kept:] >= 0) & (got[kept:] < self.ROWS)).all()

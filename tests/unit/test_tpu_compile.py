@@ -301,9 +301,11 @@ Q3_DTYPES = {"l_orderkey": "int32", "l_extendedprice": "float64",
 
 def test_join_aggregate_q3_fits_one_chip_at_cell_size(one_chip):
     """`sf10_q3_library`'s ONE program (two pointer joins through kept LUTs,
-    the build sides' own filters as masks over their rows, a float64 scatter
-    segment sum over 6M groups, the top-10 tail) compiled for a v5e at the
-    cell's shapes: 24M / 6M / 1.5M rows.  Captured from Q3 as
+    the build sides' own filters as masks over their rows, the passing rows
+    compacted into 1,507,328 and a float64 scatter segment sum of those
+    into 6M groups, or past that capacity of the whole probe: a `lax.cond`;
+    the top-10 tail) compiled for a v5e at the cell's shapes: 24M / 6M /
+    1.5M rows.  Captured from Q3 as
     `perfbench.traffic` renders it over the cell's generator at 200,000
     lineitems, then traced anew with the cell's domains.  What is read here
     (ROADMAP S3f's first step): compile seconds and temporaries; a 64-bit
@@ -314,6 +316,7 @@ def test_join_aggregate_q3_fits_one_chip_at_cell_size(one_chip):
 
     from dask_sql_tpu import Context
     from dask_sql_tpu import config as config_module
+    from dask_sql_tpu.physical import compiled_join
     from dask_sql_tpu.physical.compiled_join import CompiledJoinAggregate
     from perfbench import traffic
     from perfbench.datagen import tpch_q3_tables
@@ -330,6 +333,8 @@ def test_join_aggregate_q3_fits_one_chip_at_cell_size(one_chip):
     with pytest.MonkeyPatch.context() as mp, \
             config_module.set({"serving.cache.enabled": False}):
         mp.setattr(CompiledJoinAggregate, "run", spy_run)
+        # the cell's probe is above the compaction's row floor
+        mp.setattr(compiled_join, "_COMPACT_MIN_ROWS", SMALL_ROWS)
         c = Context()
         for name in ("customer", "orders", "lineitem"):
             c.create_table(name, frames[name])
@@ -362,6 +367,10 @@ def test_join_aggregate_q3_fits_one_chip_at_cell_size(one_chip):
     pipeline.probe_table = probe_table
     pipeline.build_tables = [SimpleNamespace(num_rows=n) for n in rows]
     pipeline.domain = rows[0]
+    assert pipeline.compact_cap == compiled_join.compact_capacity(SMALL_ROWS)
+    cap = pipeline.compact_cap = compiled_join.compact_capacity(
+        Q3_ROWS["lineitem"])
+    assert cap == 1_507_328
     try:
         lowered = jax.jit(pipeline._build()).lower(
             big_probe, probe_valids, big_luts, big_build, None,
@@ -376,10 +385,31 @@ def test_join_aggregate_q3_fits_one_chip_at_cell_size(one_chip):
           f"{m.argument_size_in_bytes}, temporaries {m.temp_size_in_bytes}, "
           f"output {m.output_size_in_bytes}")
     assert seconds < 240, seconds
+    # ONE executable, two branches: the compaction's `lax.cond`.  The branch
+    # taken while the passing rows fit scatters `cap` update rows, the other
+    # the whole probe's (three scatters each: the float64 sum, two counts)
+    text = compiled.as_text()
+    (branches,) = re.findall(r" conditional\(.*?branch_computations=\{(.*?)\}",
+                             text)
+    assert len(branches.split(",")) == 2, branches
+    updates = re.findall(r'"stablehlo\.scatter"\(.*?\}\) : \(tensor<\d+xf?\w+>, '
+                         r"tensor<(\d+)x1xi32>", lowered.as_text(), re.S)
+    assert sorted(map(int, updates)) == [cap] * 3 \
+        + [Q3_ROWS["lineitem"]] * 3, updates
     # the sorts in it are the TPU's own lowering of scatter-add (32-bit keys
-    # over the probe's rows); the tail brings none
-    sorts = re.findall(r" sort\(.*?op_name=\"([^\"]*)\"", compiled.as_text())
-    assert all(name.endswith("scatter-add") for name in sorts), sorts
+    # over the rows it is handed) and the compaction's ONE over the probe's
+    # rows, in the branch that compacts; the tail brings none
+    sorts = re.findall(r"= \(?\w+\[(\d+)\][^=]*? sort\(.*?op_name=\"([^\"]*)\"",
+                       text)
+    by_branch = {}
+    for n, name in sorts:
+        by_branch.setdefault(name.split("/")[-2], []).append(
+            (name.split("/")[-1], int(n)))
+    assert sorted(by_branch["branch_0_fun"]) == \
+        [("scatter-add", Q3_ROWS["lineitem"])] * 2, sorts
+    assert sorted(by_branch["branch_1_fun"]) == \
+        [("scatter-add", cap)] * 2 + [("sort", Q3_ROWS["lineitem"])], sorts
+    assert set(by_branch) == {"branch_0_fun", "branch_1_fun"}, sorts
     # LINEITEM resident at 54 B/row, ORDERS and CUSTOMER beside it
     resident = 54 * Q3_ROWS["lineitem"] + 40 * Q3_ROWS["orders"] \
         + 40 * Q3_ROWS["customer"]
