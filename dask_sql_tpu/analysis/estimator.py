@@ -66,7 +66,7 @@ from ..planner.expressions import (
     SortKey,
     walk,
 )
-from ..ops.grouping import RADIX_DOMAIN_LIMIT
+from ..ops.grouping import RADIX_DOMAIN_LIMIT, one_key_domain_limit
 from .verifier import _pow2_bucket, _Verifier
 
 logger = logging.getLogger(__name__)
@@ -463,10 +463,17 @@ class _Estimator:
         row.  The radix-domain lower bound (dictionary sizes + BOOLEAN=3,
         unknown keys contribute factor 1) makes the matrix bound provable;
         the gate caps the domain at ``1 << 22``, which caps the upper
-        bound even when the true domain is unknown."""
+        bound even when the true domain is unknown; ONE key that host
+        metadata cannot size may be an integer key, which the rungs admit
+        by the bytes of this very matrix (`ops.grouping.
+        one_key_domain_limit`), so there the cap is that rule's."""
         domain, all_known = self._v._radix_domain(node)
         slots = len(node.agg_exprs) + 1
-        cap_hi = RADIX_DOMAIN_LIMIT * slots * _PACKED_SLOT_BYTES
+        gate = RADIX_DOMAIN_LIMIT
+        if len(node.group_exprs) == 1 and not all_known:
+            gate = one_key_domain_limit(
+                slots, getattr(self.context, "config", None))
+        cap_hi = gate * slots * _PACKED_SLOT_BYTES
         if domain is not None and all_known:
             # every key sized (a global aggregate's domain is exactly 1):
             # the gate cap tightens to the true matrix size

@@ -759,7 +759,9 @@ class _Verifier:
         the radix planning in CompiledAggregate.__init__ / _plan_radix
         without touching device buffers.  Unknown keys contribute factor 1,
         so the product is a provable lower bound: exceeding the gate is
-        certain, staying under it is not."""
+        certain, staying under it is not.  Only strings and booleans are
+        sized, so the ONE integer key that the rungs admit past the gate by
+        the bytes of its state (`one_key_domain_limit`) never reads here."""
         if not agg.group_exprs:
             return 1, True
         product = 1

@@ -57,6 +57,30 @@ def key_arrays(cols: Sequence[Column]) -> List[jnp.ndarray]:
 #: bind-time verdict and the compile-time gate can never drift
 RADIX_DOMAIN_LIMIT = 1 << 22
 
+#: bytes of ``[domain]`` reduction state (8 a slot: the present indicator and
+#: one per aggregate) that ONE integer group key's range may cost.  A product
+#: of several keys' radices is mostly empty space and keeps the gate above; a
+#: single key's range is what the table's own keys span (TPC-H's 6M orders
+#: lie on a range of 24M), and what declines it is the memory of its state,
+#: not the count of its values
+ONE_KEY_STATE_BYTES = 1 << 30
+
+
+def one_key_domain_limit(slots: int, config=None) -> int:
+    """The widest range of a group-by on ONE integer key (PLAIN values, FOR
+    codes) that the compiled rungs reduce into ``[domain]`` state of `slots`
+    8-byte slots: `ONE_KEY_STATE_BYTES` of it, or `config`'s device budget
+    (``analysis.estimate.device_budget_bytes``) below that; never under the
+    mixed-radix gate.  Shared by `CompiledAggregate`, the join rung's radix
+    plan and its semi-join build sides, and the estimator's bound."""
+    from ..config import parse_byte_budget
+
+    budget = parse_byte_budget(config.get(
+        "analysis.estimate.device_budget_bytes")) if config is not None \
+        else None
+    room = min(budget or ONE_KEY_STATE_BYTES, ONE_KEY_STATE_BYTES)
+    return max(RADIX_DOMAIN_LIMIT, room // (8 * max(int(slots), 1)))
+
 
 def radix_gid(cols: Sequence[Column], max_domain: int = RADIX_DOMAIN_LIMIT):
     """Sort-free group ids for small-domain keys (dictionary codes / bools).

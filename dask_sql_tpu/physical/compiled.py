@@ -1123,9 +1123,14 @@ class CompiledAggregate:
             else:
                 raise _Unsupported("non-dictionary group key")
             gcols.append(c)
-        from ..ops.grouping import RADIX_DOMAIN_LIMIT, resolve_int_bounds
+        from ..ops.grouping import (RADIX_DOMAIN_LIMIT, one_key_domain_limit,
+                                    resolve_int_bounds)
 
-        spans = resolve_int_bounds(pending, RADIX_DOMAIN_LIMIT)
+        limit = RADIX_DOMAIN_LIMIT
+        if len(group_exprs) == 1 and pending:
+            # ONE integer key: admitted by the bytes of its [domain] state
+            limit = one_key_domain_limit(len(agg_exprs) + 1, config)
+        spans = resolve_int_bounds(pending, limit)
         if spans is None:
             raise _Unsupported("integer key range too large")
         for slot, (span, lo) in spans.items():
@@ -1134,9 +1139,11 @@ class CompiledAggregate:
         domain = 1
         for r in radices:
             domain *= r
-        if domain > RADIX_DOMAIN_LIMIT:
+        if domain > limit:
             raise _Unsupported("group domain too large")
         self.domain = max(domain, 1)
+        #: a one-key range past the mixed-radix gate (`aggregate.domain.wide`)
+        self.wide_domain = self.domain > RADIX_DOMAIN_LIMIT
         self.radices = radices
         self.offsets = offsets
         # metadata only — the decode in run() needs dtype/sql_type/dictionary
@@ -1419,6 +1426,8 @@ def try_compiled_aggregate(rel: p.Aggregate, executor) -> Optional[Table]:
             return None  # deferred to the background compiler
         if built_here:
             record_predicate_spaces(ctx, compiled)
+            if compiled.wide_domain:
+                ctx.metrics.inc("aggregate.domain.wide")
         from ..resilience import faults
 
         faults.maybe_inject("oom", executor.config)

@@ -18,10 +18,19 @@ fuses into a single XLA program over the probe table:
                    table's rows, read through the pointer.  So neither the
                    program's shapes nor its identity depend on the
                    literals: one executable per plan family and table
-                   version.  Any other build side (a nested join, an
-                   aggregate, computed columns, a key the LUT rule
-                   declines) is executed eagerly per request as before,
-                   its LUT built from the filtered rows
+                   version.  A LEFTSEMI join whose build side is an
+                   Aggregate grouped by the join key (``x IN (SELECT k ..
+                   GROUP BY k HAVING ..)``: unique on the key by
+                   construction) is an INNER join that exposes no column,
+                   and its build side is REDUCED IN the program: the inner
+                   aggregates over all of that table's rows into the values
+                   of the key's range (kept per table version, admitted by
+                   the bytes of the state: `one_key_domain_limit`), the
+                   HAVING filters a mask over them with runtime literals,
+                   the mask the join's LUT.  Any other build side (a nested
+                   join, another aggregate, computed columns, a key the LUT
+                   rule declines) is executed eagerly per request as
+                   before, its LUT built from the filtered rows
     probe side   : filters become masks, joins become `lut[key - rmin]`
                    gathers carrying a matched mask, build columns
                    materialize as gathers through the pointer
@@ -37,7 +46,11 @@ fuses into a single XLA program over the probe table:
     aggregation  : group keys that live on one build table (or are that
                    join's key) make the build-row pointer itself the segment
                    id — no factorize, no sort; segment reductions land at
-                   HBM bandwidth
+                   HBM bandwidth.  A key on a SECOND build table that is
+                   reached from the first's columns alone by its unique key
+                   (ORDERS -> CUSTOMER) is determined by the first's row
+                   too, and is read through that pointer for the rows that
+                   leave (a string among them decoded on the host)
     tail         : under ORDER BY .. LIMIT k (`TopK`, handed down by the
                    Sort above) the k first groups are selected inside the
                    program over the `[domain]` state, exactly, and only
@@ -93,8 +106,9 @@ logger = logging.getLogger(__name__)
 
 _MAX_JOINS = 6
 #: ORDER BY .. LIMIT k is selected inside the program up to this k (one
-#: round of masked reductions over the group domain per row)
-_MAX_TOPK = 64
+#: round of masked reductions over the group domain per row; TPC-H Q18
+#: asks for the first 100)
+_MAX_TOPK = 128
 #: widest LUT (bytes) a whole build side may keep resident; a configured
 #: device budget (``analysis.estimate.device_budget_bytes``) below it holds
 _LUT_MAX_BYTES = 1 << 30
@@ -131,9 +145,11 @@ class _Extraction:
     def __init__(self):
         self.scan: Optional[p.TableScan] = None
         self.conjuncts: List[Expr] = []  # over global space (probe + _BuildRef)
-        #: {"plan": right subplan, "lkey", "rkey"}; `_plan_whole_builds`
-        #: adds "whole": the build side's own conjuncts where it is a
-        #: filtered base-table scan, else None
+        #: {"plan": right subplan, "lkey", "rkey", "exposes": False for a
+        #: semi-join, "grouped": `_grouped_by_key`'s reading of its build
+        #: side}; `_plan_whole_builds` adds "whole": the build side's
+        #: own conjuncts where it is a filtered base-table scan, else None,
+        #: and "semi": `_plan_semi_build`'s reading of it, or None
         self.joins: List[dict] = []
 
 
@@ -146,6 +162,12 @@ def _rewrite(expr: Expr, slots: List[Expr]) -> Expr:
         return x
 
     return transform(expr, fn)
+
+
+def _rewrite_agg(a: AggExpr, slots: List[Expr]) -> AggExpr:
+    return _rp(a, args=tuple(_rewrite(x, slots) for x in a.args),
+               filter=_rewrite(a.filter, slots) if a.filter is not None
+               else None)
 
 
 def _walk_left_spine(node, ext: _Extraction) -> Optional[List[Expr]]:
@@ -169,18 +191,29 @@ def _walk_left_spine(node, ext: _Extraction) -> Optional[List[Expr]]:
         ext.conjuncts.append(_rewrite(node.predicate, inner))
         return inner
     if isinstance(node, p.Join):
-        if node.join_type != "INNER" or node.filter is not None:
+        if node.join_type not in ("INNER", "LEFTSEMI") \
+                or node.filter is not None:
             return None
         if len(node.on) != 1 or len(ext.joins) >= _MAX_JOINS:
             return None
+        lkey_raw, rkey_raw = node.on[0]
+        rkey = shift_columns(rkey_raw, -len(node.left.schema))
+        grouped = None
+        if node.join_type == "LEFTSEMI":
+            grouped = _grouped_by_key(node.right, rkey)
+            if grouped is None:
+                return None
         left = _walk_left_spine(node.left, ext)
         if left is None:
             return None
         k = len(ext.joins)
-        lkey_raw, rkey_raw = node.on[0]
         lkey = _rewrite(lkey_raw, left)
-        rkey = shift_columns(rkey_raw, -len(node.left.schema))
-        ext.joins.append({"plan": node.right, "lkey": lkey, "rkey": rkey})
+        ext.joins.append({"plan": node.right, "lkey": lkey, "rkey": rkey,
+                          "exposes": grouped is None, "grouped": grouped})
+        if grouped is not None:
+            # the build side is unique on the key, so a left row matches at
+            # most one row of it: an INNER join that exposes no column
+            return left
         rslots = [_BuildRef(k, j, f.sql_type, f.nullable)
                   for j, f in enumerate(node.right.schema)]
         return left + rslots
@@ -194,22 +227,79 @@ def _walk_left_spine(node, ext: _Extraction) -> Optional[List[Expr]]:
     return None
 
 
+def _grouped_by_key(node, rkey: Expr) -> Optional[dict]:
+    """A semi-join's build side that is UNIQUE on its join key by
+    construction: an Aggregate grouped by ONE key, under HAVING filters,
+    column-picking Projections and aliases, whose key column is what `rkey`
+    (over `node`'s schema) reads.  ``{"agg": the Aggregate, "having": its
+    filters over the Aggregate's schema}``, or None for any other shape."""
+    having: List[Expr] = []
+
+    def down(node):
+        if isinstance(node, p.SubqueryAlias):
+            return down(node.inputs()[0])
+        if isinstance(node, p.Projection):
+            inner = down(node.input)
+            if inner is None or not all(type(e) is ColumnRef
+                                        for e in node.exprs):
+                return None
+            agg, slots = inner
+            return agg, [slots[e.index] for e in node.exprs]
+        if isinstance(node, p.Filter):
+            inner = down(node.input)
+            if inner is None:
+                return None
+            having.append(_rewrite(node.predicate, inner[1]))
+            return inner
+        if isinstance(node, p.Aggregate) and len(node.group_exprs) == 1:
+            return node, [ColumnRef(j, f.name, f.sql_type, f.nullable)
+                          for j, f in enumerate(node.schema)]
+        return None
+
+    found = down(node)
+    if found is None or type(rkey) is not ColumnRef:
+        return None
+    agg, slots = found
+    if slots[rkey.index].index != 0:
+        return None
+    return {"agg": agg, "having": having}
+
+
+def _plan_semi_build(join: dict) -> Optional[dict]:
+    """The build side of `_grouped_by_key`'s shape whose Aggregate reads a
+    filtered scan of ONE base table and groups by one of its columns, as the
+    program reduces it: ``{"scan": the scan with its conjuncts as filters,
+    "conjuncts", "key": the group column over the scan's schema, "aggs":
+    the aggregates over it, "having": the filters over [key, aggregates..],
+    "schema": the Aggregate's}``; None where it reads anything else."""
+    side = join["grouped"]
+    agg = side["agg"]
+    sub = _Extraction()
+    slots = _walk_left_spine(agg.input, sub)
+    if slots is None or sub.scan is None or sub.joins:
+        return None
+    key = _rewrite(agg.group_exprs[0], slots)
+    if type(key) is not ColumnRef:
+        return None
+    return {"scan": _rp(sub.scan, filters=list(sub.conjuncts)),
+            "conjuncts": list(sub.conjuncts), "key": key,
+            "aggs": [_rewrite_agg(a, slots) for a in agg.agg_exprs],
+            "having": side["having"], "schema": list(agg.schema)}
+
+
 def _extract(agg: p.Aggregate):
     ext = _Extraction()
     slots = _walk_left_spine(agg.input, ext)
     if slots is None or ext.scan is None or not ext.joins:
         return None
     group_exprs = [_rewrite(e, slots) for e in agg.group_exprs]
-    agg_exprs = []
-    for a in agg.agg_exprs:
-        new_args = tuple(_rewrite(x, slots) for x in a.args)
-        new_filter = _rewrite(a.filter, slots) if a.filter is not None else None
-        agg_exprs.append(_rp(a, args=new_args, filter=new_filter))
-    return ext, group_exprs, agg_exprs
+    return ext, group_exprs, [_rewrite_agg(a, slots) for a in agg.agg_exprs]
 
 
 def _plan_whole_builds(ext: _Extraction, group_exprs, agg_exprs):
-    """Mark the build sides that are a filtered scan of ONE base table
+    """Mark the semi-joins' aggregate build sides (``join["semi"]``:
+    `_plan_semi_build`'s reading, else None) and the build sides that are a
+    filtered scan of ONE base table
     (Filter / SubqueryAlias / column-picking Projection over a TableScan):
     ``join["whole"]`` gets their conjuncts over the scan's schema and
     ``join["plan"]`` becomes that scan with the conjuncts as its filters,
@@ -221,6 +311,10 @@ def _plan_whole_builds(ext: _Extraction, group_exprs, agg_exprs):
     rebase: Dict[int, List[int]] = {}
     for k, j in enumerate(ext.joins):
         j["whole"] = None
+        #: a semi-join's aggregate build side the PROGRAM reduces
+        j["semi"] = None if j["exposes"] else _plan_semi_build(j)
+        if not j["exposes"] and j["semi"] is None:
+            raise _Unsupported("semi-join over more than a filtered scan")
         sub = _Extraction()
         slots = _walk_left_spine(j["plan"], sub)
         if slots is None or sub.scan is None or sub.joins \
@@ -334,22 +428,45 @@ class LutCache:
 LUTS = LutCache(16)
 
 
-def _choose_gid_join(ext, group_exprs) -> Optional[Tuple[int, List[int]]]:
+def _reached_from(ext, m: int) -> Optional[int]:
+    """The ONE build side whose columns alone join `m`'s probe key reads
+    (ORDERS for ORDERS -> CUSTOMER under LINEITEM -> ORDERS), else None: a
+    row of that build side then determines build `m`'s row, since `m`'s key
+    is unique."""
+    subs = list(walk(ext.joins[m]["lkey"]))
+    parents = {sub.k for sub in subs if isinstance(sub, _BuildRef)}
+    if len(parents) != 1 or any(type(sub) is ColumnRef for sub in subs):
+        return None
+    (j,) = parents
+    return j
+
+
+def _column_of(build_tables, bcol: Tuple[int, int]) -> Column:
+    """Column ``(build side, position)`` of the build tables."""
+    bt = build_tables[bcol[0]]
+    return bt.columns[bt.column_names[bcol[1]]]
+
+
+def _choose_gid_join(ext, group_exprs, dependents: bool = True
+                     ) -> Optional[Tuple[int, List[Tuple[int, int]]]]:
     """Find a join k whose build-row pointer can serve as the segment id.
 
     Sound only when the group keys functionally DETERMINE the build row:
     the key set must include join k's key itself (probe-side expr, or the
-    build key column), and every other key must be a column of build k
-    (functionally dependent on the row).  Grouping by a non-key build
-    column (e.g. a category shared by many dim rows) must NOT use the
-    pointer — it would split one group per build row — that case goes
-    through the radix gid instead.  Returns (k, build col per group expr)."""
+    build key column), and every other key must be functionally dependent
+    on the row: a column of build k, or (`dependents`) a column of a build
+    side m that is reached from build k's columns alone by m's unique key
+    (`_reached_from`).  Grouping by a non-key build column (e.g. a category
+    shared by many dim rows) must NOT use the pointer — it would split one
+    group per build row — that case goes through the radix gid instead.
+    Returns (k, (build side, column) per group expr)."""
     if not group_exprs:
         return (-1, [])  # global aggregate
     for k in range(len(ext.joins) - 1, -1, -1):
         rkey = ext.joins[k]["rkey"]
-        if not (isinstance(rkey, ColumnRef) and type(rkey) is ColumnRef):
-            continue
+        if not (isinstance(rkey, ColumnRef) and type(rkey) is ColumnRef) \
+                or not ext.joins[k]["exposes"]:
+            continue  # a semi-join's build side has no row to point at
         cols = []
         has_key = False
         ok = True
@@ -357,10 +474,11 @@ def _choose_gid_join(ext, group_exprs) -> Optional[Tuple[int, List[int]]]:
             if g == ext.joins[k]["lkey"] or (
                     isinstance(g, _BuildRef) and g.k == k
                     and g.col == rkey.index):
-                cols.append(rkey.index)
+                cols.append((k, rkey.index))
                 has_key = True
-            elif isinstance(g, _BuildRef) and g.k == k:
-                cols.append(g.col)
+            elif isinstance(g, _BuildRef) and (g.k == k or (
+                    dependents and _reached_from(ext, g.k) == k)):
+                cols.append((g.k, g.col))
             else:
                 ok = False
                 break
@@ -398,7 +516,11 @@ class CompiledJoinAggregate:
         """``whole[k]``: None where build table k was executed eagerly (its
         LUT is built here, from the rows it has left), else ``{"conjuncts":
         the build side's own parameterised conjuncts, "lut": (rmin, lut)}``
-        with build table k the base table's scan, unfiltered."""
+        with build table k the base table's scan, unfiltered.  A semi-join's
+        aggregate build side the program reduces itself adds ``"semi":
+        {"domain", "key", "aggs", "having", "schema"}`` (parameterised) and
+        has ``"lut": (the key range's low end, None)``: its LUT is made in
+        the program, over the ``domain`` values of that range."""
         self.rel = rel
         self.ext = ext
         self.probe_table = probe_table
@@ -415,7 +537,15 @@ class CompiledJoinAggregate:
             getattr(c, "encoding", Encoding.PLAIN) is not Encoding.PLAIN
             for c in probe_table.columns.values())
 
-        choice = _choose_gid_join(ext, group_exprs)
+        # a key on a second build side is read through the pointer of the
+        # first: only the one-chip program evaluates join keys at a WHOLE
+        # build side's rows
+        choice = _choose_gid_join(ext, group_exprs,
+                                  type(self) is CompiledJoinAggregate)
+        if choice is not None and any(
+                bk != choice[0] for bk, _ in choice[1]) \
+                and whole[choice[0]] is None:
+            choice = _choose_gid_join(ext, group_exprs, False)
         if choice is not None:
             self.gid_join, self.group_cols = choice
             self.radix_spec = None
@@ -424,8 +554,9 @@ class CompiledJoinAggregate:
             # merge-correct form; pointer gid above is the high-cardinality
             # escape hatch for group-by-join-key shapes
             self.gid_join, self.group_cols = None, []
-            self.radix_spec = self._plan_radix(group_exprs, probe_table,
-                                               build_tables)
+            self.radix_spec = self._plan_radix(
+                group_exprs, probe_table, build_tables, len(agg_exprs) + 1,
+                executor.config)
 
         # per-build prep: a whole build side brings the LUT of its table
         # version (`LUTS`); an eagerly executed one gets its own here
@@ -443,6 +574,13 @@ class CompiledJoinAggregate:
         self._build_evs = [
             None if w is None else _TraceEval(_TableMeta(bt))
             for bt, w in zip(build_tables, whole)]
+        #: join k -> its semi-join build side as the program reduces it
+        self.semis: Dict[int, dict] = {
+            k: self._plan_semi(w["semi"], executor)
+            for k, w in enumerate(whole)
+            if w is not None and w.get("semi") is not None}
+        #: (groups, passed the HAVING) per semi-join, of the newest run
+        self.semi_stats: List[Tuple[int, int]] = []
 
         # global slot space: probe scan columns, then every _BuildRef used
         n_probe = len(probe_table.column_names)
@@ -460,6 +598,14 @@ class CompiledJoinAggregate:
                 if isinstance(sub, _BuildRef):
                     used.setdefault((sub.k, sub.col), n_probe + len(used))
         self.used_build_slots = used
+        #: build sides other than the pointer gid's that hold group keys
+        self.dependents = sorted({bk for bk, _ in self.group_cols
+                                  if bk != self.gid_join})
+        for bcol in self.group_cols:
+            if bcol[0] != self.gid_join and getattr(
+                    _column_of(build_tables, bcol), "encoding",
+                    Encoding.PLAIN) is Encoding.RLE:
+                raise _Unsupported("run-length key on a dependent build")
 
         def finalize(expr):
             def fn(x):
@@ -480,6 +626,9 @@ class CompiledJoinAggregate:
         self.lkeys = [onto_build(j["lkey"]) if k in self.folded
                       else finalize(j["lkey"])
                       for k, j in enumerate(ext.joins)]
+        #: a dependent's key over the gid build side's own columns
+        self.dep_lkeys = {m: onto_build(ext.joins[m]["lkey"])
+                          for m in self.dependents}
         if self.radix_spec is not None:
             self.radix_spec = [dict(s, ref=finalize(s["ref"]),
                                     col=_ColMeta(s["col"]))
@@ -524,6 +673,14 @@ class CompiledJoinAggregate:
         from ..ops.pallas_kernels import choose_segsum_impl
 
         self.domain = domain_est
+        from ..ops.grouping import RADIX_DOMAIN_LIMIT
+
+        #: one-key integer ranges past the mixed-radix gate that this
+        #: program reduces into (`aggregate.domain.wide`)
+        self.wide_domains = sum(
+            d > RADIX_DOMAIN_LIMIT for d in
+            [semi["domain"] for semi in self.semis.values()]
+            + ([domain_est] if self.radix_spec is not None else []))
         self.segsum_mode = choose_segsum_impl(executor.config, domain_est)
         self.topk = self._plan_topk(topk, build_tables)
         self.compact_cap = self._plan_compaction(probe_table)
@@ -538,14 +695,40 @@ class CompiledJoinAggregate:
         for k, j in self.folded.items():
             keys.update((j, sub.index) for sub in walk(self.lkeys[k])
                         if type(sub) is ColumnRef)
+        for m, lkey in self.dep_lkeys.items():
+            keys.update((self.gid_join, sub.index) for sub in walk(lkey)
+                        if type(sub) is ColumnRef)
+        for k, semi in self.semis.items():
+            exprs = [semi["key"]] + [x for a in semi["aggs"] for x in
+                                     list(a.args) + ([a.filter] if a.filter
+                                                     is not None else [])]
+            keys.update((k, sub.index) for e in exprs for sub in walk(e)
+                        if type(sub) is ColumnRef)
         if self.topk is not None:
-            keys.update((self.gid_join, col) for col in self.topk["cols"])
+            keys.update(self.topk["cols"])
         self.build_col_keys = sorted(keys)
         #: (kind, np.dtype) per packed output row; filled when _fn traces
         self._pack_tags: List[Tuple[str, np.dtype]] = []
         self._fn = jax.jit(self._build())
         #: compile-watchdog hint: True after _fn compiled for these shapes
         self._warm = False
+
+    @staticmethod
+    def _plan_semi(semi: dict, executor) -> dict:
+        """`semi` with what the trace needs beside it: the segment-sum mode
+        of its domain, and the evaluator of its HAVING filters over the
+        inner Aggregate's output (the key's values, then each aggregate)."""
+        from ..ops.pallas_kernels import choose_segsum_impl
+
+        check_agg_static_support(semi["aggs"])
+        fields = semi["schema"]
+        metas = [_ColMeta(Column(np.empty(0, dtype=sql_to_np(f.sql_type)),
+                                 f.sql_type)) for f in fields]
+        return dict(semi, mode=choose_segsum_impl(executor.config,
+                                                  semi["domain"]),
+                    key_dtype=sql_to_np(fields[0].sql_type),
+                    having_ev=_TraceEval(_SlotMeta(
+                        metas, [f"__h{i}" for i in range(len(fields))])))
 
     def _plan_folds(self, ext, whole, rest) -> Dict[int, int]:
         """Joins to probe from an earlier build side's rows instead of the
@@ -563,12 +746,9 @@ class CompiledJoinAggregate:
                        for e in exprs for sub in walk(e))
 
         for k in range(len(ext.joins) - 1, 0, -1):
-            subs = list(walk(ext.joins[k]["lkey"]))
-            parents = {sub.k for sub in subs if isinstance(sub, _BuildRef)}
-            if len(parents) != 1 or any(type(sub) is ColumnRef
-                                        for sub in subs):
+            j = _reached_from(ext, k)
+            if j is None:
                 continue
-            (j,) = parents
             later = [ext.joins[m]["lkey"] for m in range(k + 1,
                                                          len(ext.joins))
                      if folded.get(m) != k]
@@ -599,16 +779,20 @@ class CompiledJoinAggregate:
         """The top-k tail this program runs, or None where the hint is
         absent or names what the tail cannot order: ``{"k", "keys": [(kind,
         index, ascending, nulls first)], "cols"}`` with kind ``"agg"`` (an
-        aggregate's output) or ``"group"`` (a group key: a column of the
-        pointer-gid build table, ordered on its stored integers, which DICT,
-        FOR and PLAIN all keep in value order), and ``cols`` the group-key
-        columns whose rows the program gathers for the result."""
+        aggregate's output) or ``"group"`` (a group key, ``(build side,
+        column)``: a column of the pointer-gid build table or of a build
+        side reached from it, ordered as stored: integers, which DICT, FOR
+        and PLAIN all keep in value order, or PLAIN floats), and ``cols``
+        the group-key columns whose rows the program gathers for the
+        result."""
         if topk is None or not 0 < topk.k <= _MAX_TOPK:
             return None
         if self.gid_join is None or self.gid_join < 0:
             return None  # a radix domain is small: pulled whole as before
-        bt = build_tables[self.gid_join]
         n_groups = len(self.group_cols)
+
+        def column(bcol):
+            return _column_of(build_tables, bcol)
 
         def sortable(col) -> bool:
             enc = getattr(col, "encoding", Encoding.PLAIN)
@@ -622,20 +806,22 @@ class CompiledJoinAggregate:
                 keys.append(("agg", index - n_groups, asc, nulls_first))
                 continue
             bcol = self.group_cols[index]
-            if not sortable(bt.columns[bt.column_names[bcol]]):
+            if not sortable(column(bcol)):
                 return None
             keys.append(("group", bcol, asc, nulls_first))
         cols = sorted({
-            c for c in self.group_cols
-            if getattr(bt.columns[bt.column_names[c]], "encoding",
+            bcol for bcol in self.group_cols
+            if getattr(column(bcol), "encoding",
                        Encoding.PLAIN) is not Encoding.RLE})
         return {"k": topk.k, "keys": keys, "cols": cols}
 
     @staticmethod
-    def _plan_radix(group_exprs, probe_table, build_tables):
+    def _plan_radix(group_exprs, probe_table, build_tables, slots: int = 1,
+                    config=None):
         """Mixed-radix gid plan over group-key columns (same scheme as
         CompiledAggregate: dict strings / bools / small-int ranges, one
-        extra code per key for NULL)."""
+        extra code per key for NULL; ONE integer key is admitted by the
+        bytes of its state of `slots` slots, `one_key_domain_limit`)."""
         spec = []
         domain = 1
         pending = []  # (slot, device min, device max): ONE pull for all keys
@@ -676,9 +862,12 @@ class CompiledJoinAggregate:
                                    Encoding.PLAIN) is Encoding.FOR})
             else:
                 raise _Unsupported("group key not radix-encodable")
-        from ..ops.grouping import RADIX_DOMAIN_LIMIT, resolve_int_bounds
+        from ..ops.grouping import (RADIX_DOMAIN_LIMIT, one_key_domain_limit,
+                                    resolve_int_bounds)
 
-        spans = resolve_int_bounds(pending, RADIX_DOMAIN_LIMIT)
+        limit = one_key_domain_limit(slots, config) \
+            if len(group_exprs) == 1 and pending else RADIX_DOMAIN_LIMIT
+        spans = resolve_int_bounds(pending, limit)
         if spans is None:
             raise _Unsupported("integer key range too large")
         for slot, (span, lo) in spans.items():
@@ -686,7 +875,7 @@ class CompiledJoinAggregate:
             spec[slot]["off"] = lo
         for entry in spec:
             domain *= entry["r"]
-            if domain > RADIX_DOMAIN_LIMIT:
+            if domain > limit:
                 raise _Unsupported("group domain too large")
         return spec
 
@@ -706,6 +895,9 @@ class CompiledJoinAggregate:
         folded = self.folded
         topk = self.topk
         compact_cap = self.compact_cap
+        semis = self.semis
+        dependents = self.dependents
+        dep_lkeys = self.dep_lkeys
         #: the slots the aggregates read: all the compact branch gathers
         agg_slots = sorted({
             sub.index for a in agg_exprs
@@ -758,7 +950,58 @@ class CompiledJoinAggregate:
                 ri = jnp.where(inb, lut[idx32].astype(jnp.int32), jnp.int32(-1))
                 return ri if kv is None else jnp.where(kv, ri, -1)
 
+            def build_slots(k):
+                bslots = {col: build_cols[(bk, col)]
+                          for (bk, col) in build_cols if bk == k}
+                bslots[PARAMS_SLOT] = params
+                return bslots
+
+            semi_counts: List = []
+
+            def semi_lut(k):
+                """The LUT of semi-join `k`, made here: its build side's
+                aggregates reduced over ALL of that table's rows into the
+                values of the key's kept range, the HAVING filters as a mask
+                over those values (their literals runtime parameters): 0
+                where a key's group passes, -1 elsewhere."""
+                semi, bev, bslots = semis[k], build_evs[k], build_slots(k)
+                domain = semi["domain"]
+                kd, kv = bev.eval(semi["key"], bslots)
+                sel = jnp.ones(kd.shape[0], dtype=bool) if kv is None else kv
+                for f in build_conjuncts[k]:
+                    d, v = bev.eval(f, bslots)
+                    sel = sel & (d if v is None else (d & v))
+                if np.dtype(kd.dtype).itemsize < 4:
+                    kd = kd.astype(jnp.int32)
+                # every row's key lies in the kept range of its table version
+                gid = jnp.clip(kd - jnp.asarray(rmins[k], dtype=kd.dtype), 0,
+                               domain - 1).astype(jnp.int32)
+                from .compiled import SegmentReducer
+
+                reducer = SegmentReducer(gid, domain, semi["mode"],
+                                         kd.shape[0])
+                hit_h = reducer.count(sel)
+                outs = segment_agg_outputs(bev, bslots, semi["aggs"], sel,
+                                           gid, domain, reducer)
+                keep = present = reducer.get(hit_h) > 0
+                hslots = {0: (jnp.arange(domain, dtype=semi["key_dtype"])
+                              + rmins[k], None), PARAMS_SLOT: params}
+                hslots.update({1 + i: out for i, out in enumerate(outs)})
+                for f in semi["having"]:
+                    d, v = semi["having_ev"].eval(f, hslots)
+                    keep = keep & (d if v is None else (d & v))
+                semi_counts.extend([jnp.sum(present, dtype=jnp.int32),
+                                    jnp.sum(keep, dtype=jnp.int32)])
+                return jnp.where(keep, jnp.int8(0), jnp.int8(-1))
+
+            kept: Dict[int, jnp.ndarray] = {}
+
             def kept_lut(k):
+                if k not in kept:
+                    kept[k] = semi_lut(k) if k in semis else filtered_lut(k)
+                return kept[k]
+
+            def filtered_lut(k):
                 """LUT `k` without the rows that build side k's own filters
                 reject, or a join probed from its rows (`folded`) leaves
                 unmatched: the mask is evaluated over ITS rows and folded
@@ -768,9 +1011,7 @@ class CompiledJoinAggregate:
                 lut = luts[k]
                 if build_evs[k] is None:
                     return lut  # an eagerly executed build side came filtered
-                bslots = {col: build_cols[(bk, col)]
-                          for (bk, col) in build_cols if bk == k}
-                bslots[PARAMS_SLOT] = params
+                bslots = build_slots(k)
                 keep = None
                 for f in build_conjuncts[k]:
                     d, v = build_evs[k].eval(f, bslots)
@@ -778,12 +1019,32 @@ class CompiledJoinAggregate:
                     keep = d if keep is None else (keep & d)
                 for m, parent in folded.items():
                     if parent == k:
-                        kd, kv = build_evs[k].eval(lkeys[m], bslots)
-                        hit = pointer(m, kd, kv, kept_lut(m)) >= 0
+                        hit = rows_of(m, k) >= 0
                         keep = hit if keep is None else (keep & hit)
                 if keep is None:
                     return lut
                 return jnp.where(keep[jnp.clip(lut, 0, None)], lut, -1)
+
+            reached: Dict[int, jnp.ndarray] = {}
+
+            def rows_of(m, k):
+                """Build side `m`'s row (-1: none) per ROW of build side
+                `k`, whose columns alone its key reads."""
+                if m not in reached:
+                    lkey = lkeys[m] if m in folded else dep_lkeys[m]
+                    kd, kv = build_evs[k].eval(lkey, build_slots(k))
+                    reached[m] = pointer(m, kd, kv, kept_lut(m))
+                return reached[m]
+
+            def group_column(bcol):
+                """A group key's ``(data, validity)`` per row of the pointer
+                gid's build side, read through `rows_of` from another's."""
+                if bcol[0] == gid_join:
+                    return build_cols[bcol]
+                ri = rows_of(bcol[0], gid_join)
+                d, v = build_cols[bcol]
+                safe = jnp.clip(ri, 0, None)
+                return d[safe], (ri >= 0) if v is None else (ri >= 0) & v[safe]
 
             ri_safe: Dict[int, jnp.ndarray] = {}
             for k in range(n_joins):
@@ -871,33 +1132,45 @@ class CompiledJoinAggregate:
                     lambda: reduce_rows(slots, mask, gid, n_rows))
             hit = hits > 0
             tags: List[Tuple[str, np.dtype]] = []
+            # the counts the host reads beside the rows: the probe rows that
+            # passed (a compacting program), then each semi-join's groups
+            # and those of them its HAVING kept
+            counts = ([passed] if compact_cap else []) + semi_counts
             if topk is None:
                 flat = [hit]
                 for d, v in outs:
                     flat.append(d)
                     flat.append(v if v is not None else jnp.ones_like(hit))
+                # a dependent build side's row per group, for the host's take
+                flat.extend(rows_of(m, gid_join) for m in dependents)
             else:
                 # the tail: the k first present groups by the sort keys,
                 # then only their rows of every output and group-key column
                 keys = [(outs[i] if kind == "agg"
-                         else build_cols[(gid_join, i)]) + (asc, nulls_first)
+                         else group_column(i)) + (asc, nulls_first)
                         for kind, i, asc, nulls_first in topk["keys"]]
                 at, found = select_topk(hit, keys, topk["k"])
                 groups = jnp.sum(hit, dtype=jnp.int32)
                 flat = [found, at, jnp.broadcast_to(groups, at.shape)]
-                if compact_cap:
-                    flat.append(jnp.broadcast_to(passed, at.shape))
-                for d, v in outs + [build_cols[(gid_join, c)]
-                                    for c in topk["cols"]]:
+                flat.extend(jnp.broadcast_to(c, at.shape) for c in counts)
+                for d, v in outs:
                     flat.append(d[at])
                     flat.append(v[at] if v is not None
                                 else jnp.ones_like(found))
+                for bcol in topk["cols"]:
+                    rows = at if bcol[0] == gid_join else jnp.clip(
+                        rows_of(bcol[0], gid_join)[at], 0, None)
+                    d, v = build_cols[bcol]
+                    flat.append(d[rows])
+                    flat.append(v[rows] if v is not None
+                                else jnp.ones_like(found))
             out = pack_flat(flat, tags)
             self._pack_tags = tags
-            # `passed` rides in the top-k pack; the plain pack is the one
-            # the other rungs pull (`fetch_packed`), so there it is a
-            # second, scalar output
-            return (out, passed) if compact_cap and topk is None else out
+            # the counts ride in the top-k pack; the plain pack is the one
+            # the other rungs pull (`fetch_packed`), so there they are a
+            # second, small output
+            return (out, jnp.stack(counts)) if counts and topk is None \
+                else out
 
         # domains are python ints (build table row counts) — bind them now
         build_domains = [bt.num_rows for bt in self.build_tables]
@@ -941,6 +1214,8 @@ class CompiledJoinAggregate:
                         "segsum": self.segsum_mode}
         if cap:
             launch_attrs["compact"] = cap
+        if self.semis:
+            launch_attrs["semi"] = len(self.semis)
         packed = timed_jit_call(
             "compiled_join_aggregate", self._fn, *args,
             may_compile=not self._warm, launch_attrs=launch_attrs)
@@ -953,29 +1228,33 @@ class CompiledJoinAggregate:
         # `fetch` child also holds the wait for the device) and the decode
         with detail("join:tail") as attrs:
             if self.topk is not None:
-                result, groups, passed = self._decode_topk(packed, tags)
+                result, groups, counts = self._decode_topk(packed, tags)
             else:
-                if cap:  # the program's outputs: (pack, passed)
-                    host, present, passed = _fetch_packed_and_passed(
+                if cap or self.semis:  # the program's outputs: (pack, counts)
+                    host, present, counts = _fetch_packed_and_counts(
                         *packed, self.domain)
                 else:
                     host, present = fetch_packed(packed, self.domain)
+                    counts = []
                 result = self._decode_result(host, present, tags)
                 groups = int(present.shape[0])
             attrs.update(groups=groups, rows=result.num_rows)
             if cap:
+                passed, counts = counts[0], counts[1:]
                 attrs.update(passed=passed, cap=cap)
+            self.semi_stats = list(zip(counts[::2], counts[1::2]))
         if cap:
             self.metrics.inc("join.compact.engaged")
             if passed > cap:
                 self.metrics.inc("join.compact.overflow")
         return result
 
-    def _decode_topk(self, packed, tags) -> Tuple[Table, int, Optional[int]]:
+    def _decode_topk(self, packed, tags) -> Tuple[Table, int, List[int]]:
         """The host's half of the top-k tail: one pull of the ``[rows, k]``
         pack, then the found rows as a host-resident table in the tail's
-        order, the count of present groups and, from a compacting program,
-        of the probe rows that passed."""
+        order, the count of present groups and the program's other counts
+        (`_build`: the probe rows that passed, from a compacting program,
+        then two per semi-join)."""
         from ..utils import d2h_fetch
         from .compiled import unpack_row
         from .rel.base import unique_names
@@ -985,16 +1264,16 @@ class CompiledJoinAggregate:
         n = int(np.count_nonzero(host[0]))  # found rows come first
         at = unpack_row(host, 1, tags)
         groups = int(unpack_row(host, 2, tags)[0])
-        head = 3  # found, at, groups; a compacting program adds `passed`
-        passed = None
-        if self.compact_cap:
-            passed = int(unpack_row(host, head, tags)[0])
-            head += 1
+        head = 3 + bool(self.compact_cap) + 2 * len(self.semis)
+        counts = [int(unpack_row(host, i, tags)[0]) for i in range(3, head)]
         pairs = iter(range(head, host.shape[0], 2))
 
         def pulled(i):
             v = unpack_row(host, i + 1, tags)[:n] != 0
             return unpack_row(host, i, tags)[:n], None if v.all() else v
+
+        def column(bcol):
+            return _column_of(self.build_tables, bcol)
 
         names = unique_names([f.name for f in self.rel.schema])
         n_groups = len(self.group_cols)
@@ -1004,24 +1283,23 @@ class CompiledJoinAggregate:
             target = sql_to_np(a.sql_type)
             out[name] = Column(d.astype(target) if d.dtype != target else d,
                                a.sql_type, validity)
-        bt = self.build_tables[self.gid_join]
-        keys: Dict[int, Column] = {}
-        for col in self.topk["cols"]:
+        keys: Dict[Tuple[int, int], Column] = {}
+        for bcol in self.topk["cols"]:
             d, validity = pulled(next(pairs))
-            keys[col] = decode_radix_group_key(
-                _ColMeta(bt.columns[bt.column_names[col]]), d, 0, validity)
-        for col in set(self.group_cols) - set(keys):
+            keys[bcol] = decode_radix_group_key(_ColMeta(column(bcol)), d, 0,
+                                                validity)
+        for bcol in set(self.group_cols) - set(keys):
             # an RLE key has no row to gather in the program: k rows of it
             # (a static shape), cut to the found ones on the host
-            c = bt.columns[bt.column_names[col]].take(jnp.asarray(at))
+            c = column(bcol).take(jnp.asarray(at))
             with d2h_fetch():
                 d, v = jax.device_get((c.data, c.validity))
-            keys[col] = Column(
+            keys[bcol] = Column(
                 np.asarray(d)[:n], c.sql_type,
                 None if v is None else np.asarray(v)[:n], c.dictionary)
-        group_out = {name: keys[col]
-                     for name, col in zip(names, self.group_cols)}
-        return Table({**group_out, **out}, n), groups, passed
+        group_out = {name: keys[bcol]
+                     for name, bcol in zip(names, self.group_cols)}
+        return Table({**group_out, **out}, n), groups, counts
 
     def _decode_result(self, host, present, tags, build_tables=None) -> Table:
         from .compiled import unpack_row
@@ -1066,10 +1344,14 @@ class CompiledJoinAggregate:
                                                    spec["off"], validity)
             n_groups = len(self.radix_spec)
         elif self.gid_join is not None and self.gid_join >= 0:
-            bt = build_tables[self.gid_join]
-            for name, col_idx in zip(names, self.group_cols):
-                c = bt.columns[bt.column_names[col_idx]]
-                out[name] = c.take(present)
+            # a dependent build side's row per present group rides behind
+            # the aggregates' rows of the pack
+            n_aggs = len(self.rel.agg_exprs)
+            rows = {self.gid_join: present}
+            for i, m in enumerate(self.dependents):
+                rows[m] = unpack_row(host, 1 + 2 * n_aggs + i, tags)
+            for name, bcol in zip(names, self.group_cols):
+                out[name] = _column_of(build_tables, bcol).take(rows[bcol[0]])
             n_groups = len(self.group_cols)
         else:
             n_groups = 0
@@ -1096,23 +1378,23 @@ def _plan_nodes(node):
 PROGRAMS = ProgramCache("compiled_join_aggregate", 16)
 
 
-def _fetch_packed_and_passed(packed, passed, domain: int):
-    """`compiled.fetch_packed` for a compacting program without a top-k
-    tail, whose second output is the scalar `passed`: the same ONE pull,
-    the scalar in it: ``(host_matrix[:, present], present, passed)``."""
+def _fetch_packed_and_counts(packed, counts, domain: int):
+    """`compiled.fetch_packed` for a program without a top-k tail whose
+    second output is its small vector of counts (`_build`): the same ONE
+    pull, the counts in it: ``(host_matrix[:, present], present, counts)``."""
     from ..utils import d2h_fetch
     from .compiled import HOST_PULL_DOMAIN
 
     if domain <= HOST_PULL_DOMAIN:
         with d2h_fetch(nbytes=int(packed.nbytes)):
-            host, passed = jax.device_get((packed, passed))
+            host, counts = jax.device_get((packed, counts))
         present = np.nonzero(host[0] != 0.0)[0]
-        return host[:, present], present, int(passed)
+        return host[:, present], present, [int(c) for c in counts]
     present_dev = jnp.nonzero(packed[0] != 0.0)[0]
     with d2h_fetch():
-        host, present, passed = jax.device_get(
-            (packed[:, present_dev], present_dev, passed))
-    return np.asarray(host), np.asarray(present), int(passed)
+        host, present, counts = jax.device_get(
+            (packed[:, present_dev], present_dev, counts))
+    return np.asarray(host), np.asarray(present), [int(c) for c in counts]
 
 
 def _whole_lut(executor, join: dict, bdc, table: Table):
@@ -1125,6 +1407,70 @@ def _whole_lut(executor, join: dict, bdc, table: Table):
     return LUTS.get_or_build(
         (bdc.uid, str(join["rkey"]), budget),
         lambda: build_lut(executor, join["rkey"], table, max_bytes=budget))
+
+
+def _key_range(executor, key: Expr, table: Table) -> Optional[Tuple[int, int]]:
+    """``(lowest, highest)`` value of integer column `key` of `table`, or
+    None where it is no integer column, holds no value, or the table is
+    padded (a sharded one: the sharded rungs' to serve)."""
+    from .compiled import padded_int_bounds
+    from ..utils import host_ints
+
+    if table.row_valid is not None or not table.num_rows:
+        return None
+    kc = executor.eval_expr(key, table).decode()
+    if not jnp.issubdtype(kc.data.dtype, jnp.integer):
+        return None
+    lo, hi, valid = host_ints(
+        *padded_int_bounds(kc.data, kc.validity),
+        jnp.bool_(True) if kc.validity is None else jnp.any(kc.validity))
+    return (lo, hi) if valid else None
+
+
+def _semi_build(executor, ctx, join: dict, pz, attrs: dict):
+    """A semi-join's aggregate build side as the program reduces it:
+    ``(its scan's table, CompiledJoinAggregate's whole[k])``, or None where
+    the rule declines it (and with it the rung, as before PR 35): a
+    table the request overrides, a run-length or non-integer key, a range
+    of key values whose ``[domain]`` state `one_key_domain_limit` refuses.
+    The key's range is kept per table version (`LUTS`); the HAVING filters'
+    and the aggregates' literals become runtime parameters of `pz`."""
+    from ..ops.grouping import one_key_domain_limit
+
+    semi, scan = join["semi"], join["semi"]["scan"]
+    if (scan.schema_name, scan.table_name) in executor.table_overrides:
+        return None
+    sdc = ctx.schema[scan.schema_name].tables.get(scan.table_name)
+    if sdc is None:
+        return None
+    table = executor.get_table(scan.schema_name, scan.table_name)
+    if scan.projection is not None:
+        table = table.select(scan.projection)
+    if not table.column_names or any(
+            getattr(c, "encoding", Encoding.PLAIN) is Encoding.RLE
+            for c in table.columns.values()):
+        return None
+    span, built_here = LUTS.get_or_build(
+        (sdc.uid, "range", str(semi["key"])),
+        lambda: _key_range(executor, semi["key"], table))
+    if span is None:
+        return None
+    domain = span[1] - span[0] + 1
+    attrs.update(domain=domain, rows=table.num_rows, reused=not built_here)
+    if domain > one_key_domain_limit(len(semi["aggs"]) + 1, executor.config):
+        return None
+
+    def dictionary_of(i):
+        return table.columns[table.column_names[i]].dictionary
+
+    return table, {
+        "lut": (span[0], None),
+        "conjuncts": [pz.rewrite(e, dictionary_of)
+                      for e in semi["conjuncts"]],
+        "semi": {"domain": domain, "key": semi["key"],
+                 "aggs": [pz.rewrite_agg(a) for a in semi["aggs"]],
+                 "having": [pz.rewrite(e) for e in semi["having"]],
+                 "schema": semi["schema"]}}
 
 
 def _stays_whole(k: int, join: dict, table: Table, ext, group_exprs,
@@ -1212,12 +1558,25 @@ def try_compiled_join_aggregate(rel: p.Aggregate, executor) -> Optional[Table]:
         # all the host does per request to have the build sides ready
         build_tables: List[Table] = []
         whole: List[Optional[dict]] = []
+        semi_attrs: List[dict] = []
         with detail("join:build") as attrs:
             built = lut_bytes = 0
             for k, j in enumerate(ext.joins):
                 w = None
                 scan = j["plan"]
-                if j["whole"] is not None and (
+                if j["semi"] is not None:
+                    # all the host does per request for this build side:
+                    # its table and its key's kept range; the program
+                    # reduces it
+                    with detail("join:semi") as sattrs:
+                        got = _semi_build(executor, ctx, j, pz, sattrs)
+                    if got is None:
+                        # today's path: the interpreted converters, and no
+                        # eager run of the subquery for a LUT it may fail
+                        raise _Unsupported("semi-join build side declined")
+                    bt, w = got
+                    semi_attrs.append(sattrs)
+                elif j["whole"] is not None and (
                         scan.schema_name, scan.table_name
                 ) not in executor.table_overrides:
                     bdc = ctx.schema[scan.schema_name].tables[scan.table_name]
@@ -1258,6 +1617,14 @@ def try_compiled_join_aggregate(rel: p.Aggregate, executor) -> Optional[Table]:
                   (j["plan"].schema_name, j["plan"].table_name,
                    tuple(j["plan"].projection or ()),
                    tuple(str(e) for e in w["conjuncts"]))
+                  if "semi" not in w else
+                  ("semi", j["semi"]["scan"].schema_name,
+                   j["semi"]["scan"].table_name,
+                   tuple(j["semi"]["scan"].projection or ()),
+                   tuple(str(e) for e in w["conjuncts"]),
+                   str(w["semi"]["key"]),
+                   tuple(str(a) for a in w["semi"]["aggs"]),
+                   tuple(str(e) for e in w["semi"]["having"]))
                   for j, w in zip(ext.joins, whole)),
             tuple(str(j["lkey"]) + "=" + str(j["rkey"]) for j in ext.joins),
             tuple(str(e) for e in ext.conjuncts),
@@ -1284,16 +1651,25 @@ def try_compiled_join_aggregate(rel: p.Aggregate, executor) -> Optional[Table]:
             record_predicate_spaces(ctx, compiled)
             if compiled.compact_cap:
                 ctx.metrics.inc("join.compact.programs")
-            kept = sum(w is not None for w in whole)
+            kept = sum(w is not None and "semi" not in w for w in whole)
+            semi = len(compiled.semis)
             if kept:
                 ctx.metrics.inc("join.build.whole", kept)
-            if kept < len(whole):
-                ctx.metrics.inc("join.build.eager", len(whole) - kept)
+            if semi:
+                ctx.metrics.inc("join.build.semi", semi)
+            if kept + semi < len(whole):
+                ctx.metrics.inc("join.build.eager", len(whole) - kept - semi)
+            if compiled.wide_domains:
+                ctx.metrics.inc("aggregate.domain.wide",
+                                compiled.wide_domains)
         try:
             from ..resilience import faults
 
             faults.maybe_inject("oom", executor.config)
             result = compiled.run(params)
+            for sattrs, (groups, passed) in zip(semi_attrs,
+                                                compiled.semi_stats):
+                sattrs.update(groups=groups, passed=passed)
             if compiled.has_encoded:
                 ctx.metrics.inc("columnar.encoding.late_rows",
                                 result.num_rows)

@@ -64,6 +64,15 @@ DOCUMENTED_METRICS = frozenset({
     "join.compact.programs",
     "join.compact.engaged",
     "join.compact.overflow",
+    # programs built with a semi-join whose build side is an aggregate
+    # grouped by the join key (IN over GROUP BY .. HAVING), reduced inside
+    # the program (one per such build side)
+    "join.build.semi",
+    # physical/compiled.py + compiled_join.py — programs built with ONE
+    # integer group key whose range lies past the mixed-radix gate
+    # (`ops.grouping.one_key_domain_limit`: admitted by the bytes of its
+    # state)
+    "aggregate.domain.wide",
     # inference/ — model lowering + fused PREDICT (docs/ml.md)
     "inference.model.registered",
     "inference.model.lowered",

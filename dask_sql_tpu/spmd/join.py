@@ -192,6 +192,8 @@ def try_spmd_join_aggregate(rel: p.Aggregate, executor) -> Optional[Table]:
     if extraction is None:
         return None
     ext, group_exprs, agg_exprs = extraction
+    if not all(j["exposes"] for j in ext.joins):
+        return None  # a semi-join's build side is the one-chip program's
     try:
         from ..datacontainer import LazyParquetContainer
 

@@ -153,8 +153,10 @@ def test_stream_disabled_restores_plain_shed():
 
 
 def test_construction_ineligible_routed_plan_resheds():
-    # a shape the static routing walk cannot rule out: PLAIN int group
-    # keys whose device span overflows the 1<<22 radix gate.  The rung
+    # a shape the static routing walk cannot rule out: a PLAIN int group
+    # key whose device span overflows what ONE key's state may cost
+    # (`one_key_domain_limit`: 2^26 values at two slots; until PR 35 the
+    # 1<<22 radix gate, which 1<<23 overflowed).  The rung
     # discovers it at construction — and must RE-SHED with the gate's 429
     # rather than decline down the ladder into a full over-budget
     # single-launch execution (the regression this guards against)
@@ -163,7 +165,7 @@ def test_construction_ineligible_routed_plan_resheds():
                      "columnar.encoding": "off"})
     rng = np.random.RandomState(5)
     df = pd.DataFrame({
-        "k": rng.choice([0, 1 << 23], N_ROWS).astype(np.int64),
+        "k": rng.choice([0, 1 << 27], N_ROWS).astype(np.int64),
         "v": rng.randint(0, 100, N_ROWS).astype(np.int64),
     })
     c.create_table("t", df)

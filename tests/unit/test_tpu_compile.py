@@ -414,3 +414,120 @@ def test_join_aggregate_q3_fits_one_chip_at_cell_size(one_chip):
     resident = 54 * Q3_ROWS["lineitem"] + 40 * Q3_ROWS["orders"] \
         + 40 * Q3_ROWS["customer"]
     assert _device_bytes(compiled) + resident < HBM_BYTES
+
+
+#: the benchmark's Q18 cell (sf10_q18_library): the Q3 cell's tables; what
+#: create_table encodes Q18's columns to at that size, and the range its
+#: 6.0M order keys span (8 of every 32 are used)
+Q18_DTYPES = {"l_orderkey": "int32", "l_quantity": "int16",
+              "o_orderkey": "int32", "o_custkey": "int32",
+              "o_totalprice": "float64", "o_orderdate": "int16",
+              "c_custkey": "int32", "c_name": "int32"}
+Q18_KEY_RANGE = 24_007_040
+
+
+def test_join_aggregate_q18_fits_one_chip_at_cell_size(one_chip):
+    """`sf10_q18_library`'s ONE program compiled for a v5e at the cell's
+    shapes (24M / 6M / 1.5M rows): the semi-join's build side reduced in the
+    program (a float64 scatter and a count of ALL 24M LINEITEM rows into the
+    24M values of the order keys' range, the HAVING as a mask with a runtime
+    threshold), ORDERS' LUT narrowed at ORDERS' rows by that mask and by the
+    CUSTOMER join, one pointer join of the probe, the passing rows compacted
+    (a `lax.cond`), and a top-100 by two group attributes whose customer
+    columns are read through ORDERS' pointer.  Captured from Q18 as
+    `perfbench.traffic` renders it over the cell's generator at 200,000
+    lineitems, then traced anew with the cell's domains.  Read here: compile
+    seconds, temporaries, and that no 64-bit sort is in the compiled text.
+    Costs the suite 3 s of set-up and the compile (printed with -s)."""
+    import time
+    from types import SimpleNamespace
+
+    from dask_sql_tpu import Context
+    from dask_sql_tpu import config as config_module
+    from dask_sql_tpu.physical import compiled_join
+    from dask_sql_tpu.physical.compiled_join import CompiledJoinAggregate
+    from perfbench import traffic
+    from perfbench.datagen import tpch_q18_tables
+
+    arrays = tpch_q18_tables.generate(SMALL_ROWS, seed=35, scale_factor=10)
+    frames = tpch_q18_tables.arrow_tables(arrays)
+    seen = []
+    run = CompiledJoinAggregate.run
+
+    def spy_run(self, params=()):
+        seen.append((self, self.probe_table, self._run_args(params)))
+        return run(self, params)
+
+    with pytest.MonkeyPatch.context() as mp, \
+            config_module.set({"serving.cache.enabled": False}):
+        mp.setattr(CompiledJoinAggregate, "run", spy_run)
+        mp.setattr(compiled_join, "_COMPACT_MIN_ROWS", SMALL_ROWS)
+        compiled_join.PROGRAMS.clear()
+        c = Context()
+        for name in ("customer", "orders", "lineitem"):
+            c.create_table(name, frames[name])
+        c.sql(traffic.render(traffic.load("queries", "tpch_q18"),
+                             {"QUANTITY": 313})).compute()
+    (pipeline, probe_table, args), = seen
+    assert pipeline.topk is not None and pipeline.topk["k"] == 100
+    assert list(pipeline.semis) == [2] and pipeline.folded == {1: 0, 2: 0}
+    assert pipeline.dependents == [1] and pipeline.gid_join == 0
+    probe_datas, probe_valids, luts, build_cols, row_valid, params = args
+    assert row_valid is None and not any(v is not None for v in probe_valids)
+    assert luts[2] is None and len(params) == 1
+
+    def shaped(rows, name, data):
+        return jax.ShapeDtypeStruct((rows,), Q18_DTYPES.get(name, data.dtype),
+                                    sharding=one_chip)
+
+    names = ("orders", "customer", "lineitem")
+    tables = [c.schema[c.schema_name].tables[n].table for n in names]
+    rows = [Q3_ROWS[n] for n in names]
+    scans = [j["plan"] for j in pipeline.ext.joins[:2]] \
+        + [pipeline.ext.joins[2]["semi"]["scan"]]
+    big_probe = tuple(shaped(Q3_ROWS["lineitem"], n, d) for n, d in
+                      zip(probe_table.column_names, probe_datas))
+    big_luts = tuple(jax.ShapeDtypeStruct((n,), jnp.int32, sharding=one_chip)
+                     for n in (Q18_KEY_RANGE, Q3_ROWS["customer"])) + (None,)
+    big_build = {}
+    for (k, col), (data, valid) in build_cols.items():
+        assert valid is None
+        name = (scans[k].projection or tables[k].column_names)[col]
+        big_build[(k, col)] = (shaped(rows[k], name, data), None)
+    pipeline.probe_table = probe_table
+    pipeline.build_tables = [SimpleNamespace(num_rows=n) for n in rows]
+    pipeline.domain = rows[0]
+    pipeline.semis[2]["domain"] = Q18_KEY_RANGE
+    cap = pipeline.compact_cap = compiled_join.compact_capacity(
+        Q3_ROWS["lineitem"])
+    try:
+        lowered = jax.jit(pipeline._build()).lower(
+            big_probe, probe_valids, big_luts, big_build, None,
+            _shapes(tuple(params), one_chip))
+    finally:
+        pipeline.probe_table = pipeline.build_tables = None
+    t0 = time.perf_counter()
+    compiled = lowered.compile()
+    seconds = time.perf_counter() - t0
+    m = compiled.memory_analysis()
+    print(f"q18 at cell size: compile {seconds:.1f} s, arguments "
+          f"{m.argument_size_in_bytes}, temporaries {m.temp_size_in_bytes}, "
+          f"output {m.output_size_in_bytes}")
+    assert seconds < 240, seconds
+    assert _device_bytes(compiled) < HBM_BYTES
+    # the scatters: the semi-join's sum and two counts (its rows, and those
+    # whose quantity is no NaN) over all of LINEITEM into the key range,
+    # then the outer sum and its two counts, over the compact buffer in one
+    # branch and over the whole probe in the other
+    updates = re.findall(r'"stablehlo\.scatter"\(.*?\}\) : \(tensor<(\d+)xf?\w+>, '
+                         r"tensor<(\d+)x1xi32>", lowered.as_text(), re.S)
+    assert sorted((int(d), int(n)) for d, n in updates) == sorted(
+        [(Q18_KEY_RANGE, Q3_ROWS["lineitem"])] * 3
+        + [(Q3_ROWS["orders"], cap)] * 3
+        + [(Q3_ROWS["orders"], Q3_ROWS["lineitem"])] * 3), updates
+    # every sort is 32-bit: the TPU's lowering of scatter-add and the
+    # compaction's one; the top-100 tail brings none
+    text = compiled.as_text()
+    sorts = re.findall(r"= \(?(\w+)\[(\d+)\][^=]*? sort\(", text)
+    assert sorts and all(dtype in ("s32", "u32", "f32", "pred")
+                         for dtype, _ in sorts), sorts
