@@ -17,7 +17,7 @@ from __future__ import annotations
 
 import logging
 import re
-from typing import Callable, Dict, List, Optional, Tuple
+from typing import Callable, Dict, List, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -184,13 +184,17 @@ def count_codespace_predicates(exprs, table) -> Tuple[int, int]:
 
 def record_predicate_spaces(ctx, compiled) -> None:
     """A freshly built pipeline's `count_codespace_predicates` pair into the
-    ``columnar.encoding.*`` counters (once per build, not per query)."""
+    ``columnar.encoding.*`` counters, and the SUM / AVG aggregates it sums in
+    code space (`count_codespace_sums`) into ``aggregate.sum.codespace``
+    (once per build, not per query)."""
     if compiled.codespace_preds:
         ctx.metrics.inc("columnar.encoding.codespace_pred",
                         compiled.codespace_preds)
     if compiled.valuespace_preds:
         ctx.metrics.inc("columnar.encoding.valuespace_pred",
                         compiled.valuespace_preds)
+    if getattr(compiled, "sum_codespace", 0):
+        ctx.metrics.inc("aggregate.sum.codespace", compiled.sum_codespace)
 
 
 def check_agg_static_support(agg_exprs):
@@ -299,6 +303,12 @@ class SegmentReducer:
         # (ADVICE r3).  Pin every keyed object for the reducer's lifetime.
         self._keepalive: List = []
 
+    @property
+    def total_rows(self) -> int:
+        """The most rows one group of a finished state can hold (a mesh
+        reducer combines every shard's: spmd/aggregate.py)."""
+        return self.n_rows
+
     # -- immediate scatter reductions ---------------------------------------
     def _scatter(self, x):
         return jax.ops.segment_sum(x, self.gid, self.domain)
@@ -338,6 +348,20 @@ class SegmentReducer:
             h = self._push(jnp.where(mask, data, jnp.zeros_like(data)))
         self._fdedup[key] = h
         self._keepalive.append((data, mask))
+        return h
+
+    def sum_whole(self, data, mask, dtype):
+        """Segment sum of int32 whole numbers that `WholeSum` has bounded
+        (``total_rows * max|value| < 2**31``: no group's sum can wrap), as
+        ONE int32 scatter, exact in any order, converted at ``[domain]`` to
+        `dtype`.  Deduped like `sum_float`."""
+        key = (id(data), id(mask))
+        h = self._fdedup.get(key)
+        if h is None:
+            h = ("done", self._scatter(
+                jnp.where(mask, data, jnp.zeros_like(data))).astype(dtype))
+            self._fdedup[key] = h
+            self._keepalive.append((data, mask))
         return h
 
     def sum_int(self, data, mask):
@@ -410,16 +434,91 @@ def compact_positions(mask, cap: int):
     return at
 
 
-def agg_argument(ev, slots, a: AggExpr, sel, cache: Dict[Tuple, Tuple]):
+class WholeSum(NamedTuple):
+    """How a SUM / AVG over a raw DICT column stays in code space: the
+    dictionary's values as int32, affine in the code (``code * step + base``,
+    `table` None) or looked up in `table`, and the dtype today's decoded sum
+    has (float64 for a DOUBLE dictionary, int64 for an integer one)."""
+
+    table: Optional[np.ndarray]
+    step: int
+    base: int
+    dtype: np.dtype
+
+    def values(self, codes):
+        """The per-row int32 values of the column's `codes`."""
+        if self.table is None:
+            return codes.astype(jnp.int32) * jnp.int32(self.step) \
+                + jnp.int32(self.base)
+        return jnp.asarray(self.table)[
+            jnp.clip(codes, 0, len(self.table) - 1)]
+
+
+def codespace_sum(ev, a: AggExpr, mode: str, total_rows: int
+                  ) -> Optional[WholeSum]:
+    """The `WholeSum` of aggregate `a` where a reducer in `mode` over
+    `total_rows` rows may sum it as int32, else None (today's decode and
+    float64 / int64 scatter).  It may when `a` is a SUM or AVG of a raw
+    reference to a numeric DICT column whose dictionary, read here on the
+    host, is float64 or integer (a float32 dictionary's decoded sum is not
+    exact, so the two answers would differ) and holds finite whole numbers
+    alone with ``total_rows * max|value| < 2**31`` (every row in one group
+    cannot wrap an int32), and the mode is
+    ``scatter``: in ``matmul`` mode the column rides the one blocked matmul
+    with every other sum, and a scatter beside it would only add a pass.
+    The dictionary also answers the NaN test (`agg_argument`).  What the
+    trace asks (`segment_agg_outputs`) and what `count_codespace_sums`
+    counts: both ask here."""
+    if a.func not in ("sum", "avg") or mode != "scatter":
+        return None
+    col = ev._dict_source(a.args[0])
+    if col is None:
+        return None
+    vals = np.asarray(col.enc_values)
+    if vals.size == 0 or not (vals.dtype == np.float64
+                              or vals.dtype.kind == "i"):
+        return None
+    if vals.dtype.kind == "f" and not (
+            np.isfinite(vals).all() and (vals == np.rint(vals)).all()):
+        return None
+    if max(total_rows, 1) * max(abs(int(vals.min())),
+                                abs(int(vals.max()))) >= 1 << 31:
+        return None
+    whole = np.rint(vals).astype(np.int64)
+    step = int(whole[1] - whole[0]) if len(whole) > 1 else 0
+    affine = bool((np.diff(whole) == step).all())
+    return WholeSum(None if affine else whole.astype(np.int32), step,
+                    int(whole[0]),
+                    np.dtype(np.float64 if vals.dtype.kind == "f"
+                             else np.int64))
+
+
+def count_codespace_sums(agg_exprs, table, mode: str, total_rows: int) -> int:
+    """Static count of the aggregates a pipeline over `table` sums in code
+    space (``aggregate.sum.codespace``): `codespace_sum`'s own answer for the
+    reducer's mode and rows, so the counter cannot disagree with the
+    kernel."""
+    ev = _TraceEval(table)
+    return sum(codespace_sum(ev, a, mode, total_rows) is not None
+               for a in agg_exprs)
+
+
+def agg_argument(ev, slots, a: AggExpr, sel, cache: Dict[Tuple, Tuple],
+                 whole: Optional[WholeSum] = None):
     """One aggregate's ``(argument_or_None, validity)`` pair under trace:
     the row-selection mask ANDed with the FILTER clause and the argument's
     own validity (floats additionally drop NaNs — pandas dropna parity).
+    Given `whole` the argument is the DICT column's int32 values, never
+    decoded, and its dictionary has answered the NaN test: where the column
+    has no validity and the aggregate no FILTER the mask IS `sel`, which
+    `SegmentReducer.count` dedupes by identity.
     Deduped by (arg, filter) repr in ``cache`` so identical masks register
     once.  Shared by the finalized-output kernels (below) and the streamed
     partial-state kernel (streaming/aggregate.py) so their NULL semantics
     can never drift."""
     key = (str(a.args[0]) if a.args else "*",
-           str(a.filter) if a.filter is not None else None)
+           str(a.filter) if a.filter is not None else None,
+           whole is not None)
     got = cache.get(key)
     if got is not None:
         return got
@@ -430,7 +529,11 @@ def agg_argument(ev, slots, a: AggExpr, sel, cache: Dict[Tuple, Tuple]):
     if not a.args:
         got = (None, valid)
     else:
-        ad, av = ev.eval(a.args[0], slots)
+        if whole is not None:
+            codes, av = slots[a.args[0].index]
+            ad = whole.values(codes)
+        else:
+            ad, av = ev.eval(a.args[0], slots)
         v = valid if av is None else (valid & av)
         if jnp.issubdtype(ad.dtype, jnp.floating):
             v = v & ~jnp.isnan(ad)
@@ -454,19 +557,19 @@ def segment_agg_outputs(ev, slots, agg_exprs, sel, gid, domain, reducer):
     sum `min_count=1`, dropna-style counts)."""
     arg_cache: Dict[Tuple, Tuple] = {}
 
-    def arg_of(a):
-        return agg_argument(ev, slots, a, sel, arg_cache)
-
     # phase A: register reductions
     plans = []
     for a in agg_exprs:
-        ad, v = arg_of(a)
+        whole = codespace_sum(ev, a, reducer.mode, reducer.total_rows)
+        ad, v = agg_argument(ev, slots, a, sel, arg_cache, whole)
         cnt_h = reducer.count(v)
         if a.func in ("count", "count_star"):
             plans.append(("count", cnt_h))
             continue
         if a.func in ("sum", "avg"):
-            if ad.dtype == jnp.bool_:
+            if whole is not None:
+                h = reducer.sum_whole(ad, v, whole.dtype)
+            elif ad.dtype == jnp.bool_:
                 h = reducer.sum_int(ad.astype(jnp.int32), v)
             elif jnp.issubdtype(ad.dtype, jnp.integer):
                 h = reducer.sum_int(ad, v)
@@ -1165,6 +1268,9 @@ class CompiledAggregate:
                                  + ([a.filter] if a.filter is not None
                                     else [])],
                 table) if self.has_encoded else (0, 0)
+        #: SUM / AVG aggregates the kernel sums in code space
+        #: (`aggregate.sum.codespace`); `_build` counts its own
+        self.sum_codespace = 0
         #: (kind, np.dtype) per packed output row; rebound atomically each
         #: time a variant traces (solo and batched traces on concurrent
         #: threads produce identical tags — rebinding instead of clearing
@@ -1190,6 +1296,8 @@ class CompiledAggregate:
         ev = _TraceEval(_TableMeta(self.table))
         agg_exprs = self.agg_exprs
         domain = self.domain
+        self.sum_codespace = count_codespace_sums(
+            agg_exprs, ev.table, self.segsum_mode, self.table.padded_rows)
 
         def fn(datas, valids, row_valid, params=()):
             slots, sel, gid, nr = self._trace_prelude(ev, datas, valids,
@@ -1266,6 +1374,11 @@ class CompiledAggregate:
         partial states across the mesh before the shared finalize."""
         return SegmentReducer(gid, domain, self.segsum_mode, n_rows)
 
+    def _launch_attrs(self) -> Optional[dict]:
+        """What this rung's `launch` spans carry beside `rung`."""
+        return {"sum_codespace": self.sum_codespace} \
+            if self.sum_codespace else None
+
     @property
     def batchable(self) -> bool:
         """Eligible for the family batcher's stacked (vmapped) launch: the
@@ -1288,7 +1401,8 @@ class CompiledAggregate:
         packed = timed_jit_call("compiled_aggregate", self._fn,
                                 tuple(datas), tuple(valids),
                                 table.row_valid, tuple(params),
-                                may_compile=not self._warm)
+                                may_compile=not self._warm,
+                                launch_attrs=self._launch_attrs())
         self._warm = True
         tags = self._pack_tags
         host, present = fetch_packed(packed, self.domain)
@@ -1314,7 +1428,8 @@ class CompiledAggregate:
         valids = tuple(table.columns[c].validity for c in table.column_names)
         packed = timed_jit_call("compiled_aggregate", self._fn_batched,
                                 datas, valids, table.row_valid, stacked,
-                                may_compile=bucket not in self._warm_batch)
+                                may_compile=bucket not in self._warm_batch,
+                                launch_attrs=self._launch_attrs())
         self._warm_batch.add(bucket)
         tags = self._pack_tags
         with d2h_fetch(nbytes=int(packed.nbytes)):

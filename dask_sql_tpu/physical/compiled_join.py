@@ -96,6 +96,7 @@ from .compiled import (
     check_no_rle,
     compact_positions,
     count_codespace_predicates,
+    count_codespace_sums,
     record_predicate_spaces,
     decode_radix_group_key,
     segment_agg_outputs,
@@ -684,6 +685,17 @@ class CompiledJoinAggregate:
         self.segsum_mode = choose_segsum_impl(executor.config, domain_est)
         self.topk = self._plan_topk(topk, build_tables)
         self.compact_cap = self._plan_compaction(probe_table)
+        #: SUM / AVG aggregates summed in code space (`aggregate.sum.
+        #: codespace`): the outer ones over the probe's rows (the compact
+        #: branch reduces fewer, so it engages wherever the whole one does),
+        #: each semi-join's over its build side's
+        self.sum_codespace = count_codespace_sums(
+            self.agg_exprs, self._ev.table, self.segsum_mode,
+            probe_table.padded_rows) + sum(
+                count_codespace_sums(semi["aggs"], self._build_evs[k].table,
+                                     semi["mode"],
+                                     build_tables[k].padded_rows)
+                for k, semi in self.semis.items())
         #: every build column the program is handed: gathered through a
         #: pointer, read by a build side's own conjuncts, or a group key the
         #: top-k tail orders by and returns
@@ -1216,6 +1228,8 @@ class CompiledJoinAggregate:
             launch_attrs["compact"] = cap
         if self.semis:
             launch_attrs["semi"] = len(self.semis)
+        if self.sum_codespace:
+            launch_attrs["sum_codespace"] = self.sum_codespace
         packed = timed_jit_call(
             "compiled_join_aggregate", self._fn, *args,
             may_compile=not self._warm, launch_attrs=launch_attrs)

@@ -73,6 +73,9 @@ DOCUMENTED_METRICS = frozenset({
     # (`ops.grouping.one_key_domain_limit`: admitted by the bytes of its
     # state)
     "aggregate.domain.wide",
+    # SUM / AVG aggregates over a DICT column of whole numbers that a built
+    # program sums as int32 in code space (`compiled.py::codespace_sum`)
+    "aggregate.sum.codespace",
     # inference/ — model lowering + fused PREDICT (docs/ml.md)
     "inference.model.registered",
     "inference.model.lowered",

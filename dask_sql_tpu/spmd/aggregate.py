@@ -64,6 +64,10 @@ class SpmdSegmentReducer(SegmentReducer):
     def __init__(self, gid, domain: int, n_rows: int, mode: str = "scatter"):
         super().__init__(gid, domain, mode, n_rows)
 
+    @property
+    def total_rows(self) -> int:
+        return self.n_rows * jax.lax.axis_size(AXIS)
+
     def finish(self):
         super().finish()
         if self._out is not None:

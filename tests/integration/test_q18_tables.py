@@ -31,7 +31,8 @@ from perfbench.surfaces.library import frame_answer
 ROWS = 50_000
 QUERY = traffic.load("queries", "tpch_q18")
 COUNTERS = ("join.build.semi", "join.build.whole", "join.build.eager",
-            "aggregate.domain.wide", "resilience.degraded")
+            "aggregate.domain.wide", "aggregate.sum.codespace",
+            "resilience.degraded")
 
 
 @pytest.fixture(scope="module", autouse=True)
@@ -94,12 +95,16 @@ def test_all_four_quantities_share_one_executable(q18):
         frame, names = held_to_reference(c, arrays, quantity)
         compiles += sum(n.startswith("compile:") for n in names)
         hits += "family_hit" in names
+        # both SUM(l_quantity), the semi-join's and the outer one, stay in
+        # code space (the dictionary is the whole numbers 1..50)
+        launch = [s for s in c.last_trace.spans if s.name == "launch"][-1]
+        assert launch.attrs["sum_codespace"] == 2
     assert compiles == 1 and hits == 5
     assert len(frame) > 0  # 220: orders pass at this size
     moved = {k: c.metrics.counter(k) - v for k, v in before.items()}
     assert moved == {"join.build.semi": 1, "join.build.whole": 2,
                      "join.build.eager": 0, "aggregate.domain.wide": 0,
-                     "resilience.degraded": 0}
+                     "aggregate.sum.codespace": 2, "resilience.degraded": 0}
     (program,) = cj.PROGRAMS.values()
     # CUSTOMER and the semi-join are both probed at ORDERS' rows; CUSTOMER's
     # group keys are read through ORDERS' pointer
@@ -184,18 +189,51 @@ def same_rows(got, want, by):
 Q18_NO_LIMIT = QUERY["sql"].split(" ORDER BY")[0]
 
 
+def with_quantity(arrays, case):
+    """The module's tables with ONE row of `l_quantity` changed: NULL, or
+    the only value of its dictionary that is no whole number."""
+    import pyarrow as pa
+
+    frames = tpch_q18_tables.arrow_tables(arrays)
+    lineitem = frames["lineitem"]
+    quantity = lineitem.column("l_quantity").to_numpy().copy()
+    at = ROWS // 3
+    mask = np.zeros(ROWS, dtype=bool)
+    if case == "quantity_null":
+        mask[at] = True
+    else:
+        quantity[at] += 0.5
+    frames["lineitem"] = lineitem.set_column(
+        lineitem.schema.get_field_index("l_quantity"), "l_quantity",
+        pa.array(quantity, mask=mask))
+    c = Context()
+    for name in ("customer", "orders", "lineitem"):
+        c.create_table(name, frames[name])
+    column = c.schema[c.schema_name].tables["lineitem"].table.columns[
+        "l_quantity"]
+    assert column.encoding.value == "DICT"
+    assert (column.validity is not None) == (case == "quantity_null")
+    return c
+
+
 @pytest.mark.parametrize("case", ["no_limit", "compacting", "overflowing",
-                                  "subquery_filter", "count_in_having"])
+                                  "subquery_filter", "count_in_having",
+                                  "quantity_null", "quantity_not_whole"])
 def test_semi_join_program_equals_the_interpreted_converters(q18, case,
                                                              monkeypatch):
     """The same rules outside Q18's own text: without ORDER BY / LIMIT
     (every passing group leaves, CUSTOMER's keys taken on the host through
     the pointer the pack carries); with the compaction of the passing rows
     engaged (the floor lowered) and overflowing; with a WHERE inside the
-    subquery; with two HAVING conjuncts, one on a COUNT."""
+    subquery; with two HAVING conjuncts, one on a COUNT; with one NULL in
+    `l_quantity` (both sums stay in code space, the column's validity in
+    their counts); with one quantity that is no whole number (both sums
+    decline to the float64 scatter, `aggregate.sum.codespace` stands)."""
     c, arrays = q18
     cj.PROGRAMS.clear()
     sql = Q18_NO_LIMIT.format(QUANTITY=230)
+    if case.startswith("quantity_"):
+        c = with_quantity(arrays, case)
     if case in ("compacting", "overflowing"):
         monkeypatch.setattr(cj, "_COMPACT_MIN_ROWS", 1 << 12)
         # 230: a few hundred probe rows pass; 40: most of them do
@@ -211,6 +249,11 @@ def test_semi_join_program_equals_the_interpreted_converters(q18, case,
     assert "rung:compiled_join_aggregate" in names
     (program,) = cj.PROGRAMS.values()
     assert list(program.semis) == [2] and program.dependents == [1]
+    engaged = 0 if case == "quantity_not_whole" else 2
+    assert program.sum_codespace == engaged
+    assert c.metrics.counter("aggregate.sum.codespace") >= engaged
+    if case == "quantity_not_whole":
+        assert c.metrics.counter("aggregate.sum.codespace") == 0
     if case in ("compacting", "overflowing"):
         tail = spans(c)["join:tail"].attrs
         assert program.compact_cap and tail["cap"] == program.compact_cap

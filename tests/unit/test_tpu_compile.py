@@ -515,16 +515,19 @@ def test_join_aggregate_q18_fits_one_chip_at_cell_size(one_chip):
           f"output {m.output_size_in_bytes}")
     assert seconds < 240, seconds
     assert _device_bytes(compiled) < HBM_BYTES
-    # the scatters: the semi-join's sum and two counts (its rows, and those
-    # whose quantity is no NaN) over all of LINEITEM into the key range,
-    # then the outer sum and its two counts, over the compact buffer in one
-    # branch and over the whole probe in the other
-    updates = re.findall(r'"stablehlo\.scatter"\(.*?\}\) : \(tensor<(\d+)xf?\w+>, '
+    # the scatters, every one int32 (PR 36: `l_quantity`'s dictionary is the
+    # whole numbers 1..50, so both SUMs stay in code space and, with no NULL
+    # and no NaN test, their counts ARE the rows' count): the semi-join's
+    # sum and ONE count over all of LINEITEM into the key range, then the
+    # outer sum and its count, over the compact buffer in one branch and
+    # over the whole probe in the other.  No scatter has a 64-bit operand
+    updates = re.findall(r'"stablehlo\.scatter"\(.*?\}\) : \(tensor<(\d+)x(\w+)>, '
                          r"tensor<(\d+)x1xi32>", lowered.as_text(), re.S)
-    assert sorted((int(d), int(n)) for d, n in updates) == sorted(
-        [(Q18_KEY_RANGE, Q3_ROWS["lineitem"])] * 3
-        + [(Q3_ROWS["orders"], cap)] * 3
-        + [(Q3_ROWS["orders"], Q3_ROWS["lineitem"])] * 3), updates
+    assert sorted((int(d), dtype, int(n)) for d, dtype, n in updates) == sorted(
+        [(Q18_KEY_RANGE, "i32", Q3_ROWS["lineitem"])] * 2
+        + [(Q3_ROWS["orders"], "i32", cap)] * 2
+        + [(Q3_ROWS["orders"], "i32", Q3_ROWS["lineitem"])] * 2), updates
+    assert pipeline.sum_codespace == 2
     # every sort is 32-bit: the TPU's lowering of scatter-add and the
     # compaction's one; the top-100 tail brings none
     text = compiled.as_text()
