@@ -442,6 +442,10 @@ class Context:
         #: serving metrics registry: query/cache/executor counters and
         #: latency histograms (SHOW METRICS, server /v1/metrics)
         self.metrics = MetricsRegistry()
+        # every compile's histograms (observability/xla.py), empty until the
+        # first one: a process whose executables all came from the cache
+        # reads 0, not nothing
+        observability.xla.declare(self.metrics)
         # arm the process-wide lock sanitizer when this context's config
         # asks for it (arming is one-way: a later default-config Context
         # must not disarm a suite that opted in), and point its

@@ -24,10 +24,14 @@ every stage visible (docs/observability.md):
                 as ``serving.ledger.*`` gauges;
 - `flight`    — the always-on bounded flight recorder of structured
                 engine events (``GET /v1/debug/events``), with a
-                registered event vocabulary (self-lint DSQL501).
+                registered event vocabulary (self-lint DSQL501);
+- `xla`       — the ONE JAX monitoring listener: every XLA compile and
+                persistent-cache load as ``xla:lower`` / ``xla:compile``
+                spans and ``xla.*_ms`` histograms (registered at import).
 """
 from . import flight
 from . import live
+from . import xla
 from .ledger import DeviceLedger
 from .live import LiveQuery, QueryRegistry
 from .profiles import ProfileStore
@@ -75,4 +79,5 @@ __all__ = [
     "stage",
     "timed_jit_call",
     "trace_event",
+    "xla",
 ]

@@ -35,7 +35,9 @@ this module is that instrumentation for the whole engine:
   compile (detected via the jit cache-size delta).  The recorded wall time
   is the first-call time — trace + lower + XLA compile + first dispatch —
   which is the cost a cold fingerprint actually pays; warm calls record
-  only their ``launch`` detail span.
+  only their ``launch`` detail span.  What the first call spends in JAX's
+  lowering, in XLA and in persistent-cache loads is observability/xla.py's:
+  its ``xla:lower`` / ``xla:compile`` spans nest under ``compile:<rung>``.
 
 Span clocks: `time.perf_counter()` (monotonic, process-wide comparable);
 each trace also carries an epoch anchor so exported timestamps are
@@ -166,6 +168,14 @@ class QueryTrace:
         with self._lock:
             for s in reversed(self.spans):
                 if s.kind == STAGE and s.t1 is None:
+                    return s.name
+        return None
+
+    def innermost_open(self) -> Optional[str]:
+        """Name of the newest span still open (a stage or a detail)."""
+        with self._lock:
+            for s in reversed(self.spans):
+                if s.t1 is None and s.kind != EVENT:
                     return s.name
         return None
 
@@ -437,13 +447,20 @@ class _LoadSink:
     sequential, disjoint and tile the call by construction."""
 
     def __init__(self, trace: Optional[QueryTrace], kind: str,
-                 parent: Optional[str]):
+                 parent: Optional[str], metrics=None):
         self.trace, self.kind, self.parent = trace, kind, parent
+        #: the loading context's registry (observability/xla.py observes a
+        #: compile inside the load here, tracing on or off)
+        self.metrics = metrics
         self.seconds = dict.fromkeys(LOAD_PHASES, 0.0)
         self.h2d_bytes = 0
         #: (phase, attrs, segments recorded so far) of the open phases
         self._stack: List[tuple] = []
         self._since = 0.0  # start of the running segment
+
+    def phase(self) -> Optional[str]:
+        """The innermost phase open right now, if any."""
+        return self._stack[-1][0] if self._stack else None
 
     def _close_segment(self, now: float) -> None:
         if not self._stack or now <= self._since:
@@ -495,7 +512,7 @@ def load_trace(context, schema_name: str, table_name: str):
                         metrics=context.metrics, profiles=context.profiles)
         context.traces.put(tr.qid, tr)
         kind, parent, owned = STAGE, None, True
-    sink = _LoadSink(tr, kind, parent)
+    sink = _LoadSink(tr, kind, parent, context.metrics)
     token = _load.set(sink)
     try:
         yield
@@ -556,6 +573,24 @@ def compile_sink(metrics, profiles=None, fingerprint: Optional[str] = None,
         _sink.reset(token)
 
 
+class _RungCall:
+    """The extent of one `timed_jit_call`: its rung, and the cache verdict
+    (``hit`` / ``miss`` / ``off``) of each backend compile the listener of
+    observability/xla.py saw inside it."""
+
+    __slots__ = ("rung", "caches")
+
+    def __init__(self, rung: str):
+        self.rung = rung
+        self.caches: List[str] = []
+
+
+#: set for a `timed_jit_call`'s extent; the compile watchdog's helper thread
+#: inherits it (resilience/watchdog.py copies the caller's context)
+_rung_call: "contextvars.ContextVar[Optional[_RungCall]]" = \
+    contextvars.ContextVar("dsql_rung_call", default=None)
+
+
 def _jit_cache_size(fn) -> Optional[int]:
     try:
         return fn._cache_size()
@@ -576,10 +611,12 @@ def timed_jit_call(rung: str, fn, *args, may_compile: Optional[bool] = None,
     histogram observation and a per-fingerprint ProfileStore entry (via the
     installed `compile_sink` — independent of tracing, so SHOW METRICS and
     the pre-warm input stay populated with tracing disabled), plus a
-    ``compile:<rung>`` detail span when a trace is active.  When the
-    persistent executable cache (serving/compile_cache.py) is enabled, the
-    span carries a ``persistent_hit`` flag and the compile is counted as
-    ``resilience.compile_cache.hit`` / ``.miss``.
+    ``compile:<rung>`` detail span under ``launch``, over the same interval,
+    when a trace is active.  The call's extent is a `_RungCall`: the
+    ``xla:lower`` / ``xla:compile`` spans of observability/xla.py carry its
+    ``rung`` and nest under ``compile:<rung>``, and the cache verdicts of
+    its compiles give the span's ``persistent_hit`` (True: loaded from the
+    persistent cache, False: XLA compiled, None: no cache directory).
 
     ``may_compile`` is the caller's hint about whether THIS call can
     trigger a fresh compile (False = the shape is known-warm).  When a
@@ -595,29 +632,30 @@ def timed_jit_call(rung: str, fn, *args, may_compile: Optional[bool] = None,
     if tr is not None and metrics is None:
         metrics = tr.metrics
     before = _jit_cache_size(fn)
-    pc_hits0 = None
-    from ..serving import compile_cache
-
-    if compile_cache.enabled_path() is not None:
-        pc_hits0 = compile_cache.hit_count()
+    call = _RungCall(rung)
+    token = _rung_call.set(call)
     t0 = time.perf_counter()
-    deadline_ms = None
-    if may_compile is not False:
-        from ..config import config as _config
-        from ..resilience import faults, watchdog
+    try:
+        deadline_ms = None
+        if may_compile is not False:
+            from ..config import config as _config
+            from ..resilience import faults, watchdog
 
-        deadline_ms = watchdog.timeout_ms(_config)
-    if deadline_ms is not None:
-        out = watchdog.watched_call(
-            rung, fn, args, kwargs, deadline_ms=deadline_ms,
-            hang_s=faults.hang_duration("compile_hang", _config),
-            metrics=metrics)
-    else:
-        out = fn(*args, **kwargs)
+            deadline_ms = watchdog.timeout_ms(_config)
+        if deadline_ms is not None:
+            out = watchdog.watched_call(
+                rung, fn, args, kwargs, deadline_ms=deadline_ms,
+                hang_s=faults.hang_duration("compile_hang", _config),
+                metrics=metrics)
+        else:
+            out = fn(*args, **kwargs)
+    finally:
+        _rung_call.reset(token)
+    t1 = time.perf_counter()
     if tr is not None:
         # every call, warm or cold: the host's side of the dispatch (a warm
         # call returns before the device is done; the wait shows in `fetch`)
-        tr.add_span("launch", t0, time.perf_counter(), kind=DETAIL,
+        tr.add_span("launch", t0, t1, kind=DETAIL,
                     parent=tr.open_stage() or "execute", rung=rung,
                     **(launch_attrs or {}))
         tr.last_rung = rung
@@ -626,19 +664,12 @@ def timed_jit_call(rung: str, fn, *args, may_compile: Optional[bool] = None,
     after = _jit_cache_size(fn)
     if after is None or after <= before:
         return out
-    t1 = time.perf_counter()
     ms = (t1 - t0) * 1000.0
-    persistent_hit = None
-    if pc_hits0 is not None:
-        # best-effort attribution: a concurrent query's compile can land in
-        # the same window, but a false positive only flips a trace flag
-        persistent_hit = compile_cache.hit_count() > pc_hits0
-        if metrics is not None:
-            metrics.inc("resilience.compile_cache.hit" if persistent_hit
-                        else "resilience.compile_cache.miss")
+    persistent_hit = (False if "miss" in call.caches
+                      else True if "hit" in call.caches else None)
     if tr is not None:
         fingerprint = tr.fingerprint or fingerprint
-        tr.add_span(f"compile:{rung}", t0, t1, kind=DETAIL, parent="execute",
+        tr.add_span(f"compile:{rung}", t0, t1, kind=DETAIL, parent="launch",
                     rung=rung, fingerprint=fingerprint,
                     persistent_hit=persistent_hit)
         profiles = profiles if profiles is not None else tr.profiles
@@ -648,20 +679,4 @@ def timed_jit_call(rung: str, fn, *args, may_compile: Optional[bool] = None,
     if profiles is not None and fingerprint:
         profiles.record_compile(fingerprint, rung, ms, sql=sql,
                                 family=family)
-    from . import flight
-
-    qid = tr.qid if tr is not None else None
-    if qid is None:
-        from ..serving.runtime import current_ticket
-
-        ticket = current_ticket()
-        qid = ticket.qid if ticket is not None else None
-    # start/end pair stamped retrospectively — a compile is only known to
-    # have happened once the jit cache grew, but the recorder accepts
-    # explicit timestamps so the timeline still shows the true window
-    wall_end = time.time()
-    flight.record("compile.start", qid=qid, ts=wall_end - ms / 1e3,
-                  rung=rung)
-    flight.record("compile.end", qid=qid, ts=wall_end, rung=rung,
-                  ms=round(ms, 3), persistent_hit=persistent_hit)
     return out

@@ -14,11 +14,12 @@ persistent compilation cache under ``serving.compile_cache.path``:
 - a half-written entry (crash mid-write) is a cache MISS, never an error:
   ``jax_raise_persistent_cache_errors`` stays False, so corruption degrades
   to a recompile (tests/unit/test_coldstart.py proves it);
-- hit/miss attribution reaches the engine's own metrics: a jax monitoring
-  listener feeds process-global counters, and `timed_jit_call`
-  (observability/spans.py) snapshots them around each recorded compile to
-  emit ``resilience.compile_cache.hit`` / ``.miss`` and stamp the
-  ``persistent_hit`` attribute on the trace's ``compile:<rung>`` span.
+- hit/miss attribution reaches the engine's own metrics: the process's
+  one jax monitoring listener (observability/xla.py) gives every compile
+  its cache verdict on the compiling thread, counted as
+  ``resilience.compile_cache.hit`` / ``.miss``, stamped on the
+  ``xla:compile`` span and, for a rung's compile, as ``persistent_hit`` on
+  its ``compile:<rung>`` span; `stats` reads its process totals.
 
 The JAX cache directory is process-global state: one path per process.
 `enable` is idempotent for the same path and logs (rather than flips) on a
@@ -46,23 +47,9 @@ ENV_DIR = "JAX_COMPILATION_CACHE_DIR"
 CONFIG_PATH_KEY = "serving.compile_cache.path"
 CONFIG_MIN_COMPILE_KEY = "serving.compile_cache.min_compile_time_s"
 
-_HIT_EVENT = "/jax/compilation_cache/cache_hits"
-_MISS_EVENT = "/jax/compilation_cache/cache_misses"
-
 _lock = threading.Lock()
 _state: Dict[str, Any] = {"path": None, "adopted": False,
-                          "listener_registered": False,
                           "ignored_logged": False}
-_counters = {"hits": 0, "misses": 0}
-
-
-def _listener(event: str, **kwargs) -> None:
-    if event == _HIT_EVENT:
-        with _lock:
-            _counters["hits"] += 1
-    elif event == _MISS_EVENT:
-        with _lock:
-            _counters["misses"] += 1
 
 
 def env_path() -> Optional[str]:
@@ -130,16 +117,6 @@ def enable(path: Optional[str], min_compile_time_s: float = 0.0) -> bool:
             logger.warning("could not enable the persistent compile cache",
                            exc_info=True)
             return False
-        if not _state["listener_registered"]:
-            try:
-                from jax._src import monitoring
-
-                monitoring.register_event_listener(_listener)
-                _state["listener_registered"] = True
-            except Exception:  # dsql: allow-broad-except — hit/miss
-                # attribution is best-effort; the cache itself still works
-                logger.debug("jax monitoring listener unavailable",
-                             exc_info=True)
         _state["path"] = path
         _state["adopted"] = adopted is not None
         logger.info("persistent compile cache %s at %s",
@@ -204,14 +181,12 @@ def enabled_path() -> Optional[str]:
         return _state["path"]
 
 
-def hit_count() -> int:
-    """Cumulative persistent-cache hits this process (monitoring events).
-    `timed_jit_call` snapshots this around a compile to attribute the hit
-    to a specific rung/span — best-effort under concurrent compiles."""
-    with _lock:
-        return _counters["hits"]
+def stats() -> Dict[str, float]:
+    """The process's compiles so far (observability/xla.py `totals`):
+    ``hits`` and ``misses`` of the persistent cache, ``compiles`` (every
+    executable built by XLA or loaded from the cache), and the seconds of
+    ``compile_s`` (XLA), ``lower_s`` (trace + lowering) and
+    ``cache_load_s`` (persistent-cache loads)."""
+    from ..observability import xla
 
-
-def stats() -> Dict[str, int]:
-    with _lock:
-        return dict(_counters)
+    return xla.totals()

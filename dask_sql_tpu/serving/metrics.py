@@ -115,6 +115,12 @@ DOCUMENTED_METRICS = frozenset({
     "load.register_ms",
     "load.rows",
     "load.h2d_bytes",
+    # observability/xla.py — every compile's trace + lowering, XLA's own
+    # seconds (persistent cache missed or off) and persistent-cache loads
+    # (histograms, ms; every Context creates them empty)
+    "xla.lower_ms",
+    "xla.compile_ms",
+    "xla.cache_load_ms",
     # observability/ — lifecycle tracing + slow-query log + flight recorder
     "observability.slow_query",
     "observability.flight.dumps",
@@ -379,6 +385,12 @@ class MetricsRegistry:
     def gauge(self, name: str, value: float) -> None:
         with self._lock:
             self._gauges[name] = value
+
+    def declare(self, name: str) -> None:
+        """Create histogram `name` empty, so a snapshot reports it at
+        count 0 before (or without) its first observation."""
+        with self._lock:
+            self._hists.setdefault(name, Histogram())
 
     def observe(self, name: str, value: float) -> None:
         with self._lock:

@@ -86,7 +86,12 @@ def test_compile_span_and_metric_recorded():
     tr = c.last_trace
     compiles = [s for s in tr.spans if s.name == "compile:compiled_select"]
     assert compiles, [s.name for s in tr.spans]
-    assert all(s.parent == "execute" for s in compiles)
+    # launch > compile:<rung> > xla:*, all under execute
+    assert all(s.parent == "launch" for s in compiles)
+    launches = [s for s in tr.spans if s.name == "launch"]
+    assert launches and all(s.parent == "execute" for s in launches)
+    assert any(s.parent == "compile:compiled_select"
+               for s in tr.spans if s.name == "xla:compile")
     snap = c.metrics.snapshot()
     assert "resilience.compile_ms.compiled_select" in snap["histograms"]
     # the profile store saw the compile under this plan's fingerprint
