@@ -18,7 +18,13 @@ fuses into a single XLA program over the probe table:
                    table's rows, read through the pointer.  So neither the
                    program's shapes nor its identity depend on the
                    literals: one executable per plan family and table
-                   version.  A LEFTSEMI join whose build side is an
+                   version.  The program reads such a side, its LUT and
+                   a semi-join's key range at lengths rounded up to
+                   `bucket_rows` (rows past the true count masked, the
+                   bounds runtime operands), so a table version whose
+                   sizes fall in the same buckets lowers to the same
+                   program and finds its executables in the persistent
+                   compile cache.  A LEFTSEMI join whose build side is an
                    Aggregate grouped by the join key (``x IN (SELECT k ..
                    GROUP BY k HAVING ..)``: unique on the key by
                    construction) is an INNER join that exposes no column,
@@ -75,7 +81,7 @@ import numpy as np
 from ..columnar.column import Column
 from ..columnar.dtypes import STRING_TYPES, SqlType, sql_to_np
 from ..columnar.table import Table
-from ..ops.join import dense_unique_lut
+from ..ops.join import bucket_rows, by_parts, dense_unique_lut, pad_rows
 from ..planner import plan as p
 from ..planner.expressions import (
     AggExpr,
@@ -397,10 +403,12 @@ def select_topk(alive, keys, k: int):
 
 
 class LutCache:
-    """The LUTs of whole build sides, one per (table version, key column,
-    byte budget), built on first use and kept: bounded, least recently used
-    first out, so a replaced table's LUT cannot pin device memory for good.
-    A key the rule declines is remembered as None."""
+    """What the program reads of whole build sides, per table version: the
+    LUT of each (key column, byte budget), each column padded to its
+    bucket (`padded_column`), a semi-join key's range; built on first use
+    and kept: bounded, least recently used first out, so a replaced table's
+    buffers cannot pin device memory for good.  A key the rule declines is
+    remembered as None."""
 
     def __init__(self, cap: int):
         self.cap = cap
@@ -426,7 +434,7 @@ class LutCache:
             self._entries.clear()
 
 
-LUTS = LutCache(16)
+LUTS = LutCache(64)
 
 
 def _reached_from(ext, m: int) -> Optional[int]:
@@ -489,13 +497,39 @@ def _choose_gid_join(ext, group_exprs, dependents: bool = True
 
 
 def build_lut(executor, rkey: Expr, table: Table,
-              max_bytes: Optional[int] = None):
+              max_bytes: Optional[int] = None, uid=None):
     """``(rmin, lut)`` of `table`'s join key `rkey` (`dense_unique_lut`,
-    which says what `max_bytes` means), or None where the key declines."""
-    kc = executor.eval_expr(rkey, table).decode()
+    which says what `max_bytes` means), or None where the key declines.
+    With `uid`, the table version of a whole build side, a key column is
+    read padded to its bucket (`padded_column`), so the build compiles
+    once per bucket and not once per row count."""
+    if uid is not None and type(rkey) is ColumnRef:
+        kc = padded_column(uid, table, rkey.index)
+    else:
+        kc = executor.eval_expr(rkey, table)
+    kc = kc.decode()
     if kc.sql_type in STRING_TYPES:
         return None
-    return dense_unique_lut(kc.data, kc.validity, max_bytes=max_bytes)
+    return dense_unique_lut(kc.data, kc.validity, max_bytes=max_bytes,
+                            rows=table.num_rows)
+
+
+def padded_column(uid, table: Table, index: int) -> Column:
+    """Column `index` of whole build side `table` (table version `uid`) as
+    the program reads it: its buffers padded to `bucket_rows` of the
+    table's rows by `pad_rows`, kept per table version in `LUTS`.  The
+    rows past `table.num_rows` repeat the last one: every reader masks
+    them."""
+    name = table.column_names[index]
+
+    def pad():
+        col = table.columns[name]  # one value a row: never RLE here
+        rows = bucket_rows(table.padded_rows)
+        return _rp(col, data=pad_rows(col.data, rows),
+                   validity=None if col.validity is None
+                   else pad_rows(col.validity, rows))
+
+    return LUTS.get_or_build((uid, "padded", name), pad)[0]
 
 
 class _SlotMeta:
@@ -516,12 +550,14 @@ class CompiledJoinAggregate:
                  topk: Optional[TopK] = None):
         """``whole[k]``: None where build table k was executed eagerly (its
         LUT is built here, from the rows it has left), else ``{"conjuncts":
-        the build side's own parameterised conjuncts, "lut": (rmin, lut)}``
-        with build table k the base table's scan, unfiltered.  A semi-join's
-        aggregate build side the program reduces itself adds ``"semi":
-        {"domain", "key", "aggs", "having", "schema"}`` (parameterised) and
-        has ``"lut": (the key range's low end, None)``: its LUT is made in
-        the program, over the ``domain`` values of that range."""
+        the build side's own parameterised conjuncts, "lut": (rmin, lut),
+        "uid": the table version}`` with build table k the base table's
+        scan, unfiltered: the program reads its columns padded to
+        `bucket_rows` (`padded_column`).  A semi-join's aggregate build side
+        the program reduces itself has ``"semi": {"domain", "key", "aggs",
+        "having", "schema"}`` (parameterised) in place of "uid", and
+        ``"lut": (the key range's low end, None)``: its LUT is made in the
+        program, over the ``domain`` values (bucketed) of that range."""
         self.rel = rel
         self.ext = ext
         self.probe_table = probe_table
@@ -568,6 +604,29 @@ class CompiledJoinAggregate:
             if prep is None:
                 raise _Unsupported("build keys not unique-dense ints")
             self.luts.append(prep)
+        #: per build side: its table version where the program reads its
+        #: columns padded (`padded_column`), else None
+        self.side_uids = [None if w is None else w.get("uid") for w in whole]
+        #: per build side: the rows of its buffers as the program reads them
+        self.build_rows = [
+            bucket_rows(bt.padded_rows) if uid is not None else bt.padded_rows
+            for bt, uid in zip(build_tables, self.side_uids)]
+        #: per join, the program's runtime bounds ``[lo, hi, rows]``: the
+        #: lowest and highest key its LUT's (or semi-join state's) slots
+        #: hold, and the build side's true row count; operands, so the
+        #: lowered program carries none of them.  int32 where they fit (a
+        #: 64-bit integer is two words on the TPU, and the program compares
+        #: with them at a build side's rows)
+        bounds = np.array(
+            [[lo, min(lo + (lut.shape[0] if lut is not None else
+                            w["semi"]["domain"]) - 1, np.iinfo(np.int64).max),
+              bt.num_rows]
+             for (lo, lut), bt, w in zip(self.luts, build_tables, whole)],
+            dtype=np.int64)
+        narrow = np.iinfo(np.int32)
+        self.bounds = bounds.astype(np.int32) if bounds.size and \
+            narrow.min <= bounds.min() and bounds.max() <= narrow.max \
+            else bounds
         #: per join: the conjuncts evaluated over the WHOLE build table in
         #: the program (None: the build side came filtered)
         self.build_conjuncts: List[Optional[List[Expr]]] = [
@@ -668,7 +727,7 @@ class CompiledJoinAggregate:
             for s in self.radix_spec:
                 domain_est *= s["r"]
         elif self.gid_join is not None and self.gid_join >= 0:
-            domain_est = build_tables[self.gid_join].num_rows
+            domain_est = self.build_rows[self.gid_join]
         else:
             domain_est = 1
         from ..ops.pallas_kernels import choose_segsum_impl
@@ -901,7 +960,12 @@ class CompiledJoinAggregate:
         gid_join = -1 if self.gid_join is None else self.gid_join
         radix_spec = self.radix_spec
         n_joins = len(self.ext.joins)
+        #: the host's lowest keys, read only to pick the arithmetic of a
+        #: probe key's dtype (whether it holds the lowest key: the same for
+        #: every version of a table); the program's values are `bounds`
         rmins = [rmin for rmin, _ in self.luts]
+        padded = [uid is not None for uid in self.side_uids]
+        build_domains = list(self.build_rows)
         build_conjuncts = self.build_conjuncts
         build_evs = self._build_evs
         folded = self.folded
@@ -918,8 +982,9 @@ class CompiledJoinAggregate:
             for sub in walk(e) if type(sub) is ColumnRef})
 
         def fn(probe_datas, probe_valids, luts, build_cols, row_valid,
-               params=()):
+               params=(), bounds=None):
             # build_cols: {(k,col): (data, valid_or_None)} full build tables
+            # (whole ones padded); bounds: `self.bounds`
             n_rows = probe_datas[0].shape[0] if probe_datas else 0
             slots: Dict[int, Tuple] = {
                 i: (probe_datas[i], probe_valids[i]) for i in range(n_probe)}
@@ -935,28 +1000,24 @@ class CompiledJoinAggregate:
                 # overflow under `key - rmin`); if rmin itself doesn't fit
                 # the key dtype, compute in int64 (no match is representable
                 # without it).  LUT positions/row-ids always fit int32.
-                rmin = rmins[k]
+                lo, hi = bounds[k, 0], bounds[k, 1]
                 if np.dtype(kd.dtype).itemsize < 4:
                     kd = kd.astype(jnp.int32)
-                if rmin:
-                    info = jnp.iinfo(kd.dtype)
-                    if info.min <= rmin <= info.max:
-                        # in-dtype subtraction can wrap for probe keys far
-                        # outside the build range (e.g. kd < INT_MIN + rmin)
-                        # and land back inside [0, size) — bound the KEY
-                        # itself first; within [rmin, rmin+size-1] the
-                        # subtraction is exact (ADVICE r3)
-                        lo_k = jnp.asarray(rmin, dtype=kd.dtype)
-                        hi_k = jnp.asarray(min(rmin + size - 1, int(info.max)),
-                                           dtype=kd.dtype)
-                        inb = (kd >= lo_k) & (kd <= hi_k)
-                        idx = jnp.where(inb, kd - lo_k,
-                                        jnp.zeros_like(kd))
-                    else:
-                        idx = kd.astype(jnp.int64) - rmin
-                        inb = (idx >= 0) & (idx < size)
+                info = jnp.iinfo(kd.dtype)
+                if info.min <= rmins[k] <= info.max:
+                    # in-dtype subtraction can wrap for probe keys far
+                    # outside the build range (e.g. kd < INT_MIN + rmin)
+                    # and land back inside [0, size) — bound the KEY itself
+                    # first; within [rmin, rmin+size-1] the subtraction is
+                    # exact (ADVICE r3)
+                    lo_k = lo.astype(kd.dtype)
+                    if jnp.iinfo(hi.dtype).max > info.max:
+                        hi = jnp.minimum(hi, int(info.max))
+                    hi_k = hi.astype(kd.dtype)
+                    inb = (kd >= lo_k) & (kd <= hi_k)
+                    idx = jnp.where(inb, kd - lo_k, jnp.zeros_like(kd))
                 else:
-                    idx = kd
+                    idx = kd.astype(jnp.int64) - lo
                     inb = (idx >= 0) & (idx < size)
                 idx32 = jnp.clip(idx, 0, size - 1).astype(jnp.int32)
                 ri = jnp.where(inb, lut[idx32].astype(jnp.int32), jnp.int32(-1))
@@ -986,8 +1047,8 @@ class CompiledJoinAggregate:
                 if np.dtype(kd.dtype).itemsize < 4:
                     kd = kd.astype(jnp.int32)
                 # every row's key lies in the kept range of its table version
-                gid = jnp.clip(kd - jnp.asarray(rmins[k], dtype=kd.dtype), 0,
-                               domain - 1).astype(jnp.int32)
+                lo = bounds[k, 0].astype(kd.dtype)
+                gid = jnp.clip(kd - lo, 0, domain - 1).astype(jnp.int32)
                 from .compiled import SegmentReducer
 
                 reducer = SegmentReducer(gid, domain, semi["mode"],
@@ -996,8 +1057,10 @@ class CompiledJoinAggregate:
                 outs = segment_agg_outputs(bev, bslots, semi["aggs"], sel,
                                            gid, domain, reducer)
                 keep = present = reducer.get(hit_h) > 0
+                # a slot past the range's top holds no row: not `present`
                 hslots = {0: (jnp.arange(domain, dtype=semi["key_dtype"])
-                              + rmins[k], None), PARAMS_SLOT: params}
+                              + bounds[k, 0].astype(semi["key_dtype"]), None),
+                          PARAMS_SLOT: params}
                 hslots.update({1 + i: out for i, out in enumerate(outs)})
                 for f in semi["having"]:
                     d, v = semi["having_ev"].eval(f, hslots)
@@ -1035,17 +1098,24 @@ class CompiledJoinAggregate:
                         keep = hit if keep is None else (keep & hit)
                 if keep is None:
                     return lut
-                return jnp.where(keep[jnp.clip(lut, 0, None)], lut, -1)
+                return by_parts(
+                    lambda part: jnp.where(keep[jnp.clip(part, 0, None)],
+                                           part, -1), lut)
 
             reached: Dict[int, jnp.ndarray] = {}
 
             def rows_of(m, k):
                 """Build side `m`'s row (-1: none) per ROW of build side
-                `k`, whose columns alone its key reads."""
+                `k`, whose columns alone its key reads; none for the pad
+                rows of a padded `k`."""
                 if m not in reached:
                     lkey = lkeys[m] if m in folded else dep_lkeys[m]
                     kd, kv = build_evs[k].eval(lkey, build_slots(k))
-                    reached[m] = pointer(m, kd, kv, kept_lut(m))
+                    ri = pointer(m, kd, kv, kept_lut(m))
+                    if padded[k]:
+                        ri = jnp.where(jnp.arange(ri.shape[0], dtype=bounds.dtype)
+                                       < bounds[k, 2], ri, -1)
+                    reached[m] = ri
                 return reached[m]
 
             def group_column(bcol):
@@ -1184,8 +1254,6 @@ class CompiledJoinAggregate:
             return (out, jnp.stack(counts)) if counts and topk is None \
                 else out
 
-        # domains are python ints (build table row counts) — bind them now
-        build_domains = [bt.num_rows for bt in self.build_tables]
         return fn
 
     def _make_reducer(self, gid, domain: int, n_rows: int):
@@ -1206,10 +1274,11 @@ class CompiledJoinAggregate:
         build_cols = {}
         for (k, col) in self.build_col_keys:
             bt = self.build_tables[k]
-            c = bt.columns[bt.column_names[col]]
+            c = bt.columns[bt.column_names[col]] if self.side_uids[k] is None \
+                else padded_column(self.side_uids[k], bt, col)
             build_cols[(k, col)] = (c.data, c.validity)
         return (probe_datas, probe_valids, luts, build_cols, pt.row_valid,
-                tuple(params))
+                tuple(params), self.bounds)
 
     def run(self, params: Tuple = ()) -> Table:
         args = self._run_args(params)
@@ -1223,7 +1292,8 @@ class CompiledJoinAggregate:
 
         cap = self.compact_cap
         launch_attrs = {"joins": len(self.luts), "domain": self.domain,
-                        "segsum": self.segsum_mode}
+                        "segsum": self.segsum_mode,
+                        "build_rows": list(self.build_rows)}
         if cap:
             launch_attrs["compact"] = cap
         if self.semis:
@@ -1303,14 +1373,18 @@ class CompiledJoinAggregate:
             keys[bcol] = decode_radix_group_key(_ColMeta(column(bcol)), d, 0,
                                                 validity)
         for bcol in set(self.group_cols) - set(keys):
-            # an RLE key has no row to gather in the program: k rows of it
-            # (a static shape), cut to the found ones on the host
-            c = column(bcol).take(jnp.asarray(at))
+            # an RLE key has no row to gather in the program: the runs of
+            # the found rows, looked up on the host (a decode on the device
+            # would compile at the table's row count)
+            c = column(bcol)
             with d2h_fetch():
-                d, v = jax.device_get((c.data, c.validity))
+                d, lengths, v = jax.device_get(
+                    (c.data, c.enc_lengths, c.validity))
+            run = np.searchsorted(np.cumsum(lengths, dtype=np.int64), at[:n],
+                                  side="right")
             keys[bcol] = Column(
-                np.asarray(d)[:n], c.sql_type,
-                None if v is None else np.asarray(v)[:n], c.dictionary)
+                np.asarray(d)[run], c.sql_type,
+                None if v is None else np.asarray(v)[run], c.dictionary)
         group_out = {name: keys[bcol]
                      for name, bcol in zip(names, self.group_cols)}
         return Table({**group_out, **out}, n), groups, counts
@@ -1420,7 +1494,8 @@ def _whole_lut(executor, join: dict, bdc, table: Table):
                  _LUT_MAX_BYTES)
     return LUTS.get_or_build(
         (bdc.uid, str(join["rkey"]), budget),
-        lambda: build_lut(executor, join["rkey"], table, max_bytes=budget))
+        lambda: build_lut(executor, join["rkey"], table, max_bytes=budget,
+                          uid=bdc.uid))
 
 
 def _key_range(executor, key: Expr, table: Table) -> Optional[Tuple[int, int]]:
@@ -1473,6 +1548,9 @@ def _semi_build(executor, ctx, join: dict, pz, attrs: dict):
     attrs.update(domain=domain, rows=table.num_rows, reused=not built_here)
     if domain > one_key_domain_limit(len(semi["aggs"]) + 1, executor.config):
         return None
+    # the program's state covers the range's bucket: a value past its top
+    # holds no row, so its group is never present and no HAVING keeps it
+    domain = bucket_rows(domain)
 
     def dictionary_of(i):
         return table.columns[table.column_names[i]].dictionary
@@ -1574,7 +1652,7 @@ def try_compiled_join_aggregate(rel: p.Aggregate, executor) -> Optional[Table]:
         whole: List[Optional[dict]] = []
         semi_attrs: List[dict] = []
         with detail("join:build") as attrs:
-            built = lut_bytes = 0
+            built = lut_bytes = padded = 0
             for k, j in enumerate(ext.joins):
                 w = None
                 scan = j["plan"]
@@ -1605,12 +1683,14 @@ def try_compiled_join_aggregate(rel: p.Aggregate, executor) -> Optional[Table]:
                                             else "join.lut.reused")
                             built += int(built_here)
                             lut_bytes += int(lut[1].nbytes)
+                            padded += int(bucket_rows(bt.padded_rows)
+                                          > bt.padded_rows)
 
                             def dictionary_of(i, bt=bt):
                                 return bt.columns[
                                     bt.column_names[i]].dictionary
 
-                            w = {"lut": lut, "conjuncts": [
+                            w = {"lut": lut, "uid": bdc.uid, "conjuncts": [
                                 pz.rewrite(e, dictionary_of)
                                 for e in j["whole"]]}
                 if w is None:
@@ -1621,7 +1701,7 @@ def try_compiled_join_aggregate(rel: p.Aggregate, executor) -> Optional[Table]:
                 build_tables.append(bt)
                 whole.append(w)
             attrs.update(tables=len(build_tables), lut_bytes=lut_bytes,
-                         built=built)
+                         built=built, padded=padded)
         params = pz.params
         topk = executor.topk_hints.get(id(rel))
         family = (
@@ -1648,7 +1728,9 @@ def try_compiled_join_aggregate(rel: p.Aggregate, executor) -> Optional[Table]:
             topk,
         )
         bucket = (tuple(uids), probe_table.num_rows, probe_table.padded_rows,
-                  tuple(bt.num_rows for bt in build_tables))
+                  tuple(bt.num_rows if w is None or "semi" in w
+                        else bucket_rows(bt.padded_rows)
+                        for bt, w in zip(build_tables, whole)))
         # the constructor binds the tables this first run reads; the finally
         # below drops them.  No `warm`: this rung never defers
         compiled, built_here = PROGRAMS.get_or_build(
@@ -1669,6 +1751,8 @@ def try_compiled_join_aggregate(rel: p.Aggregate, executor) -> Optional[Table]:
             semi = len(compiled.semis)
             if kept:
                 ctx.metrics.inc("join.build.whole", kept)
+            if padded:
+                ctx.metrics.inc("join.build.padded", padded)
             if semi:
                 ctx.metrics.inc("join.build.semi", semi)
             if kept + semi < len(whole):

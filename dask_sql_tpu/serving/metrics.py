@@ -58,6 +58,9 @@ DOCUMENTED_METRICS = frozenset({
     "join.lut.reused",
     "join.build.whole",
     "join.build.eager",
+    # ... of the whole ones, those read with pad rows past their true count
+    # (`ops/join.py::bucket_rows`), per built program
+    "join.build.padded",
     # the join rung's compaction of the passing rows: programs built with
     # it, requests they served, and of those the ones whose passing rows
     # outran the buffer (the same executable then reduces the probe whole)

@@ -96,7 +96,7 @@ class SpmdJoinAggregate(CompiledJoinAggregate):
         has_rv = self._has_row_valid
 
         def packed_fn(pdatas, pvalids_p, luts, bdatas, bvalids_p, rv_t,
-                      params):
+                      params, bounds):
             pvalids = []
             i = 0
             for present in pvp:
@@ -110,7 +110,7 @@ class SpmdJoinAggregate(CompiledJoinAggregate):
                 build_cols[key] = (bd, bv)
             rv = rv_t[0] if rv_t else None
             return raw(tuple(pdatas), tuple(pvalids), tuple(luts),
-                       build_cols, rv, tuple(params))
+                       build_cols, rv, tuple(params), bounds)
 
         in_specs = (
             (P(AXIS),) * len(pvp),
@@ -120,6 +120,7 @@ class SpmdJoinAggregate(CompiledJoinAggregate):
             (P(),) * sum(bvp),
             (P(AXIS),) * (1 if has_rv else 0),
             (P(),) * n_params,
+            P(),
         )
         mapped = shard_map(packed_fn, mesh=self.mesh, in_specs=in_specs,
                            out_specs=P(None, None), check_vma=False)
@@ -167,7 +168,7 @@ class SpmdJoinAggregate(CompiledJoinAggregate):
         fn = self._mapped_for(len(params))
         packed = timed_jit_call(
             "spmd_join_aggregate", fn, tuple(pdatas), pvalids_p, luts,
-            tuple(bdatas), tuple(bvalids_p), rv_t, params,
+            tuple(bdatas), tuple(bvalids_p), rv_t, params, self.bounds,
             may_compile=not self._warm,
             launch_attrs=launch_attrs(self.mesh, pt.padded_rows))
         self._warm = True

@@ -22,7 +22,7 @@ import pytest
 
 from dask_sql_tpu import Context
 from dask_sql_tpu import config as config_module
-from dask_sql_tpu.ops.join import dense_unique_lut
+from dask_sql_tpu.ops.join import bucket_rows, dense_unique_lut
 from dask_sql_tpu.physical import compiled_join as cj
 from perfbench import compare, traffic
 from perfbench.datagen import tpch_q3_tables
@@ -71,7 +71,7 @@ def test_all_155_parameter_sets_share_one_executable(q3):
     reference = tpch_q3_topk.Reference(arrays)
     before = {k: c.metrics.counter(k) for k in
               ("join.lut.built", "join.lut.reused", "join.build.whole",
-               "join.build.eager", "resilience.degraded",
+               "join.build.eager", "join.build.padded", "resilience.degraded",
                "columnar.encoding.valuespace_pred")}
     compiles, misses, requests = 0, None, 0
     for segment in SEGMENTS:
@@ -94,11 +94,11 @@ def test_all_155_parameter_sets_share_one_executable(q3):
     moved = {k: c.metrics.counter(k) - v for k, v in before.items()}
     assert moved == {"join.lut.built": 2, "join.lut.reused": 2 * 155 - 2,
                      "join.build.whole": 2, "join.build.eager": 0,
-                     "resilience.degraded": 0,
+                     "join.build.padded": 2, "resilience.degraded": 0,
                      "columnar.encoding.valuespace_pred": 0}
     spans = {s.name: s for s in c.last_trace.spans}
     assert spans["join:build"].attrs == {
-        "tables": 2, "built": 0,
+        "tables": 2, "built": 0, "padded": 2,
         "lut_bytes": spans["join:build"].attrs["lut_bytes"]}
     assert spans["join:build"].attrs["lut_bytes"] > 4 * ROWS
     assert spans["join:tail"].attrs["rows"] == 10
@@ -108,7 +108,11 @@ def test_all_155_parameter_sets_share_one_executable(q3):
     assert program.folded == {1: 0}
     launch = [s for s in c.last_trace.spans if s.name == "launch"][-1]
     assert launch.attrs["joins"] == 2 and launch.attrs["segsum"] == "scatter"
-    assert launch.attrs["domain"] == len(arrays["o_orderkey"])
+    # ORDERS' and CUSTOMER's rows as the program reads them: their buckets
+    orders = len(arrays["o_orderkey"])
+    assert launch.attrs["domain"] == bucket_rows(orders) > orders
+    assert launch.attrs["build_rows"] == [bucket_rows(orders),
+                                          bucket_rows(len(arrays["c_segment"]))]
 
 
 def test_segment_absent_from_the_dictionary_and_a_date_before_every_order(q3):
@@ -147,7 +151,9 @@ def test_dense_unique_lut_admits_by_bytes(case):
     keys = sparse_keys(4_000)                    # range 15,976: 63,904 bytes
     if case == "sparse_primary_key":
         rmin, lut = dense_unique_lut(jnp.asarray(keys), max_bytes=1 << 20)
-        assert rmin == 1 and lut.shape[0] == int(keys.max())
+        # the range's 15,976 slots in a table of its bucket, the rest empty
+        assert rmin == 1 and lut.shape[0] == bucket_rows(int(keys.max())) \
+            == 16_000
         assert np.array_equal(np.asarray(lut)[keys - 1], np.arange(4_000))
         assert int((np.asarray(lut) >= 0).sum()) == 4_000
     elif case == "duplicates":

@@ -312,10 +312,10 @@ def test_join_aggregate_q3_fits_one_chip_at_cell_size(one_chip):
     sort or gather that compiles for minutes shows here, not on the chip.
     Costs the suite 3 s of set-up and the compile (printed with -s)."""
     import time
-    from types import SimpleNamespace
 
     from dask_sql_tpu import Context
     from dask_sql_tpu import config as config_module
+    from dask_sql_tpu.ops.join import bucket_rows
     from dask_sql_tpu.physical import compiled_join
     from dask_sql_tpu.physical.compiled_join import CompiledJoinAggregate
     from perfbench import traffic
@@ -343,7 +343,8 @@ def test_join_aggregate_q3_fits_one_chip_at_cell_size(one_chip):
     (pipeline, probe_table, args), = seen
     assert pipeline.topk is not None and pipeline.segsum_mode == "scatter"
     assert all(conj is not None for conj in pipeline.build_conjuncts)
-    probe_datas, probe_valids, luts, build_cols, row_valid, params = args
+    probe_datas, probe_valids, luts, build_cols, row_valid, params, bounds \
+        = args
     assert row_valid is None and not any(v is not None for v in probe_valids)
 
     def shaped(rows, name, data):
@@ -352,20 +353,22 @@ def test_join_aggregate_q3_fits_one_chip_at_cell_size(one_chip):
 
     tables = [c.schema[c.schema_name].tables[n].table
               for n in ("orders", "customer")]
-    rows = [Q3_ROWS["orders"], Q3_ROWS["customer"]]
+    # the build sides as the program reads them: at their buckets
+    rows = [bucket_rows(Q3_ROWS["orders"]), bucket_rows(Q3_ROWS["customer"])]
     scans = [j["plan"] for j in pipeline.ext.joins]
     big_probe = tuple(shaped(Q3_ROWS["lineitem"], n, d) for n, d in
                       zip(probe_table.column_names, probe_datas))
-    big_luts = tuple(jax.ShapeDtypeStruct((n,), jnp.int32, sharding=one_chip)
+    big_luts = tuple(jax.ShapeDtypeStruct((bucket_rows(n),), jnp.int32,
+                                          sharding=one_chip)
                      for n in Q3_LUT_KEYS)
     big_build = {}
     for (k, col), (data, valid) in build_cols.items():
         assert valid is None
         name = (scans[k].projection or tables[k].column_names)[col]
         big_build[(k, col)] = (shaped(rows[k], name, data), None)
-    # the trace binds the build tables' row counts (the group domain)
+    # the trace binds the build sides' bucketed rows (the group domain)
     pipeline.probe_table = probe_table
-    pipeline.build_tables = [SimpleNamespace(num_rows=n) for n in rows]
+    pipeline.build_rows = rows
     pipeline.domain = rows[0]
     assert pipeline.compact_cap == compiled_join.compact_capacity(SMALL_ROWS)
     cap = pipeline.compact_cap = compiled_join.compact_capacity(
@@ -374,7 +377,7 @@ def test_join_aggregate_q3_fits_one_chip_at_cell_size(one_chip):
     try:
         lowered = jax.jit(pipeline._build()).lower(
             big_probe, probe_valids, big_luts, big_build, None,
-            _shapes(tuple(params), one_chip))
+            _shapes(tuple(params), one_chip), _shapes(bounds, one_chip))
     finally:
         pipeline.probe_table = pipeline.build_tables = None
     t0 = time.perf_counter()
@@ -440,10 +443,10 @@ def test_join_aggregate_q18_fits_one_chip_at_cell_size(one_chip):
     seconds, temporaries, and that no 64-bit sort is in the compiled text.
     Costs the suite 3 s of set-up and the compile (printed with -s)."""
     import time
-    from types import SimpleNamespace
 
     from dask_sql_tpu import Context
     from dask_sql_tpu import config as config_module
+    from dask_sql_tpu.ops.join import bucket_rows
     from dask_sql_tpu.physical import compiled_join
     from dask_sql_tpu.physical.compiled_join import CompiledJoinAggregate
     from perfbench import traffic
@@ -472,7 +475,8 @@ def test_join_aggregate_q18_fits_one_chip_at_cell_size(one_chip):
     assert pipeline.topk is not None and pipeline.topk["k"] == 100
     assert list(pipeline.semis) == [2] and pipeline.folded == {1: 0, 2: 0}
     assert pipeline.dependents == [1] and pipeline.gid_join == 0
-    probe_datas, probe_valids, luts, build_cols, row_valid, params = args
+    probe_datas, probe_valids, luts, build_cols, row_valid, params, bounds \
+        = args
     assert row_valid is None and not any(v is not None for v in probe_valids)
     assert luts[2] is None and len(params) == 1
 
@@ -482,28 +486,32 @@ def test_join_aggregate_q18_fits_one_chip_at_cell_size(one_chip):
 
     names = ("orders", "customer", "lineitem")
     tables = [c.schema[c.schema_name].tables[n].table for n in names]
-    rows = [Q3_ROWS[n] for n in names]
+    # ORDERS and CUSTOMER at their buckets; the semi-join's LINEITEM is
+    # read at its own rows, as the probe is
+    rows = [bucket_rows(Q3_ROWS["orders"]), bucket_rows(Q3_ROWS["customer"]),
+            Q3_ROWS["lineitem"]]
+    key_range = bucket_rows(Q18_KEY_RANGE)
     scans = [j["plan"] for j in pipeline.ext.joins[:2]] \
         + [pipeline.ext.joins[2]["semi"]["scan"]]
     big_probe = tuple(shaped(Q3_ROWS["lineitem"], n, d) for n, d in
                       zip(probe_table.column_names, probe_datas))
     big_luts = tuple(jax.ShapeDtypeStruct((n,), jnp.int32, sharding=one_chip)
-                     for n in (Q18_KEY_RANGE, Q3_ROWS["customer"])) + (None,)
+                     for n in (key_range, rows[1])) + (None,)
     big_build = {}
     for (k, col), (data, valid) in build_cols.items():
         assert valid is None
         name = (scans[k].projection or tables[k].column_names)[col]
         big_build[(k, col)] = (shaped(rows[k], name, data), None)
     pipeline.probe_table = probe_table
-    pipeline.build_tables = [SimpleNamespace(num_rows=n) for n in rows]
+    pipeline.build_rows = rows
     pipeline.domain = rows[0]
-    pipeline.semis[2]["domain"] = Q18_KEY_RANGE
+    pipeline.semis[2]["domain"] = key_range
     cap = pipeline.compact_cap = compiled_join.compact_capacity(
         Q3_ROWS["lineitem"])
     try:
         lowered = jax.jit(pipeline._build()).lower(
             big_probe, probe_valids, big_luts, big_build, None,
-            _shapes(tuple(params), one_chip))
+            _shapes(tuple(params), one_chip), _shapes(bounds, one_chip))
     finally:
         pipeline.probe_table = pipeline.build_tables = None
     t0 = time.perf_counter()
@@ -524,9 +532,9 @@ def test_join_aggregate_q18_fits_one_chip_at_cell_size(one_chip):
     updates = re.findall(r'"stablehlo\.scatter"\(.*?\}\) : \(tensor<(\d+)x(\w+)>, '
                          r"tensor<(\d+)x1xi32>", lowered.as_text(), re.S)
     assert sorted((int(d), dtype, int(n)) for d, dtype, n in updates) == sorted(
-        [(Q18_KEY_RANGE, "i32", Q3_ROWS["lineitem"])] * 2
-        + [(Q3_ROWS["orders"], "i32", cap)] * 2
-        + [(Q3_ROWS["orders"], "i32", Q3_ROWS["lineitem"])] * 2), updates
+        [(key_range, "i32", Q3_ROWS["lineitem"])] * 2
+        + [(rows[0], "i32", cap)] * 2
+        + [(rows[0], "i32", Q3_ROWS["lineitem"])] * 2), updates
     assert pipeline.sum_codespace == 2
     # every sort is 32-bit: the TPU's lowering of scatter-add and the
     # compaction's one; the top-100 tail brings none
