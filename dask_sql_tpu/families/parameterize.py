@@ -23,14 +23,26 @@ Eligibility is deliberately conservative — a literal stays baked whenever
 the compiled evaluators consume it at *trace* time:
 
 - string literals (dictionary lookup tables are built per value at
-  compile time), and NULL literals (validity shape is structural).  One
-  kind of string literal does parameterize, where the caller hands
+  compile time), and NULL literals (validity shape is structural).  Two
+  kinds of string predicate do parameterize, where the caller hands
   `rewrite` the columns' dictionaries (the compiled join pipeline, for
-  the conjuncts of a build side it keeps whole): ``col = 'v'`` /
-  ``col <> 'v'`` on a dictionary-coded string column becomes a comparison
-  of the codes with a runtime code, looked up in the dictionary at bind
-  time (-1, a code no row holds, where the dictionary lacks the value);
-- LIKE / ILIKE / SIMILAR patterns and escapes (host-compiled regexes);
+  the conjuncts of a build side it keeps whole), on a dictionary-coded
+  string column of any dictionary length:
+
+  * ``col = 'v'`` / ``col <> 'v'`` becomes a comparison of the codes with
+    a runtime code, looked up in the dictionary at bind time (-1, a code
+    no row holds, where the dictionary lacks the value), up to
+    `_CODE_LOOKUP_ENTRIES` entries;
+  * ``col [NOT] LIKE 'p' [ESCAPE 'e']`` and ILIKE, and ``=`` / ``<>`` on a
+    longer dictionary, become ``code_mask(col, ?i)``: at bind time the
+    pattern is evaluated over the whole dictionary at once (pyarrow
+    compute, `string_mask`) into one bool per entry, padded to the
+    dictionary's bucket (`ops/join.py::bucket_rows`) and handed to the
+    program as a runtime operand that it reads at the rows' codes.  So
+    every pattern shares one executable; the span ``join:like`` times
+    each evaluation and its transfer (`masks` lists them);
+- other LIKE / ILIKE and every SIMILAR pattern and escape (host-compiled
+  regexes);
 - DATE_TRUNC / CEIL unit arguments (static truncation unit);
 - plan-node integer fields (LIMIT windows, sort fetch, sample fraction,
   window frames) — these change static shapes or host-side slicing, so
@@ -46,8 +58,10 @@ aggregate arguments all parameterize.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import hashlib
 import logging
+import os
 from typing import Any, List, Optional, Tuple
 
 import numpy as np
@@ -115,6 +129,92 @@ def normalize_in_values(col_dtype: np.dtype,
     return np.sort(arr.astype(cmp, copy=False))
 
 
+#: id of a string dictionary -> the same strings as one pyarrow array,
+#: dropped with the dictionary (`_arrow_strings`)
+_ARROW_STRINGS: dict = {}
+
+
+def _arrow_strings(dictionary: np.ndarray):
+    """`dictionary` (an object array of str) as a pyarrow large_string
+    array, made once per dictionary: a mask of 2M entries is then one
+    vectorised pass, not a conversion plus a pass.  None where pyarrow
+    cannot take it."""
+    import weakref
+
+    import pyarrow as pa
+
+    key = id(dictionary)
+    got = _ARROW_STRINGS.get(key)
+    if got is None:
+        try:
+            got = pa.array(dictionary, type=pa.large_string())
+        except (pa.ArrowException, TypeError, ValueError):
+            return None
+        _ARROW_STRINGS[key] = got
+        weakref.finalize(dictionary, _ARROW_STRINGS.pop, key, None)
+    return got
+
+
+def _arrow_like(pattern: str, escape: Optional[str]) -> str:
+    """A LIKE pattern with escape character `escape` in pyarrow's LIKE
+    syntax, whose escape is a backslash (`ops/strings.py::like_to_regex`
+    reads the same pattern the same way)."""
+    out, i = [], 0
+    while i < len(pattern):
+        ch = pattern[i]
+        if escape and ch == escape and i + 1 < len(pattern):
+            nxt = pattern[i + 1]
+            out.append("\\" + nxt if nxt in "%_\\" else nxt)
+            i += 2
+            continue
+        out.append("\\\\" if ch == "\\" else ch)
+        i += 1
+    return "".join(out)
+
+
+#: entries one thread of `string_mask` takes: a dictionary up to this long is
+#: one pass, a longer one is split over `_mask_threads`
+_MASK_PART = 1 << 18
+
+
+@functools.lru_cache(maxsize=1)
+def _mask_threads():
+    from concurrent.futures import ThreadPoolExecutor
+
+    return ThreadPoolExecutor(min(8, os.cpu_count() or 1),
+                              thread_name_prefix="string-mask")
+
+
+def string_mask(dictionary: np.ndarray, op: str, pattern: str,
+                escape: Optional[str] = None) -> Optional[np.ndarray]:
+    """One bool per entry of string `dictionary`: whether it satisfies
+    ``entry <op> pattern``, `op` one of like / ilike / eq / ne, vectorised
+    (pyarrow compute, which lets go of the interpreter lock, so a long
+    dictionary's parts run on several threads at once); a NULL entry
+    satisfies none.  None where pyarrow cannot take the dictionary."""
+    import pyarrow.compute as pc
+
+    strings = _arrow_strings(dictionary)
+    if strings is None:
+        return None
+
+    def part(at: int) -> np.ndarray:
+        some = strings.slice(at, _MASK_PART)
+        if op in ("like", "ilike"):
+            hit = pc.match_like(some, _arrow_like(pattern, escape),
+                                ignore_case=op == "ilike")
+        else:
+            hit = pc.equal(some, pattern)
+            if op == "ne":
+                hit = pc.invert(hit)
+        return hit.fill_null(False).to_numpy(zero_copy_only=False)
+
+    starts = range(0, len(strings), _MASK_PART)
+    if len(starts) <= 1:
+        return part(0)
+    return np.concatenate(list(_mask_threads().map(part, starts)))
+
+
 def pow2_bucket(n: int) -> int:
     return 1 << max(0, (int(n) - 1)).bit_length()
 
@@ -153,6 +253,8 @@ class Parameterizer:
         self.key_values: List[Any] = []
         #: column index -> string dictionary, for the span of one `rewrite`
         self._dictionary_of = None
+        #: one ``{"entries", "matched"}`` per `code_mask` bound so far
+        self.masks: List[dict] = []
 
     @property
     def params(self) -> Tuple[np.ndarray, ...]:
@@ -179,6 +281,11 @@ class Parameterizer:
             coded = self._string_code_compare(e)
             if coded is not None:
                 return coded
+        if self._dictionary_of is not None and isinstance(e, ScalarFunc) \
+                and e.op in ("eq", "ne", "like", "ilike"):
+            masked = self._string_mask(e)
+            if masked is not None:
+                return masked
         if isinstance(e, InListExpr):
             return self._rewrite_in_list(e)
         if isinstance(e, ScalarFunc) and e.op in _STATIC_TAIL_OPS and e.args:
@@ -238,6 +345,54 @@ class Parameterizer:
             self.key_values.append(code)
             return dataclasses.replace(
                 e, args=(col, ParamRef(index, SqlType.INTEGER)))
+        return None
+
+    def _string_mask(self, e: ScalarFunc) -> Optional[Expr]:
+        """``string column [I]LIKE 'pattern' [ESCAPE 'e']``, or ``(=|<>)
+        'literal'`` past `_CODE_LOOKUP_ENTRIES`, as ``code_mask(column,
+        ?i)``: the predicate's value for every dictionary entry, a runtime
+        BOOLEAN vector; None where the shape is another or the column's
+        dictionary is not at hand."""
+        from jax import device_put
+
+        from ..columnar.dtypes import STRING_TYPES
+        from ..observability import detail
+        from ..ops.join import bucket_rows
+        from ..planner.expressions import ColumnRef
+
+        args = e.args
+        if e.op in ("eq", "ne"):
+            pairs, escape = (args, args[::-1]), None
+        else:
+            pairs, escape = (args[:2],), (args[2] if len(args) > 2 else None)
+            if escape is not None and not (isinstance(escape, Literal)
+                                           and isinstance(escape.value, str)):
+                return None
+        for col, lit in pairs:
+            if not (type(col) is ColumnRef and col.sql_type in STRING_TYPES
+                    and isinstance(lit, Literal)
+                    and isinstance(lit.value, str)):
+                continue
+            dictionary = self._dictionary_of(col.index)
+            if dictionary is None:
+                return None
+            esc = None if escape is None else escape.value
+            with detail("join:like") as attrs:
+                hit = string_mask(dictionary, e.op, lit.value, esc)
+                if hit is None:
+                    return None
+                mask = np.zeros(bucket_rows(max(len(hit), 1)), dtype=bool)
+                mask[:len(hit)] = hit
+                matched = int(np.count_nonzero(hit))
+                attrs.update(entries=len(hit), matched=matched)
+                value = device_put(mask)
+            self.masks.append({"entries": len(hit), "matched": matched})
+            index = len(self.values)
+            self.values.append(value)
+            self.key_values.append((e.op, lit.value, esc))
+            return ScalarFunc("code_mask",
+                              (col, ParamRef(index, SqlType.BOOLEAN)),
+                              SqlType.BOOLEAN)
         return None
 
     def _rewrite_in_list(self, e: InListExpr) -> Expr:

@@ -267,6 +267,75 @@ def dense_unique_lut(key: jnp.ndarray, valid=None,
     return rmin, lut
 
 
+#: the most rows one leading key value may hold in `composite_slots`
+COMPOSITE_MAX_RUN = 16
+
+
+def composite_slots(keys, max_bytes: int) -> Optional[dict]:
+    """The slots of a build side joined on a TWO-column key whose pair is
+    unique though neither column is (TPC-H's PARTSUPP: four suppliers a
+    part), or None where the side declines.
+
+    `keys`: the side's two key columns on the host, ``[(int64 data, valid
+    or None)]``.  One column leads: each of its values owns ``run`` slots
+    in a row, ``run`` the most rows any value holds rounded up to a power
+    of two (4 for PARTSUPP by part key; whichever column gives the shorter
+    run leads).  A row takes the slot of its leading value and its rank
+    among that value's rows, so the probe finds its row among ``run``
+    candidates at ``(lead - lo) * run + i`` by comparing the other column,
+    with no sort and no shape that depends on the data.  Returns ``{"lead":
+    which column leads, "run", "lo", "hi": the leading column's range,
+    "lo2", "hi2": the other's, "second": int32[slots], the other column
+    less ``lo2`` per slot (-1: no row), "rows": int32[slots], the row in
+    each slot (row 0 where none: `second` masks it), "slots"}``, the slots
+    `bucket_rows` of the leading range times ``run``.  A NULL key leaves
+    its row out.  Declines: no row, a duplicate pair, a run past
+    `COMPOSITE_MAX_RUN`, slots past `max_bytes` at 4 bytes each, a second
+    range past int32."""
+    live = np.ones(len(keys[0][0]), dtype=bool)
+    for _, valid in keys:
+        if valid is not None:
+            live &= np.asarray(valid, dtype=bool)
+    rows = np.flatnonzero(live)
+    if not len(rows):
+        return None
+    cols = [np.asarray(data, dtype=np.int64)[rows] for data, _ in keys]
+    best = None
+    for lead in (0, 1):
+        a = cols[lead]
+        lo, hi = int(a.min()), int(a.max())
+        span = hi - lo + 1
+        if bucket_rows(span) * 4 > max_bytes:
+            continue
+        most = int(np.unique(a, return_counts=True)[1].max())
+        run = 1 << (most - 1).bit_length()
+        slots = bucket_rows(span) * run
+        if run <= COMPOSITE_MAX_RUN and slots * 4 <= max_bytes \
+                and (best is None or (run, slots) < best[:2]):
+            best = (run, slots, lead, lo, hi)
+    if best is None:
+        return None
+    run, slots, lead, lo, hi = best
+    a, b = cols[lead], cols[1 - lead]
+    order = np.lexsort((b, a))
+    a, b, rows = a[order], b[order], rows[order]
+    if np.any((a[1:] == a[:-1]) & (b[1:] == b[:-1])):
+        return None
+    lo2, hi2 = int(b.min()), int(b.max())
+    if hi2 - lo2 >= np.iinfo(np.int32).max:
+        return None
+    starts = np.flatnonzero(np.concatenate([[True], a[1:] != a[:-1]]))
+    rank = np.arange(len(a)) - np.repeat(starts,
+                                         np.diff(np.append(starts, len(a))))
+    at = (a - lo) * run + rank
+    second = np.full(slots, -1, dtype=np.int32)
+    second[at] = b - lo2
+    row_of = np.zeros(slots, dtype=np.int32)
+    row_of[at] = rows
+    return {"lead": lead, "run": run, "lo": lo, "hi": hi, "lo2": lo2,
+            "hi2": hi2, "second": second, "rows": row_of, "slots": slots}
+
+
 def inner_join_indices(lgid: jnp.ndarray, rgid: jnp.ndarray,
                        use_jit: bool = False) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """(left_idx, right_idx) pairs of matches, left-major order."""

@@ -965,6 +965,13 @@ class _TraceEval:
         op = expr.op
         args = expr.args
 
+        if op == "code_mask":
+            # a string predicate as a runtime bool per dictionary entry
+            # (families/parameterize.py::_string_mask), read at the codes
+            codes, valid = slots[args[0].index]
+            entries = slots[PARAMS_SLOT][args[1].index]
+            return (entries[jnp.clip(codes, 0, entries.shape[0] - 1)], valid)
+
         # string comparisons / LIKE against literals via dictionary LUTs
         if op in ("eq", "ne", "like", "ilike", "similar") and len(args) >= 2:
             src = self._string_source(args[0])

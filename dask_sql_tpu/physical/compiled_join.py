@@ -201,7 +201,8 @@ def _walk_left_spine(node, ext: _Extraction) -> Optional[List[Expr]]:
         if node.join_type not in ("INNER", "LEFTSEMI") \
                 or node.filter is not None:
             return None
-        if len(node.on) != 1 or len(ext.joins) >= _MAX_JOINS:
+        if len(node.on) not in ((1, 2) if node.join_type == "INNER" else (1,)) \
+                or len(ext.joins) >= _MAX_JOINS:
             return None
         lkey_raw, rkey_raw = node.on[0]
         rkey = shift_columns(rkey_raw, -len(node.left.schema))
@@ -217,6 +218,13 @@ def _walk_left_spine(node, ext: _Extraction) -> Optional[List[Expr]]:
         lkey = _rewrite(lkey_raw, left)
         ext.joins.append({"plan": node.right, "lkey": lkey, "rkey": rkey,
                           "exposes": grouped is None, "grouped": grouped})
+        if len(node.on) == 2:
+            # a two-column key (TPC-H's PARTSUPP): `_composite_side` keeps
+            # such a build side whole, or the rung declines
+            lkey2_raw, rkey2_raw = node.on[1]
+            ext.joins[-1]["pair"] = (
+                _rewrite(lkey2_raw, left),
+                shift_columns(rkey2_raw, -len(node.left.schema)))
         if grouped is not None:
             # the build side is unique on the key, so a left row matches at
             # most one row of it: an INNER join that exposes no column
@@ -330,6 +338,8 @@ def _plan_whole_builds(ext: _Extraction, group_exprs, agg_exprs):
         rebase[k] = [x.index for x in slots]
         j["whole"] = list(sub.conjuncts)
         j["rkey"] = _rewrite(j["rkey"], slots)
+        if "pair" in j:
+            j["pair"] = (j["pair"][0], _rewrite(j["pair"][1], slots))
         j["plan"] = _rp(sub.scan, filters=list(sub.conjuncts))
 
     def fn(x):
@@ -340,6 +350,8 @@ def _plan_whole_builds(ext: _Extraction, group_exprs, agg_exprs):
     ext.conjuncts = [transform(e, fn) for e in ext.conjuncts]
     for j in ext.joins:
         j["lkey"] = transform(j["lkey"], fn)
+        if "pair" in j:
+            j["pair"] = (transform(j["pair"][0], fn), j["pair"][1])
     group_exprs = [transform(e, fn) for e in group_exprs]
     agg_exprs = [
         _rp(a, args=tuple(transform(x, fn) for x in a.args),
@@ -441,13 +453,16 @@ def _reached_from(ext, m: int) -> Optional[int]:
     """The ONE build side whose columns alone join `m`'s probe key reads
     (ORDERS for ORDERS -> CUSTOMER under LINEITEM -> ORDERS), else None: a
     row of that build side then determines build `m`'s row, since `m`'s key
-    is unique."""
+    is unique.  A two-column key is never so reached, nor reaches: its
+    side's rows are laid out in its slots (`slotted_column`)."""
+    if "pair" in ext.joins[m]:
+        return None
     subs = list(walk(ext.joins[m]["lkey"]))
     parents = {sub.k for sub in subs if isinstance(sub, _BuildRef)}
     if len(parents) != 1 or any(type(sub) is ColumnRef for sub in subs):
         return None
     (j,) = parents
-    return j
+    return None if "pair" in ext.joins[j] else j
 
 
 def _column_of(build_tables, bcol: Tuple[int, int]) -> Column:
@@ -474,8 +489,10 @@ def _choose_gid_join(ext, group_exprs, dependents: bool = True
     for k in range(len(ext.joins) - 1, -1, -1):
         rkey = ext.joins[k]["rkey"]
         if not (isinstance(rkey, ColumnRef) and type(rkey) is ColumnRef) \
-                or not ext.joins[k]["exposes"]:
-            continue  # a semi-join's build side has no row to point at
+                or not ext.joins[k]["exposes"] or "pair" in ext.joins[k]:
+            # a semi-join's build side has no row to point at, a
+            # two-column key's no row a group key alone determines
+            continue
         cols = []
         has_key = False
         ok = True
@@ -532,6 +549,75 @@ def padded_column(uid, table: Table, index: int) -> Column:
     return LUTS.get_or_build((uid, "padded", name), pad)[0]
 
 
+def slotted_column(uid, table: Table, index: int, composite: dict) -> Column:
+    """Column `index` of whole build side `table` (table version `uid`)
+    joined on a two-column key, as the program reads it: one value per
+    slot of `composite` (`ops/join.py::composite_slots`), kept per table
+    version and key in `LUTS`.  A slot that holds no row reads row 0: the
+    slots' ``second`` masks it."""
+    name = table.column_names[index]
+
+    def lay_out():
+        from ..utils import d2h_fetch
+
+        col = table.columns[name]  # one value a row: never RLE here
+        with d2h_fetch(nbytes=int(col.data.nbytes)):
+            data, valid = jax.device_get((col.data, col.validity))
+        rows = composite["rows"]
+        return _rp(col, data=jax.device_put(np.asarray(data)[rows]),
+                   validity=None if valid is None
+                   else jax.device_put(np.asarray(valid)[rows]))
+
+    return LUTS.get_or_build((uid, "slotted", composite["key"], name),
+                             lay_out)[0]
+
+
+def _derived_key(g: Expr, source) -> dict:
+    """The radix digit of group key `g`, an expression over ONE numeric
+    DICT-coded column (``EXTRACT(YEAR FROM o_orderdate)``), as a
+    `_plan_radix` entry of kind ``derived``: `g` evaluated on the host over
+    the column's dictionary gives each code's value; ``values`` are those
+    values' sorted uniques (the domain, read off the dictionary), ``map``
+    each code's index among them (``len(values)``, the NULL digit, where
+    `g` is NULL).  The program reads the column's codes, so the key costs
+    one lookup in a table of the dictionary's length.  `source(ref)` gives
+    the column a ref reads.  Any other expression raises `_Unsupported`."""
+    def ref_key(x):
+        if isinstance(x, _BuildRef):
+            return ("build", x.k, x.col)
+        if isinstance(x, ColumnRef) and type(x) is ColumnRef:
+            return ("probe", x.index)
+        return None
+
+    refs = {ref_key(sub): sub for sub in walk(g) if ref_key(sub) is not None}
+    if len(refs) != 1:
+        raise _Unsupported("non-column group key")
+    (key, ref), = refs.items()
+    col, _ = source(ref)
+    if getattr(col, "encoding", Encoding.PLAIN) is not Encoding.DICT \
+            or col.sql_type in STRING_TYPES:
+        raise _Unsupported("group key over a column that is not DICT-coded")
+    n = len(col.enc_values)
+    on_codes = transform(g, lambda x: ColumnRef(0, "__k", x.sql_type,
+                                                x.nullable)
+                         if ref_key(x) == key else x)
+    ev = _TraceEval(_SlotMeta([_ColMeta(col)], ["__k"]))
+    d, v = ev.eval(on_codes, {0: (jnp.arange(n, dtype=jnp.int32), None),
+                              PARAMS_SLOT: ()})
+    d = np.broadcast_to(np.asarray(d), (n,))
+    if d.dtype.kind not in "iub":
+        raise _Unsupported("group key expression of a non-integer type")
+    valid = np.ones(n, dtype=bool) if v is None \
+        else np.broadcast_to(np.asarray(v), (n,))
+    values, index = np.unique(d[valid], return_inverse=True)
+    code_map = np.full(n, len(values), dtype=np.int32)
+    code_map[valid] = index
+    return {"ref": ref, "kind": "derived", "raw": True, "r": len(values) + 1,
+            "off": 0, "col": col, "map": code_map,
+            "values": values.astype(sql_to_np(g.sql_type)),
+            "sql_type": g.sql_type}
+
+
 class _SlotMeta:
     """Duck-typed stand-in for Table inside _TraceEval: column metadata for
     the extended slot space (probe scan columns + gathered build columns)."""
@@ -566,6 +652,11 @@ class CompiledJoinAggregate:
         #: (rebound with the tables): counts `join.compact.*` per request
         self.metrics = executor.context.metrics
         whole = whole if whole is not None else [None] * len(build_tables)
+        #: join k -> the slots of its two-column key (`composite_slots`):
+        #: only a side kept whole has them
+        self.composites: Dict[int, dict] = {
+            k: w["composite"] for k, w in enumerate(whole)
+            if w is not None and "composite" in w}
 
         check_agg_static_support(agg_exprs)
         check_no_rle(probe_table)
@@ -609,19 +700,28 @@ class CompiledJoinAggregate:
         self.side_uids = [None if w is None else w.get("uid") for w in whole]
         #: per build side: the rows of its buffers as the program reads them
         self.build_rows = [
-            bucket_rows(bt.padded_rows) if uid is not None else bt.padded_rows
-            for bt, uid in zip(build_tables, self.side_uids)]
+            self.composites[k]["slots"] if k in self.composites
+            else bucket_rows(bt.padded_rows) if uid is not None
+            else bt.padded_rows
+            for k, (bt, uid) in enumerate(zip(build_tables, self.side_uids))]
         #: per join, the program's runtime bounds ``[lo, hi, rows]``: the
         #: lowest and highest key its LUT's (or semi-join state's) slots
         #: hold, and the build side's true row count; operands, so the
         #: lowered program carries none of them.  int32 where they fit (a
         #: 64-bit integer is two words on the TPU, and the program compares
-        #: with them at a build side's rows)
+        #: with them at a build side's rows).  With a two-column key in the
+        #: program, ``[lo2, hi2]`` follow: the other column's range (0, 0
+        #: for the other joins); `lo` and `hi` are then the leading one's
         bounds = np.array(
-            [[lo, min(lo + (lut.shape[0] if lut is not None else
-                            w["semi"]["domain"]) - 1, np.iinfo(np.int64).max),
+            [[lo, self.composites[k]["hi"] if k in self.composites else
+              min(lo + (lut.shape[0] if lut is not None else
+                        w["semi"]["domain"]) - 1, np.iinfo(np.int64).max),
               bt.num_rows]
-             for (lo, lut), bt, w in zip(self.luts, build_tables, whole)],
+             + ([] if not self.composites else
+                [self.composites[k]["lo2"], self.composites[k]["hi2"]]
+                if k in self.composites else [0, 0])
+             for k, ((lo, lut), bt, w) in enumerate(
+                 zip(self.luts, build_tables, whole))],
             dtype=np.int64)
         narrow = np.iinfo(np.int32)
         self.bounds = bounds.astype(np.int32) if bounds.size and \
@@ -652,7 +752,8 @@ class CompiledJoinAggregate:
         #: join k -> the WHOLE build side j whose rows it is probed from
         self.folded = self._plan_folds(ext, whole, rest)
         all_exprs = rest + [j["lkey"] for k, j in enumerate(ext.joins)
-                            if k not in self.folded]
+                            if k not in self.folded] \
+            + [ext.joins[k]["pair"][0] for k in self.composites]
         for e in all_exprs:
             for sub in walk(e):
                 if isinstance(sub, _BuildRef):
@@ -686,6 +787,9 @@ class CompiledJoinAggregate:
         self.lkeys = [onto_build(j["lkey"]) if k in self.folded
                       else finalize(j["lkey"])
                       for k, j in enumerate(ext.joins)]
+        #: a two-column key's other probe key, per such join
+        self.lkeys2 = {k: finalize(ext.joins[k]["pair"][0])
+                       for k in self.composites}
         #: a dependent's key over the gid build side's own columns
         self.dep_lkeys = {m: onto_build(ext.joins[m]["lkey"])
                           for m in self.dependents}
@@ -743,7 +847,18 @@ class CompiledJoinAggregate:
             + ([domain_est] if self.radix_spec is not None else []))
         self.segsum_mode = choose_segsum_impl(executor.config, domain_est)
         self.topk = self._plan_topk(topk, build_tables)
+        #: joins probed at the compacted rows only (`_plan_deferred`), in
+        #: join order; none where the program does not compact
+        self.deferred = self._plan_deferred(ext)
+        if self.deferred and str(executor.config.get(
+                "sql.compile.segsum", "auto")) == "auto":
+            # what a compacting program reduces is the buffer's rows: few,
+            # so the exact float64 scatter costs little where the blocked
+            # matmul's float32 partials lose more than a float32 sum would
+            self.segsum_mode = "scatter"
         self.compact_cap = self._plan_compaction(probe_table)
+        if not self.compact_cap:
+            self.deferred = ()
         #: SUM / AVG aggregates summed in code space (`aggregate.sum.
         #: codespace`): the outer ones over the probe's rows (the compact
         #: branch reduces fewer, so it engages wherever the whole one does),
@@ -828,18 +943,55 @@ class CompiledJoinAggregate:
                 folded[k] = j
         return folded
 
+    def _plan_deferred(self, ext) -> Tuple[int, ...]:
+        """Joins whose build side no filter narrows (a whole side without
+        conjuncts of its own, nothing folded into it; not the pointer gid's,
+        nor a side its group keys are read from): in TPC-H, a foreign key
+        every row finds.  They cannot make the rows that pass much fewer,
+        so the program compacts the rows the OTHER joins and filters pass
+        and probes these at the compacted rows alone (at the probe's rows,
+        under the mask, where more pass than the buffer holds).  A join
+        whose columns the probe's filters, or a join not deferred, read
+        stays at the probe's rows."""
+        if type(self) is not CompiledJoinAggregate:
+            return ()
+        parents = set(self.folded.values())
+        deferred = {
+            k for k, j in enumerate(ext.joins)
+            if j["exposes"] and k not in self.folded and k not in parents
+            and k != self.gid_join and k not in self.dependents
+            and self.build_conjuncts[k] == []}
+
+        def reads(e, k):
+            return any(isinstance(sub, _BuildRef) and sub.k == k
+                       for sub in walk(e))
+
+        changed = True
+        while changed:
+            kept = [m for m in range(len(ext.joins)) if m not in deferred
+                    and m not in self.folded]
+            readers = list(ext.conjuncts) + [
+                key for m in kept for key in [ext.joins[m]["lkey"]]
+                + ([ext.joins[m]["pair"][0]] if "pair" in ext.joins[m]
+                   else [])]
+            stay = {k for k in deferred if any(reads(e, k) for e in readers)}
+            deferred -= stay
+            changed = bool(stay)
+        return tuple(sorted(deferred))
+
     def _plan_compaction(self, probe_table) -> int:
         """The compact buffer's rows, or 0 where the program reduces the
         probe whole as ever: a segment sum that is not a scatter (its cost
-        is not per row), a probe sharded over a mesh or padded (the sharded
-        rung, spmd/join.py, traces this class's body per shard), a probe
-        under `_COMPACT_MIN_ROWS`."""
+        is not per row) and no join to defer to the compacted rows, a probe
+        sharded over a mesh or padded (the sharded rung, spmd/join.py,
+        traces this class's body per shard), a probe under
+        `_COMPACT_MIN_ROWS`."""
         from ..parallel import dist_plan as _dp
 
         datas = [c.data for c in probe_table.columns.values()]
         n_rows = int(datas[0].shape[0])
         if (type(self) is not CompiledJoinAggregate
-                or self.segsum_mode != "scatter"
+                or (self.segsum_mode != "scatter" and not self.deferred)
                 or probe_table.row_valid is not None
                 or any(_dp.array_is_sharded(d) for d in datas)
                 or not _COMPACT_MIN_ROWS <= n_rows < (1 << 31)):
@@ -896,16 +1048,22 @@ class CompiledJoinAggregate:
         spec = []
         domain = 1
         pending = []  # (slot, device min, device max): ONE pull for all keys
+
+        def source(ref):
+            """The column `ref` reads, and its table's row mask."""
+            if isinstance(ref, _BuildRef):
+                bt = build_tables[ref.k]
+                return bt.columns[bt.column_names[ref.col]], bt.row_valid
+            return (probe_table.columns[probe_table.column_names[ref.index]],
+                    probe_table.row_valid)
+
         for g in group_exprs:
-            if isinstance(g, _BuildRef):
-                bt = build_tables[g.k]
-                col = bt.columns[bt.column_names[g.col]]
-                row_valid = bt.row_valid
-            elif isinstance(g, ColumnRef) and type(g) is ColumnRef:
-                col = probe_table.columns[probe_table.column_names[g.index]]
-                row_valid = probe_table.row_valid
+            if isinstance(g, _BuildRef) or (isinstance(g, ColumnRef)
+                                            and type(g) is ColumnRef):
+                col, row_valid = source(g)
             else:
-                raise _Unsupported("non-column group key")
+                spec.append(_derived_key(g, source))
+                continue
             if col.sql_type in STRING_TYPES and col.dictionary is not None:
                 spec.append({"ref": g, "kind": "str",
                              "r": len(col.dictionary) + 1, "off": 0,
@@ -974,12 +1132,27 @@ class CompiledJoinAggregate:
         semis = self.semis
         dependents = self.dependents
         dep_lkeys = self.dep_lkeys
+        lkeys2 = self.lkeys2
+        #: the host's numbers of each two-column key's slots (none of its
+        #: buffers: the closure must not pin them)
+        composites = {k: {n: c[n] for n in ("run", "lo", "slots", "lo2",
+                                            "hi2")}
+                      for k, c in self.composites.items()}
         #: the slots the aggregates read: all the compact branch gathers
         agg_slots = sorted({
             sub.index for a in agg_exprs
             for e in list(a.args) + ([a.filter] if a.filter is not None
                                      else [])
             for sub in walk(e) if type(sub) is ColumnRef})
+        deferred = self.deferred
+        #: ... and, with deferred joins, what their keys and the radix group
+        #: keys read, but the slots those joins fill in the branch
+        branch_slots = agg_slots if not deferred else sorted({
+            sub.index for e in [lkeys[k] for k in deferred]
+            + [lkeys2[k] for k in deferred if k in lkeys2]
+            + [s["ref"] for s in radix_spec or ()]
+            for sub in walk(e) if type(sub) is ColumnRef}.union(agg_slots)
+            - {slot for (k, _), slot in used.items() if k in deferred})
 
         def fn(probe_datas, probe_valids, luts, build_cols, row_valid,
                params=(), bounds=None):
@@ -993,18 +1166,18 @@ class CompiledJoinAggregate:
             # join match, filter, and reduction (exact-spec sharding)
             mask = jnp.ones(n_rows, dtype=bool) if row_valid is None \
                 else row_valid
-            def pointer(k, kd, kv, lut):
-                """Build-row index per key of join `k` (-1: no row)."""
-                size = lut.shape[0]
+
+            def offset(kd, lo, hi, rmin, size):
+                """``(int32 offset of each key from lo, clipped into [0,
+                size), whether it lies in [lo, hi])``."""
                 # widen sub-int32 keys before subtracting (narrow dtypes can
                 # overflow under `key - rmin`); if rmin itself doesn't fit
                 # the key dtype, compute in int64 (no match is representable
                 # without it).  LUT positions/row-ids always fit int32.
-                lo, hi = bounds[k, 0], bounds[k, 1]
                 if np.dtype(kd.dtype).itemsize < 4:
                     kd = kd.astype(jnp.int32)
                 info = jnp.iinfo(kd.dtype)
-                if info.min <= rmins[k] <= info.max:
+                if info.min <= rmin <= info.max:
                     # in-dtype subtraction can wrap for probe keys far
                     # outside the build range (e.g. kd < INT_MIN + rmin)
                     # and land back inside [0, size) — bound the KEY itself
@@ -1019,9 +1192,35 @@ class CompiledJoinAggregate:
                 else:
                     idx = kd.astype(jnp.int64) - lo
                     inb = (idx >= 0) & (idx < size)
-                idx32 = jnp.clip(idx, 0, size - 1).astype(jnp.int32)
+                return jnp.clip(idx, 0, size - 1).astype(jnp.int32), inb
+
+            def pointer(k, kd, kv, lut):
+                """Build-row index per key of join `k` (-1: no row)."""
+                idx32, inb = offset(kd, bounds[k, 0], bounds[k, 1], rmins[k],
+                                    lut.shape[0])
                 ri = jnp.where(inb, lut[idx32].astype(jnp.int32), jnp.int32(-1))
                 return ri if kv is None else jnp.where(kv, ri, -1)
+
+            def composite_pointer(k, keys, second):
+                """Slot index per probe row of two-column join `k` (-1: no
+                row): the leading key's ``run`` slots, the one whose
+                ``second`` is the other key's offset."""
+                (kd, kv), (kd2, kv2) = keys
+                comp = composites[k]
+                run = comp["run"]
+                idx, inb = offset(kd, bounds[k, 0], bounds[k, 1], comp["lo"],
+                                  comp["slots"] // run)
+                idx2, inb2 = offset(kd2, bounds[k, 3], bounds[k, 4],
+                                    comp["lo2"], comp["hi2"] - comp["lo2"] + 1)
+                base = idx * run
+                ri = jnp.full(base.shape, -1, dtype=jnp.int32)
+                for i in range(run):
+                    ri = jnp.where(second[base + i] == idx2, base + i, ri)
+                ri = jnp.where(inb & inb2, ri, -1)
+                for v in (kv, kv2):
+                    if v is not None:
+                        ri = jnp.where(v, ri, -1)
+                return ri
 
             def build_slots(k):
                 bslots = {col: build_cols[(bk, col)]
@@ -1098,6 +1297,10 @@ class CompiledJoinAggregate:
                         keep = hit if keep is None else (keep & hit)
                 if keep is None:
                     return lut
+                if k in composites:
+                    # one entry a slot: a slot whose row falls out holds
+                    # no key
+                    return jnp.where(keep, lut, -1)
                 return by_parts(
                     lambda part: jnp.where(keep[jnp.clip(part, 0, None)],
                                            part, -1), lut)
@@ -1129,30 +1332,44 @@ class CompiledJoinAggregate:
                 return d[safe], (ri >= 0) if v is None else (ri >= 0) & v[safe]
 
             ri_safe: Dict[int, jnp.ndarray] = {}
-            for k in range(n_joins):
-                if k in folded:
-                    continue
-                kd, kv = ev.eval(lkeys[k], slots)
-                ri = pointer(k, kd, kv, kept_lut(k))
-                matched = ri >= 0
-                mask = mask & matched
-                safe = jnp.clip(ri, 0, None)
-                ri_safe[k] = safe
-                # materialize this build table's used columns into the slot
-                # space so later keys/aggs/filters can reference them
-                for (bk, col), slot in used.items():
-                    if bk != k:
-                        continue
-                    bd, bv = build_cols[(bk, col)]
-                    d = bd[safe]
-                    v = matched if bv is None else (matched & bv[safe])
-                    slots[slot] = (d, v)
+
+            def probe(ks, slots, mask):
+                """`mask` narrowed by the matches of joins `ks` probed at
+                the rows of `slots`, their used build columns put there."""
+                for k in ks:
+                    kd, kv = ev.eval(lkeys[k], slots)
+                    if k in composites:
+                        ri = composite_pointer(
+                            k, [(kd, kv), ev.eval(lkeys2[k], slots)],
+                            kept_lut(k))
+                    else:
+                        ri = pointer(k, kd, kv, kept_lut(k))
+                    matched = ri >= 0
+                    mask = mask & matched
+                    safe = jnp.clip(ri, 0, None)
+                    ri_safe[k] = safe
+                    # materialize this build table's used columns into the
+                    # slot space so later keys/aggs/filters can reference
+                    # them
+                    for (bk, col), slot in used.items():
+                        if bk != k:
+                            continue
+                        bd, bv = build_cols[(bk, col)]
+                        d = bd[safe]
+                        v = matched if bv is None else (matched & bv[safe])
+                        slots[slot] = (d, v)
+                return mask
+
+            mask = probe([k for k in range(n_joins)
+                          if k not in folded and k not in deferred],
+                         slots, mask)
             for f in conjuncts:
                 d, v = ev.eval(f, slots)
                 mask = mask & (d if v is None else (d & v))
-            if radix_spec is not None:
-                gid = jnp.zeros(n_rows, dtype=jnp.int32)
-                domain = 1
+
+            def radix_gid(slots, n):
+                """The mixed-radix group id of `slots`' `n` rows."""
+                gid = jnp.zeros(n, dtype=jnp.int32)
                 for s in radix_spec:
                     if s.get("raw"):
                         # encoded key: the CODES are the radix digits —
@@ -1161,6 +1378,14 @@ class CompiledJoinAggregate:
                     else:
                         d, v = ev.eval(s["ref"], slots)
                     r = s["r"]
+                    if s["kind"] == "derived":
+                        # the codes' digits, NULL included: `_derived_key`
+                        code = jnp.asarray(s["map"])[
+                            jnp.clip(d, 0, len(s["map"]) - 1)]
+                        if v is not None:
+                            code = jnp.where(v, code, r - 1)
+                        gid = gid * r + code
+                        continue
                     if s["kind"] == "bool":
                         code = d.astype(jnp.int32)
                     else:
@@ -1176,7 +1401,12 @@ class CompiledJoinAggregate:
                     if v is not None:
                         code = jnp.where(v, code, r - 1)
                     gid = gid * r + code
-                    domain *= r
+                return gid
+
+            if radix_spec is not None:
+                domain = int(np.prod([s["r"] for s in radix_spec]))
+                # read from a deferred join's columns: made in each branch
+                gid = None if deferred else radix_gid(slots, n_rows)
             elif gid_join < 0:
                 gid = jnp.zeros(n_rows, dtype=jnp.int32)
                 domain = 1
@@ -1197,6 +1427,7 @@ class CompiledJoinAggregate:
             if not compact_cap:
                 hits, outs = reduce_rows(slots, mask, gid, n_rows)
             else:
+                # with deferred joins: the rows the others pass, a superset
                 passed = jnp.sum(mask, dtype=jnp.int32)
 
                 def compacted():
@@ -1204,14 +1435,26 @@ class CompiledJoinAggregate:
                     # sum adds the terms the whole probe's would
                     at = compact_positions(mask, compact_cap)
                     some = {i: (slots[i][0][at], None if slots[i][1] is None
-                                else slots[i][1][at]) for i in agg_slots}
+                                else slots[i][1][at]) for i in branch_slots}
                     some[PARAMS_SLOT] = params
                     live = jnp.arange(compact_cap, dtype=jnp.int32) < passed
-                    return reduce_rows(some, live, gid[at], compact_cap)
+                    if not deferred:
+                        return reduce_rows(some, live, gid[at], compact_cap)
+                    live = probe(deferred, some, live)
+                    return reduce_rows(
+                        some, live, radix_gid(some, compact_cap)
+                        if gid is None else gid[at], compact_cap)
 
-                hits, outs = jax.lax.cond(
-                    passed <= compact_cap, compacted,
-                    lambda: reduce_rows(slots, mask, gid, n_rows))
+                def whole():
+                    if not deferred:
+                        return reduce_rows(slots, mask, gid, n_rows)
+                    full = dict(slots)
+                    matched = probe(deferred, full, mask)
+                    return reduce_rows(full, matched, radix_gid(full, n_rows)
+                                       if gid is None else gid, n_rows)
+
+                hits, outs = jax.lax.cond(passed <= compact_cap, compacted,
+                                          whole)
             hit = hits > 0
             tags: List[Tuple[str, np.dtype]] = []
             # the counts the host reads beside the rows: the probe rows that
@@ -1273,9 +1516,10 @@ class CompiledJoinAggregate:
         luts = tuple(lut for _, lut in self.luts)
         build_cols = {}
         for (k, col) in self.build_col_keys:
-            bt = self.build_tables[k]
-            c = bt.columns[bt.column_names[col]] if self.side_uids[k] is None \
-                else padded_column(self.side_uids[k], bt, col)
+            bt, uid = self.build_tables[k], self.side_uids[k]
+            c = bt.columns[bt.column_names[col]] if uid is None \
+                else slotted_column(uid, bt, col, self.composites[k]) \
+                if k in self.composites else padded_column(uid, bt, col)
             build_cols[(k, col)] = (c.data, c.validity)
         return (probe_datas, probe_valids, luts, build_cols, pt.row_valid,
                 tuple(params), self.bounds)
@@ -1298,6 +1542,10 @@ class CompiledJoinAggregate:
             launch_attrs["compact"] = cap
         if self.semis:
             launch_attrs["semi"] = len(self.semis)
+        if self.composites:
+            launch_attrs["composite"] = len(self.composites)
+        if self.deferred:
+            launch_attrs["deferred"] = len(self.deferred)
         if self.sum_codespace:
             launch_attrs["sum_codespace"] = self.sum_codespace
         packed = timed_jit_call(
@@ -1426,6 +1674,10 @@ class CompiledJoinAggregate:
                 is_null = code == (r - 1)
                 validity = ~is_null if bool(is_null.any()) else None
                 code = np.minimum(code, r - 2)
+                if spec["kind"] == "derived":
+                    out[name] = Column(spec["values"][code], spec["sql_type"],
+                                       validity)
+                    continue
                 # shared host decode handles str/bool/plain-int AND the
                 # encoded (DICT/FOR) key kinds
                 out[name] = decode_radix_group_key(spec["col"], code,
@@ -1496,6 +1748,44 @@ def _whole_lut(executor, join: dict, bdc, table: Table):
         (bdc.uid, str(join["rkey"]), budget),
         lambda: build_lut(executor, join["rkey"], table, max_bytes=budget,
                           uid=bdc.uid))
+
+
+def _composite_side(executor, join: dict, bdc, table: Table):
+    """The kept slots of a whole build side's table version joined on a
+    two-column key (`composite_slots`, ``second`` on the device, ``key``
+    naming the layout for `slotted_column`), built on first use: ``(entry
+    or None, built here)``.  None where the rule declines: a key that is
+    no plain integer column on either side, a padded (sharded) table, or
+    what `composite_slots` declines."""
+    from ..analysis.estimator import device_budget_bytes
+    from ..ops.join import composite_slots
+    from ..utils import d2h_fetch
+
+    budget = min(device_budget_bytes(executor.config) or _LUT_MAX_BYTES,
+                 _LUT_MAX_BYTES)
+    rkeys = (join["rkey"], join["pair"][1])
+    key = f"{rkeys[0]}&{rkeys[1]}"
+    if not all(type(x) is ColumnRef or isinstance(x, _BuildRef)
+               for x in (join["lkey"], join["pair"][0])):
+        return None, False  # an expression on the probe's side of a key
+
+    def build():
+        if table.row_valid is not None \
+                or not all(type(k) is ColumnRef for k in rkeys):
+            return None
+        cols = [executor.eval_expr(k, table).decode() for k in rkeys]
+        if any(c.sql_type in STRING_TYPES
+               or not jnp.issubdtype(c.data.dtype, jnp.integer)
+               for c in cols):
+            return None
+        with d2h_fetch():
+            host = jax.device_get([(c.data, c.validity) for c in cols])
+        got = composite_slots(host, budget)
+        if got is None:
+            return None
+        return dict(got, key=key, second=jax.device_put(got["second"]))
+
+    return LUTS.get_or_build((bdc.uid, "composite", key, budget), build)
 
 
 def _key_range(executor, key: Expr, table: Table) -> Optional[Tuple[int, int]]:
@@ -1578,6 +1868,7 @@ def _stays_whole(k: int, join: dict, table: Table, ext, group_exprs,
     read = {sub.index for e in join["whole"] for sub in walk(e)
             if type(sub) is ColumnRef}
     exprs = (ext.conjuncts + [j["lkey"] for j in ext.joins]
+             + [j["pair"][0] for j in ext.joins if "pair" in j]
              + [x for a in agg_exprs for x in a.args]
              + [a.filter for a in agg_exprs if a.filter is not None])
     choice = _choose_gid_join(ext, group_exprs)
@@ -1677,14 +1968,28 @@ def try_compiled_join_aggregate(rel: p.Aggregate, executor) -> Optional[Table]:
                         bt = bt.select(scan.projection)
                     if bt.column_names and _stays_whole(
                             k, j, bt, ext, group_exprs, agg_exprs):
-                        lut, built_here = _whole_lut(executor, j, bdc, bt)
+                        comp = None
+                        if "pair" in j:
+                            with detail("join:composite") as cattrs:
+                                comp, built_here = _composite_side(
+                                    executor, j, bdc, bt)
+                                cattrs.update(
+                                    rows=bt.num_rows, reused=not built_here,
+                                    run=comp and comp["run"])
+                            lut = comp and (comp["lo"], comp["second"])
+                            if comp and comp["lead"]:
+                                # the other pair leads: it is the join's key
+                                (j["lkey"], j["rkey"]), j["pair"] = \
+                                    j["pair"], (j["lkey"], j["rkey"])
+                        else:
+                            lut, built_here = _whole_lut(executor, j, bdc, bt)
                         if lut is not None:
                             ctx.metrics.inc("join.lut.built" if built_here
                                             else "join.lut.reused")
                             built += int(built_here)
                             lut_bytes += int(lut[1].nbytes)
-                            padded += int(bucket_rows(bt.padded_rows)
-                                          > bt.padded_rows)
+                            padded += int(comp is None and bucket_rows(
+                                bt.padded_rows) > bt.padded_rows)
 
                             def dictionary_of(i, bt=bt):
                                 return bt.columns[
@@ -1693,6 +1998,10 @@ def try_compiled_join_aggregate(rel: p.Aggregate, executor) -> Optional[Table]:
                             w = {"lut": lut, "uid": bdc.uid, "conjuncts": [
                                 pz.rewrite(e, dictionary_of)
                                 for e in j["whole"]]}
+                            if comp:
+                                w["composite"] = comp
+                if w is None and "pair" in j:
+                    raise _Unsupported("two-column key not kept whole")
                 if w is None:
                     # any other build side runs through the normal recursive
                     # converter (nested joins, aggregates, anything) and
@@ -1702,6 +2011,8 @@ def try_compiled_join_aggregate(rel: p.Aggregate, executor) -> Optional[Table]:
                 whole.append(w)
             attrs.update(tables=len(build_tables), lut_bytes=lut_bytes,
                          built=built, padded=padded)
+        if pz.masks:
+            ctx.metrics.inc("join.like.masks", len(pz.masks))
         params = pz.params
         topk = executor.topk_hints.get(id(rel))
         family = (
@@ -1720,7 +2031,9 @@ def try_compiled_join_aggregate(rel: p.Aggregate, executor) -> Optional[Table]:
                    tuple(str(a) for a in w["semi"]["aggs"]),
                    tuple(str(e) for e in w["semi"]["having"]))
                   for j, w in zip(ext.joins, whole)),
-            tuple(str(j["lkey"]) + "=" + str(j["rkey"]) for j in ext.joins),
+            tuple(str(j["lkey"]) + "=" + str(j["rkey"])
+                  + ("&{}={}".format(*j["pair"]) if "pair" in j else "")
+                  for j in ext.joins),
             tuple(str(e) for e in ext.conjuncts),
             tuple(str(e) for e in group_exprs),
             tuple(str(a) for a in agg_exprs),
@@ -1729,7 +2042,8 @@ def try_compiled_join_aggregate(rel: p.Aggregate, executor) -> Optional[Table]:
         )
         bucket = (tuple(uids), probe_table.num_rows, probe_table.padded_rows,
                   tuple(bt.num_rows if w is None or "semi" in w
-                        else bucket_rows(bt.padded_rows)
+                        else (w["composite"]["slots"], w["composite"]["run"])
+                        if "composite" in w else bucket_rows(bt.padded_rows)
                         for bt, w in zip(build_tables, whole)))
         # the constructor binds the tables this first run reads; the finally
         # below drops them.  No `warm`: this rung never defers
@@ -1755,6 +2069,9 @@ def try_compiled_join_aggregate(rel: p.Aggregate, executor) -> Optional[Table]:
                 ctx.metrics.inc("join.build.padded", padded)
             if semi:
                 ctx.metrics.inc("join.build.semi", semi)
+            if compiled.composites:
+                ctx.metrics.inc("join.build.composite",
+                                len(compiled.composites))
             if kept + semi < len(whole):
                 ctx.metrics.inc("join.build.eager", len(whole) - kept - semi)
             if compiled.wide_domains:

@@ -71,6 +71,13 @@ DOCUMENTED_METRICS = frozenset({
     # grouped by the join key (IN over GROUP BY .. HAVING), reduced inside
     # the program (one per such build side)
     "join.build.semi",
+    # programs built with a build side joined on a two-column key, kept
+    # whole in slots (`ops/join.py::composite_slots`; one per such side)
+    "join.build.composite",
+    # string predicates bound as a runtime mask over a build side's
+    # dictionary (`families/parameterize.py::_string_mask`; one per
+    # request and pattern)
+    "join.like.masks",
     # physical/compiled.py + compiled_join.py — programs built with ONE
     # integer group key whose range lies past the mixed-radix gate
     # (`ops.grouping.one_key_domain_limit`: admitted by the bytes of its

@@ -193,8 +193,10 @@ def try_spmd_join_aggregate(rel: p.Aggregate, executor) -> Optional[Table]:
     if extraction is None:
         return None
     ext, group_exprs, agg_exprs = extraction
-    if not all(j["exposes"] for j in ext.joins):
-        return None  # a semi-join's build side is the one-chip program's
+    if not all(j["exposes"] and "pair" not in j for j in ext.joins):
+        # a semi-join's build side, and a two-column key's slots, are the
+        # one-chip program's
+        return None
     try:
         from ..datacontainer import LazyParquetContainer
 

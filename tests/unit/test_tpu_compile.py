@@ -542,3 +542,129 @@ def test_join_aggregate_q18_fits_one_chip_at_cell_size(one_chip):
     sorts = re.findall(r"= \(?(\w+)\[(\d+)\][^=]*? sort\(", text)
     assert sorts and all(dtype in ("s32", "u32", "f32", "pred")
                          for dtype, _ in sorts), sorts
+
+
+#: the benchmark's Q9 cell (sf10_q9_library): LINEITEM and ORDERS as the Q3
+#: cell's, PART, SUPPLIER, NATION whole, PARTSUPP's slots (four a part key),
+#: and the dtypes create_table encodes Q9's columns to at that size
+Q9_ROWS = {"lineitem": 24_000_000, "supplier": 100_000, "part": 2_000_000,
+           "orders": 6_000_000, "nation": 25}
+Q9_DTYPES = {"l_orderkey": "int32", "l_partkey": "int32",
+             "l_suppkey": "int32", "o_orderkey": "int32",
+             "p_partkey": "int32", "s_suppkey": "int32"}
+
+
+def test_join_aggregate_q9_fits_one_chip_at_cell_size(one_chip):
+    """`sf10_q9_library`'s ONE program compiled for a v5e at the cell's
+    shapes, as the chip builds it (a float64 scatter segment sum into the
+    208 (nation, year) groups): PART's LIKE as a runtime mask of its 2M
+    names' bucket folded into its LUT at the probe's 24M rows, the passing
+    rows compacted into 1,507,328, and SUPPLIER, NATION, PARTSUPP (its
+    two-column key's slots, four a part) and ORDERS probed at those rows
+    alone, or at every row in the `lax.cond`'s other branch.  Captured from
+    Q9 as `perfbench.traffic` renders it over the cell's generator at
+    200,000 lineitems, then traced anew with the cell's domains.  Costs the
+    suite 4 s of set-up and the compile (printed with -s)."""
+    import time
+
+    from dask_sql_tpu import Context
+    from dask_sql_tpu import config as config_module
+    from dask_sql_tpu.ops.join import bucket_rows
+    from dask_sql_tpu.physical import compiled_join
+    from dask_sql_tpu.physical.compiled_join import CompiledJoinAggregate
+    from perfbench import traffic
+    from perfbench.datagen import tpch_q9_tables
+
+    arrays = tpch_q9_tables.generate(SMALL_ROWS, seed=39, scale_factor=10)
+    frames = tpch_q9_tables.arrow_tables(arrays)
+    seen = []
+    run = CompiledJoinAggregate.run
+
+    def spy_run(self, params=()):
+        seen.append((self, self.probe_table, self._run_args(params)))
+        return run(self, params)
+
+    with pytest.MonkeyPatch.context() as mp, \
+            config_module.set({"serving.cache.enabled": False}):
+        mp.setattr(CompiledJoinAggregate, "run", spy_run)
+        mp.setattr(compiled_join, "_COMPACT_MIN_ROWS", SMALL_ROWS)
+        compiled_join.PROGRAMS.clear()
+        c = Context()
+        for name in ("nation", "supplier", "part", "partsupp", "orders",
+                     "lineitem"):
+            c.create_table(name, frames[name])
+        query = traffic.load("queries", "tpch_q9_green")
+        c.sql(traffic.render(query, {"COLOR": 33})).compute()
+    (pipeline, probe_table, args), = seen
+    names = [j["plan"].table_name for j in pipeline.ext.joins]
+    assert sorted(names) == ["nation", "orders", "part", "partsupp",
+                             "supplier"], names
+    ps = names.index("partsupp")
+    assert list(pipeline.composites) == [ps]
+    assert pipeline.composites[ps]["run"] == 4
+    assert [names[k] for k in pipeline.deferred] == [
+        n for n in names if n != "part"]
+    # the compacted rows are few: the exact scatter, whatever the domain
+    assert pipeline.segsum_mode == "scatter"
+    probe_datas, probe_valids, luts, build_cols, row_valid, params, bounds \
+        = args
+    assert row_valid is None and not any(v is not None for v in probe_valids)
+
+    def shaped(rows, name, data):
+        return jax.ShapeDtypeStruct((rows,), Q9_DTYPES.get(name, data.dtype),
+                                    sharding=one_chip)
+
+    slots = bucket_rows(Q9_ROWS["part"]) * 4
+    rows = [slots if n == "partsupp" else bucket_rows(Q9_ROWS[n])
+            for n in names]
+    lut_rows = {"orders": bucket_rows(24_000_616), "partsupp": slots,
+                **{n: bucket_rows(Q9_ROWS[n])
+                   for n in ("supplier", "part", "nation")}}
+    big_probe = tuple(shaped(Q9_ROWS["lineitem"], n, d) for n, d in
+                      zip(probe_table.column_names, probe_datas))
+    big_luts = tuple(jax.ShapeDtypeStruct((lut_rows[n],), jnp.int32,
+                                          sharding=one_chip) for n in names)
+    tables = [c.schema[c.schema_name].tables[n].table for n in names]
+    big_build = {}
+    for (k, col), (data, valid) in build_cols.items():
+        assert valid is None
+        name = (pipeline.ext.joins[k]["plan"].projection
+                or tables[k].column_names)[col]
+        big_build[(k, col)] = (shaped(rows[k], name, data), None)
+    # the LIKE's mask: one bool per name, at the names' bucket
+    (mask_at,) = [i for i, p in enumerate(params) if np.ndim(p) == 1]
+    big_params = list(_shapes(tuple(params), one_chip))
+    big_params[mask_at] = jax.ShapeDtypeStruct(
+        (bucket_rows(Q9_ROWS["part"]),), jnp.bool_, sharding=one_chip)
+    pipeline.probe_table = probe_table
+    pipeline.build_rows = rows
+    pipeline.composites[ps] = dict(pipeline.composites[ps], slots=slots)
+    cap = pipeline.compact_cap = compiled_join.compact_capacity(
+        Q9_ROWS["lineitem"])
+    assert cap == 1_507_328 and pipeline.domain == 26 * 8
+    try:
+        lowered = jax.jit(pipeline._build()).lower(
+            big_probe, probe_valids, big_luts, big_build, None,
+            tuple(big_params), _shapes(bounds, one_chip))
+    finally:
+        pipeline.probe_table = pipeline.build_tables = None
+    t0 = time.perf_counter()
+    compiled = lowered.compile()
+    seconds = time.perf_counter() - t0
+    m = compiled.memory_analysis()
+    print(f"q9 at cell size: compile {seconds:.1f} s, arguments "
+          f"{m.argument_size_in_bytes}, temporaries {m.temp_size_in_bytes}, "
+          f"output {m.output_size_in_bytes}")
+    assert seconds < 240, seconds
+    # the six tables resident (50 columns, some 1.75 GB) beside the program
+    assert _device_bytes(compiled) + 1_750_000_000 < HBM_BYTES
+    # the scatters: the float64 sum and its count over the buffer's rows in
+    # one branch, over the probe's in the other
+    updates = re.findall(r'"stablehlo\.scatter"\(.*?\}\) : \(tensor<(\d+)x\w+>, '
+                         r"tensor<(\d+)x1xi32>", lowered.as_text(), re.S)
+    assert {int(n) for _, n in updates} == {cap, Q9_ROWS["lineitem"]}, updates
+    assert {int(d) for d, _ in updates} == {26 * 8}, updates
+    text = compiled.as_text()
+    sorts = re.findall(r"= \(?(\w+)\[(\d+)\][^=]*? sort\(", text)
+    assert sorts and all(dtype in ("s32", "u32", "f32", "pred")
+                         for dtype, _ in sorts), sorts
