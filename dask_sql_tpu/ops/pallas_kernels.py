@@ -6,15 +6,22 @@ formulation is a one-hot matmul: `onehot(gid).T @ contribs`.  Two
 implementations:
 
 - `segsum_scan_blocked` — the production path.  Rows are processed in
-  fixed-size blocks under `lax.scan`; each step builds the block's one-hot
-  in on-chip memory, runs ONE [b, domain] x [b, K] matmul on the MXU for
-  ALL K contribution columns at once, and accumulates the per-block partial
-  into a float64 carry.  The per-block f64 accumulation bounds the f32
-  matmul-accumulation error to the block (measured: ~1e-7..1e-6 max
-  relative on 6M uniform rows vs exact f64 — see
-  tests/unit/test_pallas_kernels.py::test_blocked_accuracy_bound, asserted
-  at 5e-6); 0/1 count columns are EXACT (integer-valued f32 partials below
-  2^24 per block, combined exactly in f64).  For float64 inputs the caller
+  fixed-size blocks under `lax.fori_loop`; each step builds the block's
+  one-hot in on-chip memory, runs ONE [domain, b] x [K, b] matmul on the MXU
+  for ALL K contribution columns at once, and accumulates the per-block
+  partial into a float64 carry.  A step reads its block as a contiguous
+  [b] slice of each column and stacks the K slices row-minor, [K, b], in
+  on-chip memory.  A stack of the whole columns would cost a pass over
+  HBM in front of the loop (the TPU relays out each column to concatenate
+  them), and a [blocks, b, K] view of it one more: eight blocks
+  interleaved in every (8, 128) tile, so a relayout copy of the whole
+  stack and a sublane-strided read per step.
+  The per-block f64 accumulation bounds the f32 matmul-accumulation error
+  to the block (measured: ~1e-7..1e-6 max relative on 6M uniform rows vs
+  exact f64 — see tests/unit/test_pallas_kernels.py::
+  test_segsum_scan_blocked_accuracy_and_counts, asserted at 5e-6); 0/1
+  count columns are EXACT (integer-valued f32 partials below 2^24 per
+  block, combined exactly in f64).  For float64 inputs the caller
   splits hi/lo (`split_hi_lo`) so representation error is ~2^-48.
 - `segsum_pallas` — the same math as a hand-written pallas kernel (one-hot
   built only in VMEM).  Kept as an explicit opt-in probe
@@ -64,32 +71,38 @@ def segsum_scan_blocked(gid: jnp.ndarray, cols, domain: int,
     gid: [n] integer ids in [0, domain); cols: list of [n] float32 arrays
     (pre-masked: non-selected rows must carry 0).  Returns [domain, K]
     float64.  Works under jit tracing; block count is static.
+
+    Each step slices its block of ``block`` rows out of every column (a
+    contiguous read of each) and stacks the slices row-minor, ``[K, block]``,
+    in on-chip memory: no ``[K, n]`` or ``[n, K]`` copy of the columns is
+    ever built.  The rows past the last whole block are one more, shorter,
+    partial: nothing is padded.
     """
-    k = len(cols)
     n = gid.shape[0]
-    b = min(block, max(_round_up(n, 8), 8))
-    npad = max(_round_up(n, b), b)
-    nb = npad // b
-    pad = npad - n
-    gid_p = jnp.pad(gid.astype(jnp.int32), (0, pad))
-    stack = jnp.stack([c.astype(jnp.float32) for c in cols], axis=1)  # [n, k]
-    if pad:
-        # padded rows: gid 0 with zero contributions — add nothing
-        stack = jnp.pad(stack, ((0, pad), (0, 0)))
-    gid_b = gid_p.reshape(nb, b)
-    stack_b = stack.reshape(nb, b, k)
+    gid = gid.astype(jnp.int32)
+    cols = [c.astype(jnp.float32) for c in cols]
 
-    def step(carry, xs):
-        g, c = xs
-        onehot = jax.nn.one_hot(g, domain, dtype=jnp.float32)  # [b, domain]
-        part = jax.lax.dot_general(
-            onehot, c, dimension_numbers=(((0,), (0,)), ((), ())),
+    def partial(g, cs):  # [m] ids, K [m] columns -> [domain, K] float64
+        onehot_t = (jax.lax.broadcasted_iota(jnp.int32, (domain, g.shape[0]), 0)
+                    == g[None, :]).astype(jnp.float32)
+        return jax.lax.dot_general(
+            onehot_t, jnp.stack(cs), dimension_numbers=(((1,), (1,)), ((), ())),
             precision=jax.lax.Precision.HIGHEST,
-            preferred_element_type=jnp.float32)  # [domain, k]
-        return carry + part.astype(jnp.float64), None
+            preferred_element_type=jnp.float32).astype(jnp.float64)
 
-    init = jnp.zeros((domain, k), dtype=jnp.float64)
-    out, _ = jax.lax.scan(step, init, (gid_b, stack_b))
+    def step(i, carry):
+        def block_of(x):
+            return jax.lax.dynamic_slice_in_dim(x, i * block, block)
+
+        return carry + partial(block_of(gid), [block_of(c) for c in cols])
+
+    out = jnp.zeros((domain, len(cols)), dtype=jnp.float64)
+    whole = n // block
+    if whole:
+        out = jax.lax.fori_loop(np.int32(0), np.int32(whole), step, out)
+    if whole * block < n:
+        tail = whole * block
+        out = out + partial(gid[tail:], [c[tail:] for c in cols])
     return out
 
 

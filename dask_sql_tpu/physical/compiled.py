@@ -279,7 +279,9 @@ class SegmentReducer:
         blocked one-hot MXU matmul (`ops.pallas_kernels.segsum_scan_blocked`)
         with float64 per-block partial accumulation — float64 inputs ride an
         exact hi/lo float32 split, counts are exact, and the float error is
-        bounded by MATMUL_FLOAT_REL_ERR_BOUND.
+        bounded by MATMUL_FLOAT_REL_ERR_BOUND.  Each block of rows is read
+        as a contiguous slice of every column and stacked row-minor,
+        ``[K, block]``, on chip: no stack of the whole columns is built.
     Integer sums always use exact int64 scatter (SQL exactness).
 
     Usage: register reductions (count / sum_float / sum_int / minmax),

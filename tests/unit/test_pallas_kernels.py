@@ -108,29 +108,91 @@ def test_choose_impl(c):
     assert c.metrics.snapshot()["counters"].get("resilience.degraded", 0) == 0
 
 
-def test_segsum_scan_blocked_accuracy_and_counts():
+def _blocked_case(seed, n, k, domain):
+    """ids, k float32 columns (a 0/1 count column, then hi/lo halves of
+    float64 values, then one plain float32 column if k is even) and the
+    exact float64 sums each column and each hi/lo pair stands for."""
+    from dask_sql_tpu.ops.pallas_kernels import split_hi_lo
+
+    rng = np.random.RandomState(seed)
+    gid = rng.randint(0, domain, n).astype(np.int32)
+    mask = rng.rand(n) < 0.8
+    cols, pairs = [mask.astype(np.float32)], []
+    while len(cols) + 2 <= k:
+        x64 = np.where(mask, rng.rand(n) * 1e9 + 0.123456789, 0.0)
+        hi, lo = split_hi_lo(jnp.asarray(x64))
+        pairs.append((len(cols), x64))
+        cols += [np.asarray(hi), np.asarray(lo)]
+    if len(cols) < k:
+        cols.append(np.where(mask, rng.rand(n), 0.0).astype(np.float32))
+    return gid, cols, pairs
+
+
+@pytest.mark.parametrize("n, k, domain, block", [
+    (200_000, 3, 16, 8192),      # the original case: a ragged last block
+    (4 * 4096, 4, 12, 4096),     # rows a multiple of the block
+    (4 * 4096 + 1, 16, 12, 4096),  # one row past a multiple
+    (3000, 16, 12, 4096),        # rows below one block
+    (1000, 1, 1, 4096),          # one column, one group
+    (2 * 4096 + 100, 1, 12, 4096),  # one column, a short tail
+    (9000, 4, 2048, 4096),       # the widest domain `auto` sends here
+    (5000, 16, 2048, 4096),
+    (6_000_000, 4, 1, 32768),    # a shard of the four-chip cell: 3456-row tail
+])
+def test_segsum_scan_blocked_accuracy_and_counts(n, k, domain, block):
     from dask_sql_tpu.ops.pallas_kernels import (
         MATMUL_FLOAT_REL_ERR_BOUND,
         segsum_scan_blocked,
-        split_hi_lo,
     )
 
-    rng = np.random.RandomState(7)
-    n, domain = 200_000, 16
-    gid = jnp.asarray(rng.randint(0, domain, n).astype(np.int32))
-    x64 = jnp.asarray(rng.rand(n) * 1e9 + 0.123456789)
-    mask = jnp.asarray(rng.rand(n) < 0.8)
-    hi, lo = split_hi_lo(jnp.where(mask, x64, 0.0))
-    cols = [mask.astype(jnp.float32), hi, lo]
-    out = segsum_scan_blocked(gid, cols, domain, block=8192)
-    # counts: EXACT (integer-valued f32 block partials, f64 combine)
+    gid, cols, pairs = _blocked_case(7, n, k, domain)
+    out = np.asarray(segsum_scan_blocked(
+        jnp.asarray(gid), [jnp.asarray(c) for c in cols], domain,
+        block=block))
+    assert out.shape == (domain, k) and out.dtype == np.float64
+    # counts: EXACT (integer-valued f32 block partials, f64 combine), so a
+    # row counted twice or a row left out shows
     cnt_exact = np.zeros(domain)
-    np.add.at(cnt_exact, np.asarray(gid), np.asarray(mask).astype(np.float64))
-    assert np.array_equal(np.asarray(out[:, 0]), cnt_exact)
+    np.add.at(cnt_exact, gid, cols[0].astype(np.float64))
+    assert np.array_equal(out[:, 0], cnt_exact)
     # float sums: within the stated bound of the exact f64 result
-    s_exact = np.zeros(domain)
-    np.add.at(s_exact, np.asarray(gid),
-              np.where(np.asarray(mask), np.asarray(x64), 0.0))
-    got = np.asarray(out[:, 1] + out[:, 2])
-    rel = np.max(np.abs(got - s_exact) / np.maximum(np.abs(s_exact), 1e-30))
-    assert rel < MATMUL_FLOAT_REL_ERR_BOUND, rel
+    exact = [(i, i + 1, x64) for i, x64 in pairs]
+    if k % 2 == 0:
+        exact.append((k - 1, None, cols[-1].astype(np.float64)))
+    for i, j, x64 in exact:
+        s_exact = np.zeros(domain)
+        np.add.at(s_exact, gid, x64)
+        got = out[:, i] + (out[:, j] if j is not None else 0.0)
+        rel = np.max(np.abs(got - s_exact) / np.maximum(np.abs(s_exact), 1e-30))
+        assert rel < MATMUL_FLOAT_REL_ERR_BOUND, (i, rel)
+
+
+@pytest.mark.parametrize("n", [5 * 32768, 5 * 32768 + 3456])
+def test_segsum_scan_blocked_keeps_its_blocks(n):
+    """Each block of 32768 rows, and the rows past the last whole block,
+    is one float32 partial, summed in float64 in block order: the result
+    is that sum to the bit (on the CPU, whose dot is deterministic)."""
+    from dask_sql_tpu.ops.pallas_kernels import segsum_scan_blocked
+
+    domain, block = 12, 32768
+    gid, cols, _ = _blocked_case(11, n, 16, domain)
+    stack = np.stack(cols)  # [k, n]
+    part = jax.jit(lambda g, c: jax.lax.dot_general(
+        (jnp.arange(domain)[:, None] == g[None, :]).astype(jnp.float32), c,
+        (((1,), (1,)), ((), ())), precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32))
+    want = np.zeros((domain, len(cols)))
+    for s in range(0, n, block):
+        want += np.asarray(part(gid[s:s + block], stack[:, s:s + block]),
+                           dtype=np.float64)
+    got = jax.jit(lambda g, cs: segsum_scan_blocked(g, cs, domain))(
+        jnp.asarray(gid), [jnp.asarray(c) for c in cols])
+    assert np.array_equal(np.asarray(got), want)
+
+
+def test_segsum_scan_blocked_no_rows():
+    from dask_sql_tpu.ops.pallas_kernels import segsum_scan_blocked
+
+    out = segsum_scan_blocked(jnp.zeros((0,), jnp.int32),
+                              [jnp.zeros((0,), jnp.float32)] * 2, 3)
+    assert np.array_equal(np.asarray(out), np.zeros((3, 2)))

@@ -174,6 +174,37 @@ def test_blocked_segsum_compiles_at_sf10(one_chip):
     assert _device_bytes(compiled) < HBM_BYTES
 
 
+def _instructions(text):
+    """``(result type, dims, opcode)`` of every instruction of an HLO text
+    whose result is one array."""
+    return [(t, tuple(int(d) for d in dims.split(",") if d), op)
+            for t, dims, op in re.findall(
+                r"= ([a-z0-9]+)\[([0-9,]*)\]\{[^}]*\} ([a-z-]+)\(", text)]
+
+
+def test_blocked_segsum_reads_contiguous_blocks(one_chip):
+    """Q1's segment sum at the benchmark's 24M rows, 16 columns and 12
+    groups: each step of the loop slices its 32768 rows out of each column
+    and stacks the slices to ``[16, 32768]`` on chip.  Nothing the size of
+    a column or more is built in front of the loop: no stack of the whole
+    columns, no relayout of one, no pad, no ``[blocks, 32768, 16]`` view."""
+    from dask_sql_tpu.ops.pallas_kernels import segsum_scan_blocked
+
+    gid = jax.ShapeDtypeStruct((CELL_ROWS,), jnp.int32, sharding=one_chip)
+    cols = [jax.ShapeDtypeStruct((CELL_ROWS,), jnp.float32,
+                                 sharding=one_chip)] * 16
+    compiled = jax.jit(lambda g, cs: segsum_scan_blocked(g, cs, 12)).lower(
+        gid, cols).compile()
+    found = _instructions(compiled.as_text())
+    assert not [f for f in found if f[1][1:] == (32768, 16)]
+    assert not [f for f in found if np.prod(f[1]) >= CELL_ROWS
+                and f[2] not in ("parameter", "get-tuple-element")]
+    assert ("f32", (16, 32768), "concatenate") in found
+    assert found.count(("f32", (32768,), "dynamic-slice")) == 16
+    assert compiled.memory_analysis().temp_size_in_bytes < CELL_ROWS * 4
+    assert _device_bytes(compiled) < HBM_BYTES
+
+
 @pytest.mark.parametrize("domain", [6, 2048])
 def test_pallas_segsum_compiles(one_chip, domain):
     """The kernel that had only ever run in interpret mode: Mosaic takes it
