@@ -40,7 +40,9 @@ the compiled evaluators consume it at *trace* time:
     dictionary's bucket (`ops/join.py::bucket_rows`) and handed to the
     program as a runtime operand that it reads at the rows' codes.  So
     every pattern shares one executable; the span ``join:like`` times
-    each evaluation and its transfer (`masks` lists them);
+    each evaluation and its transfer (`masks` lists them).  The probe
+    side's conjuncts (``rewrite(.., probe=True)``) take the LIKE / ILIKE
+    form alone, timed by ``join:probe_like``;
 - other LIKE / ILIKE and every SIMILAR pattern and escape (host-compiled
   regexes);
 - DATE_TRUNC / CEIL unit arguments (static truncation unit);
@@ -253,6 +255,8 @@ class Parameterizer:
         self.key_values: List[Any] = []
         #: column index -> string dictionary, for the span of one `rewrite`
         self._dictionary_of = None
+        #: whether that `rewrite` binds a probe side's conjuncts
+        self._probe = False
         #: one ``{"entries", "matched"}`` per `code_mask` bound so far
         self.masks: List[dict] = []
 
@@ -261,28 +265,36 @@ class Parameterizer:
         return tuple(self.values)
 
     # -------------------------------------------------------- expressions
-    def rewrite(self, expr: Expr, dictionary_of=None) -> Expr:
+    def rewrite(self, expr: Expr, dictionary_of=None,
+                probe: bool = False) -> Expr:
         """``dictionary_of(column index)`` gives the string dictionary of a
         column `expr`'s plain refs index, or None: with it a string literal
-        compared for (in)equality with such a column rides as its code."""
+        compared for (in)equality with such a column rides as its code.
+        With `probe` (the conjuncts of a join's probe side) only a LIKE /
+        ILIKE rides, as a mask timed by ``join:probe_like``; a literal
+        compared for (in)equality stays baked."""
         if not self.enabled or expr is None:
             return expr
         self._dictionary_of = dictionary_of
+        self._probe = probe
         try:
             return self._rewrite(expr)
         finally:
             self._dictionary_of = None
+            self._probe = False
 
     def _rewrite(self, e: Expr) -> Expr:
         if isinstance(e, Literal):
             return self._maybe_param(e)
         if self._dictionary_of is not None and isinstance(e, ScalarFunc) \
-                and e.op in ("eq", "ne") and len(e.args) == 2:
+                and e.op in ("eq", "ne") and len(e.args) == 2 \
+                and not self._probe:
             coded = self._string_code_compare(e)
             if coded is not None:
                 return coded
         if self._dictionary_of is not None and isinstance(e, ScalarFunc) \
-                and e.op in ("eq", "ne", "like", "ilike"):
+                and e.op in (("like", "ilike") if self._probe
+                             else ("eq", "ne", "like", "ilike")):
             masked = self._string_mask(e)
             if masked is not None:
                 return masked
@@ -377,7 +389,8 @@ class Parameterizer:
             if dictionary is None:
                 return None
             esc = None if escape is None else escape.value
-            with detail("join:like") as attrs:
+            with detail("join:probe_like" if self._probe
+                        else "join:like") as attrs:
                 hit = string_mask(dictionary, e.op, lit.value, esc)
                 if hit is None:
                     return None

@@ -212,26 +212,30 @@ def check_agg_static_support(agg_exprs):
                     raise _Unsupported("nested agg")
 
 
-def pack_flat(flat, tags_sink: List) -> jnp.ndarray:
+def pack_flat(flat, tags_sink: List, xp=jnp):
     """Pack every (domain,)-sized aggregate output into ONE f64 matrix so the
     host pulls the whole result in a single transfer — per-array decode costs
-    ~15 device round trips per query, which can dwarf the kernel itself.  64-bit ints ride a lossless
-    bitcast; everything narrower is exact in f64.  Runs under trace; the
-    (kind, dtype) tag per row lands in `tags_sink` for the host decode."""
+    ~15 device round trips per query, which can dwarf the kernel itself.
+    Row i holds ``flat[i]``; everything of 32 bits or less is exact in f64.
+    A 64-bit integer rides as two words: its high word in row i, its low
+    (uint32) word in a row past the flat's.  It cannot ride as a bitcast:
+    the TPU's f64 is a pair of f32, which flushes the denormals a small
+    integer's bits make.  Runs under trace (or on the host with ``xp=np``);
+    the (kind, dtype) tag per row of `flat` lands in `tags_sink` for
+    `unpack_row`."""
     tags_sink.clear()
-    packed = []
+    packed, low = [], []
     for x in flat:
         dt = np.dtype(x.dtype)
-        if dt == np.float64:
-            packed.append(x)
+        if dt.kind in "iu" and dt.itemsize == 8:
+            high = np.int32 if dt.kind == "i" else np.uint32
+            packed.append((x >> 32).astype(high).astype(np.float64))
+            low.append((x & 0xFFFFFFFF).astype(np.uint32).astype(np.float64))
+            tags_sink.append(("words", dt))
+        else:  # f64 as it is; bool, f32/f16, ints <= 32 bits: exact in f64
+            packed.append(x if dt == np.float64 else x.astype(np.float64))
             tags_sink.append(("as", dt))
-        elif dt.kind in "iu" and dt.itemsize == 8:
-            packed.append(jax.lax.bitcast_convert_type(x, jnp.float64))
-            tags_sink.append(("bits", dt))
-        else:  # bool, f32/f16, ints <= 32 bits: exact in f64
-            packed.append(x.astype(jnp.float64))
-            tags_sink.append(("as", dt))
-    return jnp.stack(packed, axis=0)
+    return xp.stack(packed + low, axis=0)
 
 
 # above this domain the device compacts to the present groups before the
@@ -259,11 +263,15 @@ def fetch_packed(packed, domain: int) -> Tuple[np.ndarray, np.ndarray]:
 
 
 def unpack_row(host: np.ndarray, i: int, tags) -> np.ndarray:
-    """Recover output row i of a fetched pack in its original dtype."""
+    """Recover output row i of a fetched pack (`pack_flat`) in its original
+    dtype."""
     kind, dt = tags[i]
     row = np.ascontiguousarray(host[i])
-    if kind == "bits":
-        return row.view(dt)
+    if kind == "words":
+        # the low words follow the flat's rows, in the order of their tags
+        j = len(tags) + sum(k == "words" for k, _ in tags[:i])
+        return ((row.astype(np.int64) << 32)
+                | host[j].astype(np.int64)).astype(dt)
     return row.astype(dt) if row.dtype != dt else row
 
 

@@ -79,7 +79,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..columnar.column import Column
-from ..columnar.dtypes import STRING_TYPES, SqlType, sql_to_np
+from ..columnar.dtypes import INTEGER_TYPES, STRING_TYPES, SqlType, sql_to_np
 from ..columnar.table import Table
 from ..ops.join import bucket_rows, by_parts, dense_unique_lut, pad_rows
 from ..planner import plan as p
@@ -193,11 +193,14 @@ def _walk_left_spine(node, ext: _Extraction) -> Optional[List[Expr]]:
         return [_rewrite(e, inner) for e in node.exprs]
     if isinstance(node, p.Filter):
         inner = _walk_left_spine(node.input, ext)
-        if inner is None:
+        if inner is None or any(j.get("outer") for j in ext.joins):
+            # above an outer join a WHERE would drop preserved groups
             return None
         ext.conjuncts.append(_rewrite(node.predicate, inner))
         return inner
     if isinstance(node, p.Join):
+        if node.join_type in ("LEFT", "RIGHT"):
+            return _walk_outer(node, ext)
         if node.join_type not in ("INNER", "LEFTSEMI") \
                 or node.filter is not None:
             return None
@@ -240,6 +243,156 @@ def _walk_left_spine(node, ext: _Extraction) -> Optional[List[Expr]]:
         return [ColumnRef(j, f.name, f.sql_type, f.nullable)
                 for j, f in enumerate(node.schema)]
     return None
+
+
+def _walk_outer(node: p.Join, ext: _Extraction) -> Optional[List[Expr]]:
+    """``A LEFT JOIN B ON a.k = b.k [AND <conjuncts over B>]`` (or its
+    mirror ``B RIGHT JOIN A``) for `_walk_left_spine`: B, the side whose
+    rows the join may drop, is the probe, and A, the preserved side, the
+    build side: a join that keeps every row of A (`_outer_shape`).  The
+    ON conjuncts read B alone and become probe masks, which drop B's rows
+    and never a row of A; a conjunct over A declines."""
+    if len(node.on) != 1:
+        return None
+    left_n = len(node.left.schema)
+    lkey_raw, rkey_raw = node.on[0]
+    rkey_raw = shift_columns(rkey_raw, -left_n)
+    if node.join_type == "LEFT":
+        build, probe, bkey, pkey = node.left, node.right, lkey_raw, rkey_raw
+    else:
+        build, probe, bkey, pkey = node.right, node.left, rkey_raw, lkey_raw
+    pslots = _walk_left_spine(probe, ext)
+    if pslots is None or ext.joins:
+        return None
+    bslots = [_BuildRef(0, j, f.sql_type, f.nullable)
+              for j, f in enumerate(build.schema)]
+    slots = bslots + pslots if node.join_type == "LEFT" else pslots + bslots
+    ext.joins.append({"plan": build, "lkey": _rewrite(pkey, pslots),
+                      "rkey": bkey, "exposes": True, "grouped": None,
+                      "outer": True})
+    if node.filter is not None:
+        on = _rewrite(node.filter, slots)
+        if any(isinstance(sub, _BuildRef) for sub in walk(on)):
+            return None
+        ext.conjuncts.append(on)
+    return slots
+
+
+def _outer_shape(ext: _Extraction, group_exprs, agg_exprs) -> bool:
+    """Whether an extraction with an outer join is one the program answers
+    exactly: the chain's only join, grouped by the preserved side's key
+    (and its other columns), every aggregate ``COUNT(*)`` or one over a
+    bare column of the probe, which a row the join extends with NULLs
+    leaves NULL.  Each preserved row is then its group: ``COUNT(*)`` is 1
+    where no probe row reached it, ``COUNT(col)`` 0, the others NULL.
+    True where no join is outer."""
+    if not any(j.get("outer") for j in ext.joins):
+        return True
+    if len(ext.joins) != 1 or type(ext.joins[0]["rkey"]) is not ColumnRef:
+        return False
+    key = ext.joins[0]["rkey"].index
+    if not group_exprs or not all(isinstance(g, _BuildRef)
+                                  for g in group_exprs) \
+            or not any(g.col == key for g in group_exprs):
+        return False
+    return all(a.filter is None and all(type(x) is ColumnRef for x in a.args)
+               for a in agg_exprs)
+
+
+def _reduce_nested(nested: dict, present, outs, params):
+    """Under trace: the outer aggregate of `_nested_shape` over the inner's
+    ``[domain]`` state, its groups `present` and its aggregates `outs`
+    (``(values, validity or None)`` each).  The outer key's runtime range
+    ``[lo, hi]`` decides: where it spans under `_NESTED_CAP` values the
+    groups fall into a histogram of that many slots (and one for NULL);
+    else a branch of the same executable gives each distinct value a slot
+    of a hash table (`_hash_slots`), exact whatever the range, and with no
+    sort (a sort compiles for a minute on the TPU).  ``(flat, fits)``:
+    rows of as many slots as the table has (and one), present then the
+    key's values and validity then each aggregate's values and
+    validity, the histogram's slots first; and whether the histogram took
+    them."""
+    from .compiled import SegmentReducer
+
+    outs = [(d.astype(dt), v) for (d, v), dt in zip(outs, nested["dtypes"])]
+    kd, kv = outs[nested["key"]]
+    n = kd.shape[0]
+    table = 1 << (2 * n - 1).bit_length()
+    width = max(table, _NESTED_CAP) + 1
+    valued = present if kv is None else present & kv
+    info = jnp.iinfo(kd.dtype)
+    lo = jnp.min(jnp.where(valued, kd, info.max))
+    hi = jnp.max(jnp.where(valued, kd, info.min))
+    # no valued group: hi < lo; a range past the dtype wraps below 0
+    span = hi - lo
+    fits = (hi < lo) | ((span >= 0) & (span < _NESTED_CAP))
+    aggs, ev = nested["aggs"], nested["ev"]
+    slots = dict(enumerate(outs))
+    slots[PARAMS_SLOT] = params
+
+    def reduce(gid, key, mode, domain):
+        reducer = SegmentReducer(gid, domain, mode, n)
+        hit_h = reducer.count(present)
+        got = segment_agg_outputs(ev, slots, aggs, present, gid, domain,
+                                  reducer)
+        values, valid = key
+        flat = [reducer.get(hit_h) > 0, values, valid]
+        for a, (d, v) in zip(aggs, got):
+            flat.append(d.astype(sql_to_np(a.sql_type)))
+            flat.append(jnp.ones(domain, dtype=bool) if v is None else v)
+        return [jnp.pad(x, (0, width - domain)) for x in flat]
+
+    def histogram():
+        gid = jnp.where(valued, jnp.clip(kd - lo, 0, _NESTED_CAP - 1),
+                        _NESTED_CAP).astype(jnp.int32)
+        at = jnp.arange(_NESTED_CAP + 1)
+        return reduce(gid, (lo + at.astype(kd.dtype), at < _NESTED_CAP),
+                      nested["mode"], _NESTED_CAP + 1)
+
+    def exact():
+        gid, used, keys = _hash_slots(kd, valued, table)
+        # the NULL key's group takes the slot past the table's
+        gid = jnp.where(valued, gid, table)
+        return reduce(gid, (jnp.pad(keys, (0, 1)), jnp.pad(used, (0, 1))),
+                      "scatter", table + 1)
+
+    return jax.lax.cond(fits, histogram, exact), fits
+
+
+def _hash_slots(kd, valued, table: int):
+    """Under trace: a slot of a hash table of `table` slots (a power of two
+    past the rows) for each distinct value of `kd` among the `valued` rows,
+    by open addressing: every round, each unplaced row probes its key's
+    next slot (double hashing, an odd step, so a key's probes visit every
+    slot), a free slot goes to the highest row that probes it, and every
+    row whose probed slot holds its key is placed.  Each round places the
+    keys of every free slot probed, so the rounds end.  ``(slot per row,
+    table's slots in use, the key in each)``."""
+    bits = table.bit_length() - 1
+    x = kd.astype(jnp.uint64)
+    shift = np.uint64(64 - bits)
+    at = ((x * np.uint64(0x9E3779B97F4A7C15)) >> shift).astype(jnp.int32)
+    step = (((x * np.uint64(0xC2B2AE3D27D4EB4F)) >> shift)
+            | np.uint64(1)).astype(jnp.int32)
+    rows = jnp.arange(kd.shape[0], dtype=jnp.int32)
+
+    def probe(state):
+        at, gid, used, keys, open_ = state
+        free = open_ & ~used[at]
+        winner = jnp.full(table, -1, dtype=jnp.int32).at[at].max(
+            jnp.where(free, rows, -1))
+        claimed = winner >= 0
+        used = used | claimed
+        keys = jnp.where(claimed, kd[jnp.clip(winner, 0)], keys)
+        placed = open_ & used[at] & (keys[at] == kd)
+        return ((at + step) & (table - 1), jnp.where(placed, at, gid), used,
+                keys, open_ & ~placed)
+
+    state = (at, jnp.zeros_like(rows), jnp.zeros(table, dtype=bool),
+             jnp.zeros(table, dtype=kd.dtype), valued)
+    _, gid, used, keys, _ = jax.lax.while_loop(
+        lambda s: jnp.any(s[4]), probe, state)
+    return gid, used, keys
 
 
 def _grouped_by_key(node, rkey: Expr) -> Optional[dict]:
@@ -308,7 +461,68 @@ def _extract(agg: p.Aggregate):
     if slots is None or ext.scan is None or not ext.joins:
         return None
     group_exprs = [_rewrite(e, slots) for e in agg.group_exprs]
-    return ext, group_exprs, [_rewrite_agg(a, slots) for a in agg.agg_exprs]
+    agg_exprs = [_rewrite_agg(a, slots) for a in agg.agg_exprs]
+    if not _outer_shape(ext, group_exprs, agg_exprs):
+        return None
+    return ext, group_exprs, agg_exprs
+
+
+#: an outer GROUP BY over an inner aggregate's outputs (`_nested_shape`) is
+#: reduced into this many values of its key's runtime range (and NULL)
+_NESTED_CAP = 1024
+_NESTED_AGGS = frozenset({"count_star", "count", "sum", "min", "max"})
+
+
+def _nested_shape(rel: p.Aggregate) -> Optional[dict]:
+    """A group-by of a group-by (TPC-H Q13's ``GROUP BY c_count`` over
+    ``c_orders``): `rel` an Aggregate over (aliases and column-picking
+    Projections over) an inner Aggregate with group keys, grouped by ONE
+    integer output of the inner's aggregates, its own aggregates
+    ``COUNT(*)``, ``COUNT``, ``SUM``, ``MIN`` or ``MAX`` of such outputs.
+    ``{"agg": the inner Aggregate, "key": the key's index among the
+    inner's aggregates, "aggs": `rel`'s aggregates over them (slot i:
+    aggregate i), "fields": the inner aggregates' fields, "rel": rel}``,
+    or None for any other shape."""
+    def down(node):
+        if isinstance(node, p.SubqueryAlias):
+            return down(node.inputs()[0])
+        if isinstance(node, p.Projection):
+            inner = down(node.input)
+            if inner is None or not all(type(e) is ColumnRef
+                                        for e in node.exprs):
+                return None
+            return inner[0], [inner[1][e.index] for e in node.exprs]
+        if isinstance(node, p.Aggregate) and node.group_exprs:
+            return node, list(range(len(node.schema)))
+        return None
+
+    found = down(rel.input)
+    if found is None or len(rel.group_exprs) != 1:
+        return None
+    inner, at = found
+    n_keys = len(inner.group_exprs)
+    fields = list(inner.schema)[n_keys:]
+
+    def output(x) -> Optional[int]:
+        """The inner aggregate `x` reads, or None."""
+        if type(x) is not ColumnRef or at[x.index] < n_keys:
+            return None
+        return at[x.index] - n_keys
+
+    key = output(rel.group_exprs[0])
+    if key is None or fields[key].sql_type not in INTEGER_TYPES:
+        return None
+    aggs = []
+    for a in rel.agg_exprs:
+        reads = [output(x) for x in a.args]
+        if a.func not in _NESTED_AGGS or a.distinct or a.filter is not None \
+                or None in reads:
+            return None
+        aggs.append(_rp(a, args=tuple(
+            ColumnRef(i, f"__n{i}", fields[i].sql_type, fields[i].nullable)
+            for i in reads)))
+    return {"agg": inner, "key": key, "aggs": aggs, "fields": fields,
+            "rel": rel}
 
 
 def _plan_whole_builds(ext: _Extraction, group_exprs, agg_exprs):
@@ -633,7 +847,7 @@ class CompiledJoinAggregate:
     def __init__(self, rel: p.Aggregate, ext: _Extraction, group_exprs,
                  agg_exprs, probe_table: Table, build_tables: List[Table],
                  executor, whole: Optional[List[Optional[dict]]] = None,
-                 topk: Optional[TopK] = None):
+                 topk: Optional[TopK] = None, nested: Optional[dict] = None):
         """``whole[k]``: None where build table k was executed eagerly (its
         LUT is built here, from the rows it has left), else ``{"conjuncts":
         the build side's own parameterised conjuncts, "lut": (rmin, lut),
@@ -643,7 +857,9 @@ class CompiledJoinAggregate:
         the program reduces itself has ``"semi": {"domain", "key", "aggs",
         "having", "schema"}`` (parameterised) in place of "uid", and
         ``"lut": (the key range's low end, None)``: its LUT is made in the
-        program, over the ``domain`` values (bucketed) of that range."""
+        program, over the ``domain`` values (bucketed) of that range.
+        `nested`: `_nested_shape`'s outer aggregate over `rel`, which the
+        program reduces over `rel`'s groups and returns in its place."""
         self.rel = rel
         self.ext = ext
         self.probe_table = probe_table
@@ -846,6 +1062,26 @@ class CompiledJoinAggregate:
             [semi["domain"] for semi in self.semis.values()]
             + ([domain_est] if self.radix_spec is not None else []))
         self.segsum_mode = choose_segsum_impl(executor.config, domain_est)
+        #: the outer join's build side (`_walk_outer`), each row a group
+        self.outer = next((k for k, j in enumerate(ext.joins)
+                           if j.get("outer")), None)
+        #: the outer aggregate the program reduces over this one's groups
+        self.nested = None if nested is None \
+            else self._plan_nested(nested, executor)
+        if self.outer is not None or self.nested is not None:
+            from ..parallel import dist_plan as _dp
+
+            if type(self) is not CompiledJoinAggregate \
+                    or probe_table.row_valid is not None \
+                    or any(_dp.array_is_sharded(c.data)
+                           for c in probe_table.columns.values()):
+                raise _Unsupported("outer join or nested aggregate off "
+                                   "one chip")
+        if self.outer is not None and (
+                self.gid_join != self.outer or self.dependents
+                or whole[self.outer] is None
+                or "uid" not in whole[self.outer]):
+            raise _Unsupported("outer join not grouped by its preserved rows")
         self.topk = self._plan_topk(topk, build_tables)
         #: joins probed at the compacted rows only (`_plan_deferred`), in
         #: join order; none where the program does not compact
@@ -916,6 +1152,21 @@ class CompiledJoinAggregate:
                     having_ev=_TraceEval(_SlotMeta(
                         metas, [f"__h{i}" for i in range(len(fields))])))
 
+    @staticmethod
+    def _plan_nested(nested: dict, executor) -> dict:
+        """`nested` with the evaluator of its aggregates over the inner
+        aggregates' outputs and the segment-sum mode of its histogram."""
+        from ..ops.pallas_kernels import choose_segsum_impl
+
+        check_agg_static_support(nested["aggs"])
+        fields = nested["fields"]
+        metas = [_ColMeta(Column(np.empty(0, dtype=sql_to_np(f.sql_type)),
+                                 f.sql_type)) for f in fields]
+        return dict(nested, dtypes=[sql_to_np(f.sql_type) for f in fields],
+                    mode=choose_segsum_impl(executor.config, _NESTED_CAP + 1),
+                    ev=_TraceEval(_SlotMeta(
+                        metas, [f"__n{i}" for i in range(len(fields))])))
+
     def _plan_folds(self, ext, whole, rest) -> Dict[int, int]:
         """Joins to probe from an earlier build side's rows instead of the
         probe table's: join k whose key reads columns of ONE whole build
@@ -985,13 +1236,17 @@ class CompiledJoinAggregate:
         is not per row) and no join to defer to the compacted rows, a probe
         sharded over a mesh or padded (the sharded rung, spmd/join.py,
         traces this class's body per shard), a probe under
-        `_COMPACT_MIN_ROWS`."""
+        `_COMPACT_MIN_ROWS`, an outer join's probe: its only conjuncts are
+        its ON clause's, whose share is the pattern's and Q13's NOT LIKE
+        passes nearly every row, so the compacted branch would compile for
+        nothing."""
         from ..parallel import dist_plan as _dp
 
         datas = [c.data for c in probe_table.columns.values()]
         n_rows = int(datas[0].shape[0])
         if (type(self) is not CompiledJoinAggregate
                 or (self.segsum_mode != "scatter" and not self.deferred)
+                or self.outer is not None
                 or probe_table.row_valid is not None
                 or any(_dp.array_is_sharded(d) for d in datas)
                 or not _COMPACT_MIN_ROWS <= n_rows < (1 << 31)):
@@ -1130,6 +1385,8 @@ class CompiledJoinAggregate:
         topk = self.topk
         compact_cap = self.compact_cap
         semis = self.semis
+        outer = self.outer
+        nested = self.nested
         dependents = self.dependents
         dep_lkeys = self.dep_lkeys
         lkeys2 = self.lkeys2
@@ -1275,6 +1532,17 @@ class CompiledJoinAggregate:
                     kept[k] = semi_lut(k) if k in semis else filtered_lut(k)
                 return kept[k]
 
+            def build_keep(k):
+                """The rows of whole build side `k` its own conjuncts keep,
+                or None where it has none."""
+                bslots = build_slots(k)
+                keep = None
+                for f in build_conjuncts[k]:
+                    d, v = build_evs[k].eval(f, bslots)
+                    d = d if v is None else (d & v)
+                    keep = d if keep is None else (keep & d)
+                return keep
+
             def filtered_lut(k):
                 """LUT `k` without the rows that build side k's own filters
                 reject, or a join probed from its rows (`folded`) leaves
@@ -1285,12 +1553,7 @@ class CompiledJoinAggregate:
                 lut = luts[k]
                 if build_evs[k] is None:
                     return lut  # an eagerly executed build side came filtered
-                bslots = build_slots(k)
-                keep = None
-                for f in build_conjuncts[k]:
-                    d, v = build_evs[k].eval(f, bslots)
-                    d = d if v is None else (d & v)
-                    keep = d if keep is None else (keep & d)
+                keep = build_keep(k)
                 for m, parent in folded.items():
                     if parent == k:
                         hit = rows_of(m, k) >= 0
@@ -1456,11 +1719,31 @@ class CompiledJoinAggregate:
                 hits, outs = jax.lax.cond(passed <= compact_cap, compacted,
                                           whole)
             hit = hits > 0
+            if outer is not None:
+                # every row of the preserved side its own filters keep is a
+                # group, whether a probe row reached it or not: where none
+                # did, the join's one row for it holds NULLs, which COUNT(*)
+                # counts and every other aggregate (of a probe column) skips
+                hit = jnp.arange(domain, dtype=bounds.dtype) < bounds[outer, 2]
+                keep = build_keep(outer)
+                if keep is not None:
+                    hit = hit & keep
+                outs = [(jnp.maximum(d, 1), v) if a.func == "count_star"
+                        else (d, v) for a, (d, v) in zip(agg_exprs, outs)]
             tags: List[Tuple[str, np.dtype]] = []
             # the counts the host reads beside the rows: the probe rows that
             # passed (a compacting program), then each semi-join's groups
             # and those of them its HAVING kept
             counts = ([passed] if compact_cap else []) + semi_counts
+            if nested is not None:
+                # the outer aggregate over these groups: only its rows
+                # leave, the histogram's from the pack's head (`run`), and
+                # whether they are all there last among the counts
+                flat, fits = _reduce_nested(nested, hit, outs, params)
+                out = pack_flat(flat, tags)
+                self._pack_tags = tags
+                counts.append((~fits).astype(jnp.int32))
+                return out[:, :_NESTED_CAP + 1], out, jnp.stack(counts)
             if topk is None:
                 flat = [hit]
                 for d, v in outs:
@@ -1544,6 +1827,10 @@ class CompiledJoinAggregate:
             launch_attrs["semi"] = len(self.semis)
         if self.composites:
             launch_attrs["composite"] = len(self.composites)
+        if self.outer is not None:
+            launch_attrs["outer"] = 1
+        if self.nested is not None:
+            launch_attrs["nested"] = 1
         if self.deferred:
             launch_attrs["deferred"] = len(self.deferred)
         if self.sum_codespace:
@@ -1559,7 +1846,11 @@ class CompiledJoinAggregate:
         # the tail as the host sees it: the pull of the packed rows (its
         # `fetch` child also holds the wait for the device) and the decode
         with detail("join:tail") as attrs:
-            if self.topk is not None:
+            if self.nested is not None:
+                result, counts, overflow = self._decode_nested(packed, tags)
+                groups = result.num_rows
+                attrs.update(overflow=overflow)
+            elif self.topk is not None:
                 result, groups, counts = self._decode_topk(packed, tags)
             else:
                 if cap or self.semis:  # the program's outputs: (pack, counts)
@@ -1580,6 +1871,37 @@ class CompiledJoinAggregate:
             if passed > cap:
                 self.metrics.inc("join.compact.overflow")
         return result
+
+    def _decode_nested(self, packed, tags) -> Tuple[Table, List[int], bool]:
+        """The host's half of a nested aggregate (`_reduce_nested`): one
+        pull of the histogram's slots and the counts, a second of every
+        slot where the key's range overflowed the histogram; then the
+        outer aggregate's present rows as a host-resident table, the
+        program's other counts, and whether it overflowed."""
+        from ..utils import d2h_fetch
+        from .compiled import unpack_row
+        from .rel.base import unique_names
+
+        head, whole, counts = packed
+        with d2h_fetch(nbytes=int(head.nbytes) + int(counts.nbytes)):
+            host, counts = jax.device_get((head, counts))
+        counts = [int(c) for c in counts]
+        overflow = bool(counts.pop())
+        if overflow:
+            with d2h_fetch(nbytes=int(whole.nbytes)):
+                host = jax.device_get(whole)
+        host = np.asarray(host)
+        host = host[:, np.nonzero(host[0] != 0.0)[0]]
+        outer = self.nested["rel"]
+        names = unique_names([f.name for f in outer.schema])
+        out: Dict[str, Column] = {}
+        for i, (name, field) in enumerate(zip(names, outer.schema)):
+            d = unpack_row(host, 1 + 2 * i, tags)
+            v = unpack_row(host, 2 + 2 * i, tags) != 0
+            target = sql_to_np(field.sql_type)
+            out[name] = Column(d.astype(target) if d.dtype != target else d,
+                               field.sql_type, None if bool(v.all()) else v)
+        return Table(out, host.shape[1]), counts, overflow
 
     def _decode_topk(self, packed, tags) -> Tuple[Table, int, List[int]]:
         """The host's half of the top-k tail: one pull of the ``[rows, k]``
@@ -1886,7 +2208,10 @@ def try_compiled_join_aggregate(rel: p.Aggregate, executor) -> Optional[Table]:
         return None
     if not executor.config.get("sql.compile.join_pipeline", True):
         return None
-    extraction = _extract(rel)
+    # a group-by of a group-by: the program reduces the inner aggregate and
+    # the outer one over its groups, and answers for `rel`
+    nested = _nested_shape(rel)
+    extraction = _extract(rel if nested is None else nested["agg"])
     if extraction is None:
         return None
     ext, group_exprs, agg_exprs = extraction
@@ -1930,14 +2255,20 @@ def try_compiled_join_aggregate(rel: p.Aggregate, executor) -> Optional[Table]:
         from .. import families
 
         pz = families.pipeline_parameterizer(executor.config)
-        ext.conjuncts = [pz.rewrite(e) for e in ext.conjuncts]
-        agg_exprs = [pz.rewrite_agg(a) for a in agg_exprs]
         probe_table = executor.get_table(ext.scan.schema_name,
                                          ext.scan.table_name)
         if ext.scan.projection is not None:
             probe_table = probe_table.select(ext.scan.projection)
         if not probe_table.column_names:
             return None
+
+        def probe_dictionary(i):
+            return probe_table.columns[probe_table.column_names[i]].dictionary
+
+        # a LIKE on a probe column rides as a mask over its dictionary
+        ext.conjuncts = [pz.rewrite(e, probe_dictionary, probe=True)
+                         for e in ext.conjuncts]
+        agg_exprs = [pz.rewrite_agg(a) for a in agg_exprs]
         # all the host does per request to have the build sides ready
         build_tables: List[Table] = []
         whole: List[Optional[dict]] = []
@@ -2002,6 +2333,14 @@ def try_compiled_join_aggregate(rel: p.Aggregate, executor) -> Optional[Table]:
                                 w["composite"] = comp
                 if w is None and "pair" in j:
                     raise _Unsupported("two-column key not kept whole")
+                if j.get("outer") and (
+                        w is None or "composite" in w
+                        or bt.columns[bt.column_names[j["rkey"].index]]
+                        .validity is not None):
+                    # each preserved row is its own group: a NULL key would
+                    # be one group of several rows
+                    raise _Unsupported("outer join's preserved side not kept "
+                                       "whole on a key without NULLs")
                 if w is None:
                     # any other build side runs through the normal recursive
                     # converter (nested joins, aggregates, anything) and
@@ -2014,7 +2353,8 @@ def try_compiled_join_aggregate(rel: p.Aggregate, executor) -> Optional[Table]:
         if pz.masks:
             ctx.metrics.inc("join.like.masks", len(pz.masks))
         params = pz.params
-        topk = executor.topk_hints.get(id(rel))
+        # the Sort above a nested aggregate orders its few rows itself
+        topk = None if nested else executor.topk_hints.get(id(rel))
         family = (
             ext.scan.schema_name, ext.scan.table_name,
             tuple(ext.scan.projection or ()),
@@ -2033,12 +2373,15 @@ def try_compiled_join_aggregate(rel: p.Aggregate, executor) -> Optional[Table]:
                   for j, w in zip(ext.joins, whole)),
             tuple(str(j["lkey"]) + "=" + str(j["rkey"])
                   + ("&{}={}".format(*j["pair"]) if "pair" in j else "")
+                  + (" outer" if j.get("outer") else "")
                   for j in ext.joins),
             tuple(str(e) for e in ext.conjuncts),
             tuple(str(e) for e in group_exprs),
             tuple(str(a) for a in agg_exprs),
             tuple((f.name, f.sql_type) for f in rel.schema),
             topk,
+            nested and (tuple(str(a) for a in nested["agg"].agg_exprs),
+                        nested["key"], tuple(str(a) for a in nested["aggs"])),
         )
         bucket = (tuple(uids), probe_table.num_rows, probe_table.padded_rows,
                   tuple(bt.num_rows if w is None or "semi" in w
@@ -2049,9 +2392,10 @@ def try_compiled_join_aggregate(rel: p.Aggregate, executor) -> Optional[Table]:
         # below drops them.  No `warm`: this rung never defers
         compiled, built_here = PROGRAMS.get_or_build(
             ctx, family, bucket,
-            lambda: CompiledJoinAggregate(rel, ext, group_exprs, agg_exprs,
-                                          probe_table, build_tables,
-                                          executor, whole=whole, topk=topk),
+            lambda: CompiledJoinAggregate(
+                rel if nested is None else nested["agg"], ext, group_exprs,
+                agg_exprs, probe_table, build_tables, executor, whole=whole,
+                topk=topk, nested=nested),
             params=params)
         if not built_here:
             compiled.probe_table = probe_table
@@ -2082,6 +2426,10 @@ def try_compiled_join_aggregate(rel: p.Aggregate, executor) -> Optional[Table]:
 
             faults.maybe_inject("oom", executor.config)
             result = compiled.run(params)
+            if compiled.outer is not None:
+                ctx.metrics.inc("join.outer.preserved")
+            if nested is not None:
+                ctx.metrics.inc("aggregate.nested")
             for sattrs, (groups, passed) in zip(semi_attrs,
                                                 compiled.semi_stats):
                 sattrs.update(groups=groups, passed=passed)

@@ -78,6 +78,9 @@ DOCUMENTED_METRICS = frozenset({
     # dictionary (`families/parameterize.py::_string_mask`; one per
     # request and pattern)
     "join.like.masks",
+    # requests answered with an outer join's preserved build side (every
+    # row of it a group: `compiled_join.py::_walk_outer`)
+    "join.outer.preserved",
     # physical/compiled.py + compiled_join.py — programs built with ONE
     # integer group key whose range lies past the mixed-radix gate
     # (`ops.grouping.one_key_domain_limit`: admitted by the bytes of its
@@ -86,6 +89,10 @@ DOCUMENTED_METRICS = frozenset({
     # SUM / AVG aggregates over a DICT column of whole numbers that a built
     # program sums as int32 in code space (`compiled.py::codespace_sum`)
     "aggregate.sum.codespace",
+    # requests whose outer GROUP BY over an inner aggregate's outputs the
+    # join rung reduced in the inner's program (`compiled_join.py::
+    # _nested_shape`)
+    "aggregate.nested",
     # inference/ — model lowering + fused PREDICT (docs/ml.md)
     "inference.model.registered",
     "inference.model.lowered",

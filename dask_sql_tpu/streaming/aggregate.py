@@ -44,6 +44,7 @@ from ..physical.compiled import (
     _TraceEval,
     _Unsupported,
     agg_argument,
+    pack_flat,
 )
 from ..physical.programs import ProgramCache
 from ..planner import plan as p
@@ -202,19 +203,10 @@ class StreamedAggregate(CompiledAggregate):
         with d2h_fetch(nbytes=sum(int(x.nbytes) for x in acc)):
             host = [np.asarray(x) for x in jax.device_get(tuple(acc))]
         hit = host[0]
-        rows: List[np.ndarray] = [(hit != 0).astype(np.float64)]
-        tags: List[Tuple[str, np.dtype]] = [("as", np.dtype(np.float64))]
+        flat: List[np.ndarray] = [hit != 0]
 
         def emit(d: np.ndarray, v: np.ndarray) -> None:
-            dt = np.dtype(d.dtype)
-            if dt.kind in "iu" and dt.itemsize == 8:
-                rows.append(np.ascontiguousarray(d).view(np.float64))
-                tags.append(("bits", dt))
-            else:
-                rows.append(d.astype(np.float64))
-                tags.append(("as", dt))
-            rows.append(v.astype(np.float64))
-            tags.append(("as", np.dtype(np.bool_)))
+            flat.extend((d, v))
 
         for func, idxs in self._finalize_plan:
             # idxs are absolute state positions (index 0 is the hit count)
@@ -241,7 +233,8 @@ class StreamedAggregate(CompiledAggregate):
                        / np.maximum(cnt - ddof, 1))
                 out = np.sqrt(var) if func.startswith("stddev") else var
                 emit(out, cnt > ddof)
-        matrix = np.stack(rows, axis=0)
+        tags: List[Tuple[str, np.dtype]] = []
+        matrix = pack_flat(flat, tags, xp=np)
         present = np.nonzero(hit != 0)[0]
         return self._decode(matrix[:, present], present, tags)
 

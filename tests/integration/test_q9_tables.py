@@ -100,6 +100,7 @@ def test_twelve_colors_equal_the_reference_on_one_executable(q9):
     assert c.metrics.counter("join.build.composite") - before <= 1
     launch = [s for s in c.last_trace.spans if s.name == "launch"]
     assert launch[0].attrs["composite"] == 1
+    assert "outer" not in launch[0].attrs and "nested" not in launch[0].attrs
 
 
 def test_the_float32_control_is_outside_the_limit(q9):

@@ -364,7 +364,8 @@ def test_join_aggregate_q3_fits_one_chip_at_cell_size(one_chip):
     with pytest.MonkeyPatch.context() as mp, \
             config_module.set({"serving.cache.enabled": False}):
         mp.setattr(CompiledJoinAggregate, "run", spy_run)
-        # the cell's probe is above the compaction's row floor
+        # the cell's probe is above the compaction's row floor: the outer
+        # join plans none even so
         mp.setattr(compiled_join, "_COMPACT_MIN_ROWS", SMALL_ROWS)
         c = Context()
         for name in ("customer", "orders", "lineitem"):
@@ -699,3 +700,104 @@ def test_join_aggregate_q9_fits_one_chip_at_cell_size(one_chip):
     sorts = re.findall(r"= \(?(\w+)\[(\d+)\][^=]*? sort\(", text)
     assert sorts and all(dtype in ("s32", "u32", "f32", "pred")
                          for dtype, _ in sorts), sorts
+
+
+#: the benchmark's Q13 cell (sf10_q13_library): ORDERS and CUSTOMER whole
+#: at SF10, what create_table encodes Q13's columns to at that size, and
+#: the comments' dictionary (one entry per order, near enough)
+Q13_ROWS = {"orders": 15_000_000, "customer": 1_500_000}
+Q13_DTYPES = {"o_orderkey": "int32", "o_custkey": "int32",
+              "o_comment": "int32", "c_custkey": "int32"}
+
+
+def test_join_aggregate_q13_fits_one_chip_at_cell_size(one_chip):
+    """`sf10_q13_library`'s ONE program compiled for a v5e at the cell's
+    shapes: ORDERS' 15M rows the probe, the NOT LIKE a runtime mask of the
+    comments' 15M-entry bucket read at the rows' codes, CUSTOMER's 1.5M
+    rows through its kept LUT, each of them a group (the LEFT OUTER join),
+    a scatter of the counts into them over the whole probe (an outer join
+    plans no compaction: the NOT LIKE passes nearly every order), and the
+    outer GROUP BY c_count over those groups: a `lax.cond` between the
+    histogram of 1,024 values and the exact branch's hash table, which
+    sorts nothing (a sort compiles for a minute).  Captured from Q13 as
+    `perfbench.traffic` renders it over the cell's generator at 200,000
+    orders, then traced anew with the cell's domains.  Costs the suite 3 s
+    of set-up and the compile (printed with -s)."""
+    import time
+
+    from dask_sql_tpu import Context
+    from dask_sql_tpu import config as config_module
+    from dask_sql_tpu.ops.join import bucket_rows
+    from dask_sql_tpu.physical import compiled_join
+    from dask_sql_tpu.physical.compiled_join import CompiledJoinAggregate
+    from perfbench import traffic
+    from perfbench.datagen import tpch_q13_tables
+
+    arrays = tpch_q13_tables.generate(SMALL_ROWS, seed=41, scale_factor=10)
+    frames = tpch_q13_tables.arrow_tables(arrays)
+    seen = []
+    run = CompiledJoinAggregate.run
+
+    def spy_run(self, params=()):
+        seen.append((self, self.probe_table, self._run_args(params)))
+        return run(self, params)
+
+    with pytest.MonkeyPatch.context() as mp, \
+            config_module.set({"serving.cache.enabled": False}):
+        mp.setattr(CompiledJoinAggregate, "run", spy_run)
+        # the cell's probe is above the compaction's row floor: the outer
+        # join plans none even so
+        mp.setattr(compiled_join, "_COMPACT_MIN_ROWS", SMALL_ROWS)
+        compiled_join.PROGRAMS.clear()
+        c = Context()
+        for name in ("customer", "orders"):
+            c.create_table(name, frames[name])
+        c.sql(traffic.render(traffic.load("queries",
+                                          "tpch_q13_special_requests"),
+                             {"WORD1": 0, "WORD2": 1})).compute()
+    (pipeline, probe_table, args), = seen
+    assert pipeline.outer == 0 and pipeline.nested is not None
+    assert pipeline.compact_cap == 0
+    probe_datas, probe_valids, luts, build_cols, row_valid, params, bounds \
+        = args
+    assert row_valid is None and not any(v is not None for v in probe_valids)
+
+    def shaped(rows, name, data):
+        return jax.ShapeDtypeStruct((rows,), Q13_DTYPES.get(name, data.dtype),
+                                    sharding=one_chip)
+
+    rows = bucket_rows(Q13_ROWS["customer"])
+    big_probe = tuple(shaped(Q13_ROWS["orders"], n, d) for n, d in
+                      zip(probe_table.column_names, probe_datas))
+    big_luts = (jax.ShapeDtypeStruct((rows,), jnp.int32, sharding=one_chip),)
+    (scan,) = [j["plan"] for j in pipeline.ext.joins]
+    big_build = {(k, col): (shaped(rows, scan.projection[col], data), None)
+                 for (k, col), (data, valid) in build_cols.items()}
+    (mask_at,) = [i for i, p in enumerate(params) if np.ndim(p) == 1]
+    big_params = list(_shapes(tuple(params), one_chip))
+    big_params[mask_at] = jax.ShapeDtypeStruct(
+        (bucket_rows(Q13_ROWS["orders"]),), jnp.bool_, sharding=one_chip)
+    pipeline.probe_table = probe_table
+    pipeline.build_rows = [rows]
+    pipeline.domain = rows
+    try:
+        lowered = jax.jit(pipeline._build()).lower(
+            big_probe, probe_valids, big_luts, big_build, None,
+            tuple(big_params), _shapes(bounds, one_chip))
+    finally:
+        pipeline.probe_table = pipeline.build_tables = None
+    t0 = time.perf_counter()
+    compiled = lowered.compile()
+    seconds = time.perf_counter() - t0
+    m = compiled.memory_analysis()
+    print(f"q13 at cell size: compile {seconds:.1f} s, arguments "
+          f"{m.argument_size_in_bytes}, temporaries {m.temp_size_in_bytes}, "
+          f"output {m.output_size_in_bytes}")
+    assert seconds < 240, seconds
+    text = compiled.as_text()
+    sorts = re.findall(r"= \(?(\w+)\[(\d+)\][^=]*? sort\(", text)
+    assert all(dtype in ("s32", "u32", "f32", "pred")
+               for dtype, _ in sorts), sorts
+    # ORDERS' 17 B a row and CUSTOMER's 8 resident beside the program
+    resident = 40 * Q13_ROWS["orders"] + 40 * Q13_ROWS["customer"]
+    assert _device_bytes(compiled) + resident < HBM_BYTES
